@@ -6,27 +6,27 @@ the full objective/metric families, categorical optimal splits, and
 data-/feature-/voting-parallel learners mapped onto XLA collectives over a
 TPU device mesh.
 """
-from .config import Config  # noqa: F401
-from .utils import log  # noqa: F401
+from .utils.backend import configure_compile_cache as _configure_compile_cache
+
+# before anything below can compile a program
+_configure_compile_cache()
+
+from .basic import Booster, Dataset  # noqa: E402,F401
+from .callback import (early_stopping, print_evaluation,  # noqa: E402,F401
+                       record_evaluation, reset_parameter)
+from .config import Config  # noqa: E402,F401
+from .engine import cv, train  # noqa: E402,F401
+from .plotting import (create_tree_digraph, plot_importance,  # noqa: E402,F401
+                       plot_metric, plot_tree)
+from .sklearn import (LGBMClassifier, LGBMModel,  # noqa: E402,F401
+                      LGBMRanker, LGBMRegressor)
+from .utils import log  # noqa: E402,F401
 
 __version__ = "2.2.4.tpu0"
 
-# Rich user-facing API (Dataset/Booster/train/cv/sklearn) re-exported as the
-# layers land; see basic.py / engine.py / sklearn.py.
-try:  # pragma: no cover - import cycle guard during early construction
-    from .basic import Booster, Dataset  # noqa: F401
-    from .callback import (early_stopping, print_evaluation,  # noqa: F401
-                           record_evaluation, reset_parameter)
-    from .engine import cv, train  # noqa: F401
-    from .plotting import (create_tree_digraph, plot_importance,  # noqa: F401
-                           plot_metric, plot_tree)
-    from .sklearn import (LGBMClassifier, LGBMModel,  # noqa: F401
-                          LGBMRanker, LGBMRegressor)
-    __all__ = ["Config", "Dataset", "Booster", "train", "cv", "log",
-               "early_stopping", "print_evaluation", "record_evaluation",
-               "reset_parameter",
-               "plot_importance", "plot_metric", "plot_tree",
-               "create_tree_digraph", "LGBMModel", "LGBMClassifier",
-               "LGBMRegressor", "LGBMRanker"]
-except ImportError:  # modules not built yet
-    __all__ = ["Config", "log"]
+__all__ = ["Config", "Dataset", "Booster", "train", "cv", "log",
+           "early_stopping", "print_evaluation", "record_evaluation",
+           "reset_parameter",
+           "plot_importance", "plot_metric", "plot_tree",
+           "create_tree_digraph", "LGBMModel", "LGBMClassifier",
+           "LGBMRegressor", "LGBMRanker"]

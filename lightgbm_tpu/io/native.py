@@ -49,7 +49,12 @@ def get_lib():
         return _lib
     _lib_tried = True
     path = os.path.join(_NATIVE_DIR, _LIB_NAME)
-    if not os.path.exists(path):
+    src = os.path.join(_NATIVE_DIR, "fast_parser.cpp")
+    # the library is git-ignored and built here on demand: one older than
+    # its source is a stale leftover, not the code in the tree
+    if not os.path.exists(path) or (
+            os.path.exists(src)
+            and os.path.getmtime(src) > os.path.getmtime(path)):
         path = _build_lib()
     if not path:
         return None

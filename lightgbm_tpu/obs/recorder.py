@@ -295,7 +295,8 @@ class TrainingRecorder:
     def _roofline(self, gbdt, wall_s: float) -> Optional[Dict[str, float]]:
         """Per-round roofline summary: the cached analytic byte/FLOP
         floor for one iteration over the measured wall time, as achieved
-        GB/s / GFLOP/s and shares of the configured roofs.  Also feeds
+        GB/s / GFLOP/s and, on a device with published peaks
+        (perf.DEVICE_PEAKS), shares of those roofs.  Also feeds
         the lgbm_roofline_* gauges and (when the tracer is armed) a
         bytes/FLOPs-tagged span.  Best-effort: any failure disables the
         section for the run rather than touching training."""
@@ -317,7 +318,7 @@ class TrainingRecorder:
                     num_leaves=int(getattr(self.config, "num_leaves", 31)),
                     engine=engine,
                     quantized=bool(getattr(gbdt, "_quantized", False)))
-                self._roof = perf.Roofline.from_config(self.config)
+                self._roof = perf.device_roofline()
             summary = perf.budget_summary(self._budget, wall_s, self._roof)
             perf.publish_iteration_gauges(self.registry, summary)
             tracer = tracing.get_tracer()
@@ -327,7 +328,7 @@ class TrainingRecorder:
                     analytic_bytes=self._budget["total_bytes"],
                     analytic_flops=self._budget["total_flops"],
                     gbps=summary["achieved_gbps"],
-                    hbm_util=summary["hbm_util"])
+                    hbm_util=summary.get("hbm_util"))
             return summary
         except Exception as exc:  # noqa: BLE001 — telemetry never raises
             self.roofline_enabled = False

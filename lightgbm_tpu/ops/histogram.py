@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils import log
+from ..utils.backend import on_tpu, pallas_interpret
 
 
 def _gh1(grad, hess, mask, dtype):
@@ -150,12 +151,12 @@ def leaf_histogram(bins, grad, hess, leaf_ids, leaf,
             from . import histogram_pallas
             return histogram_pallas.leaf_histogram(
                 bins, grad, hess, leaf_ids, leaf, max_bin,
-                interpret=jax.default_backend() != "tpu")
+                interpret=pallas_interpret())
         log.warning("Pallas histogram kernel needs uint8 bins and "
                     "max_bin <= 256; falling back to onehot")
         impl = "onehot"
     if impl == "auto":
-        impl = "compact" if jax.default_backend() == "tpu" else "scatter"
+        impl = "compact" if on_tpu() else "scatter"
     if impl == "scatter":
         return leaf_histogram_scatter(bins, grad, hess, leaf_ids, leaf, max_bin)
     if impl == "onehot":

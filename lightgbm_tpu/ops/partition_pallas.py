@@ -74,9 +74,8 @@ def arena_channels(num_features: int) -> int:
 def arena_geometry(num_data: int, num_features: int,
                    factor: int = 3) -> tuple:
     """(C, cap) of the arena for a dataset — the SINGLE sizing formula
-    shared by GBDT._setup_tree_engine and the driver compile check
-    (__graft_entry__.entry), so the compile check always exercises the
-    same shapes real training uses.  `factor` multiples of the row
+    shared by GBDT._setup_tree_engine and the parallel growers
+    (parallel/learners.py).  `factor` multiples of the row
     footprint cover root + OOB dump + bump-allocated child segments
     (pristine layout: pristine bins + root copy + dump + bump -> pass
     factor >= 4); the 16-tile tail is kernel read-overrun headroom."""
@@ -150,12 +149,9 @@ def _align8(rows: int) -> int:
 
 
 def _side_effect_params():
-    """pltpu.CompilerParams(has_side_effects=True) where available.
-    CPU-only jax builds lack the attribute; interpret-mode tests of the
-    side-effecting kernels then run without compiler params (interpret
-    mode ignores them anyway)."""
-    cp = getattr(pltpu, "CompilerParams", None)
-    return cp(has_side_effects=True) if cp is not None else None
+    """The kernels that write HBM through manual DMAs must not be
+    dead-code-eliminated or reordered as pure functions."""
+    return pltpu.CompilerParams(has_side_effects=True)
 
 
 def split_rowid(r):
@@ -856,7 +852,7 @@ def _comp_chunks(hi_n: int, m: int, payload: int = 7):
 
 def _radix_accumulate(out_ref, block, mask, *, n_blocks: int, k: int,
                       m: int, lo_n: int, hi_n: int, tile: int,
-                      payload: int = 7):
+                      payload: int = 7, planes=None):
     """Accumulate the radix-factorized split-payload histogram of `block`
     [C, tile] bf16 rows selected by `mask` [1, tile] bf16 (0/1) into
     out_ref [n_blocks*k*payload*hi_n*m, lo_n*m] f32 — the shared inner
@@ -864,15 +860,21 @@ def _radix_accumulate(out_ref, block, mask, *, n_blocks: int, k: int,
     partition/refresh+histogram passes.  payload=7 is the f32-exact mode
     (6 residue planes + count); payload=3 is the quantized mode (int8
     g/h codes + count — the accumulator then holds exact integer code
-    sums, see ops/quantize)."""
+    sums, see ops/quantize).  The payload planes are the block's rows
+    after the feature rows, or `planes` (payload-1 rows of [1, tile]
+    bf16) when the caller holds them apart from the feature rows."""
     N = lo_n * m
     Mc = payload * hi_n * m
     f_blk = k * m
     chunks = _comp_chunks(hi_n, m, payload)
     Fp = n_blocks * f_blk
-    # payload planes after the feature rows; masking by 0/1 keeps every
-    # entry a bf16-exact plane value (residue planes or int8 codes)
-    comps = [block[Fp + i:Fp + i + 1, :] * mask for i in range(payload - 1)]
+    # masking by 0/1 keeps every entry a bf16-exact plane value (residue
+    # planes or int8 codes)
+    if planes is None:
+        comps = [block[Fp + i:Fp + i + 1, :] * mask
+                 for i in range(payload - 1)]
+    else:
+        comps = [p * mask for p in planes]
     comps.append(mask)
     gh = jnp.concatenate(comps, axis=0)               # [payload, T] bf16
 
@@ -1033,35 +1035,49 @@ def segment_histogram(arena, start, cnt, num_features: int, max_bin: int,
     return hist[:F, :max_bin, :]
 
 
+# rows of the arena's payload group the fused root kernel rewrites: Mosaic
+# (libtpu 0.0.34) refuses a DMA slice of a tiled memref whose sublane
+# extent is not a multiple of 8, so the two code planes cannot be written
+# alone
+_PAY_ROWS = 8
+
+
 def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
-                       in_buf, code_buf, read_sems, code_sems, write_sems,
+                       in_buf, code_buf, pay_buf, read_sems, code_sems,
+                       pay_sems, write_sems,
                        *, n_blocks: int, k: int, m: int, lo_n: int,
                        hi_n: int, tile: int):
     """Fused per-tree g/h-plane refresh + root histogram over ONE arena
-    pass (quantized mode): per tile, DMA in the feature rows and the
-    fresh code tile, DMA the codes OUT to the arena's payload planes
-    (dynamic-destination HBM DMA — legal, unlike dynamic-offset VMEM
-    stores in a fori_loop), and accumulate the 3-component radix
+    pass (quantized mode): per tile, DMA in the feature rows, the fresh
+    code tile and the arena's 8-row payload group [Fp, Fp+8); put the
+    codes into the group's first two rows in VMEM and DMA the group back
+    (dynamic-destination HBM DMA); accumulate the 3-component radix
     histogram from the values already in VMEM.
 
+    The payload group is read, merged and written whole because a 2-row
+    DMA slice is not legal (_PAY_ROWS): its rows 2..5 are the stale
+    residue planes quantized mode never reads, rows 6..7 are the rowid
+    hi/mid byte planes, which go back as they came.
+
     This replaces the XLA plane update + separate segment_histogram
-    launch of the separate-pass schedule: the root segment's rows are
-    read ONCE (features only — the stale payload planes never leave
-    HBM), and the fresh codes are touched once on the way in instead of
-    write-then-re-read.  Naive per-CHILD fusion was measured ~10% worse
-    (see grow_partition's dead-end note); the root is different — its
-    histogram covers every row of a segment the refresh must stream
-    anyway, so the fusion is pure saving, exactly like the bagging root
+    launch of the separate-pass schedule: the root segment's feature
+    rows are read ONCE, and the fresh codes are touched once on the way
+    in instead of write-then-re-read.  Naive per-CHILD fusion was
+    measured ~10% worse (see grow_partition's dead-end note); the root
+    is different — its histogram covers every row of a segment the
+    refresh must stream anyway, exactly like the bagging root
     partition's hist_stream.
 
     sc_ref (SMEM [2] i32): start, cnt.  codes_any [2, n_al] bf16 code
     planes in segment order; arena_any/out_any [C, cap] bf16 aliased;
     hist_ref VMEM [n_blocks*k*3*hi_n*m, lo_n*m] f32.
 
-    Write-DMA discipline: write j uses sem slot j%2; it is waited at
-    iteration j+1 (before the slot's buffer is refilled for tile j+2),
-    and the final two writes are drained after the loop — strict per-slot
-    alternation, no global counters.
+    Write-DMA discipline: write j leaves pay_buf slot j%2; it is waited
+    at iteration j+1 (before that slot is refilled for tile j+2), and
+    the final two writes are drained after the loop — strict per-slot
+    alternation, no global counters.  Tiles are column-disjoint, so the
+    read of tile j+1 never races the write of tile j in the aliased
+    buffer.
     """
     s, cnt = sc_ref[0], sc_ref[1]
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
@@ -1079,21 +1095,33 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
             codes_any.at[:, pl.ds(src, tile)],
             code_buf.at[slot], code_sems.at[slot])
 
-    def code_write_dma(j, slot):
+    def pay_read_dma(j, slot):
+        src = pl.multiple_of(s + j * tile, 128)
+        return pltpu.make_async_copy(
+            arena_any.at[pl.ds(Fp, _PAY_ROWS), pl.ds(src, tile)],
+            pay_buf.at[slot], pay_sems.at[slot])
+
+    def pay_write_dma(j, slot):
         dst = pl.multiple_of(s + j * tile, 128)
         return pltpu.make_async_copy(
-            code_buf.at[slot],
-            out_any.at[pl.ds(Fp, 2), pl.ds(dst, tile)],
+            pay_buf.at[slot],
+            out_any.at[pl.ds(Fp, _PAY_ROWS), pl.ds(dst, tile)],
             write_sems.at[slot])
+
+    def reads(j, slot):
+        return feat_dma(j, slot), code_read_dma(j, slot), \
+            pay_read_dma(j, slot)
 
     hist_ref[:] = jnp.zeros_like(hist_ref)
 
     @pl.when(n_tiles > 0)
     def _():
-        feat_dma(0, 0).start()
-        code_read_dma(0, 0).start()
-        feat_dma(0, 0).wait()
-        code_read_dma(0, 0).wait()
+        for d in reads(0, 0):
+            d.start()
+        for d in reads(0, 0):
+            d.wait()
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (_PAY_ROWS, tile), 0)
 
     def loop(j, _):
         slot = jax.lax.rem(j, jnp.int32(2))
@@ -1102,25 +1130,36 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
         @pl.when(j + 1 < n_tiles)
         def _():
             # nslot's outbound write (issued at j-1) must land before the
-            # slot's code buffer is refilled
+            # slot's payload buffer is refilled
             @pl.when(j >= 1)
             def _():
-                code_write_dma(0, nslot).wait()
-            feat_dma(j + 1, nslot).start()
-            code_read_dma(j + 1, nslot).start()
+                pay_write_dma(0, nslot).wait()
+            for d in reads(j + 1, nslot):
+                d.start()
 
-        code_write_dma(j, slot).start()
+        # codes over the group's rows 0..1, in f32: the values are small
+        # integers and byte planes, exact either way, and 32-bit selects
+        # are the ones Mosaic lowers without a relayout
+        cod = code_buf[slot].astype(jnp.float32)          # [2, T]
+        merged = jnp.where(
+            row == 0, cod[0:1, :],
+            jnp.where(row == 1, cod[1:2, :],
+                      pay_buf[slot].astype(jnp.float32)))
+        pay_buf[slot] = merged.astype(ARENA_DT)
+        pay_write_dma(j, slot).start()
 
-        block = jnp.concatenate([in_buf[slot], code_buf[slot]], axis=0)
         valid = (jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
                  < (cnt - j * tile)).astype(jnp.bfloat16)
-        _radix_accumulate(hist_ref, block, valid, n_blocks=n_blocks, k=k,
-                          m=m, lo_n=lo_n, hi_n=hi_n, tile=tile, payload=3)
+        _radix_accumulate(hist_ref, in_buf[slot], valid, n_blocks=n_blocks,
+                          k=k, m=m, lo_n=lo_n, hi_n=hi_n, tile=tile,
+                          payload=3,
+                          planes=(cod[0:1, :].astype(jnp.bfloat16),
+                                  cod[1:2, :].astype(jnp.bfloat16)))
 
         @pl.when(j + 1 < n_tiles)
         def _():
-            feat_dma(j + 1, nslot).wait()
-            code_read_dma(j + 1, nslot).wait()
+            for d in reads(j + 1, nslot):
+                d.wait()
         return 0
 
     jax.lax.fori_loop(0, n_tiles, loop, 0)
@@ -1129,11 +1168,11 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
     # in-loop wait is skipped on the last iteration)
     @pl.when(n_tiles >= 2)
     def _():
-        code_write_dma(0, jax.lax.rem(n_tiles - 2, jnp.int32(2))).wait()
+        pay_write_dma(0, jax.lax.rem(n_tiles - 2, jnp.int32(2))).wait()
 
     @pl.when(n_tiles >= 1)
     def _():
-        code_write_dma(0, jax.lax.rem(n_tiles - 1, jnp.int32(2))).wait()
+        pay_write_dma(0, jax.lax.rem(n_tiles - 1, jnp.int32(2))).wait()
 
 
 @functools.partial(jax.jit,
@@ -1157,6 +1196,9 @@ def fused_refresh_histogram(arena, codes, start, cnt, num_features: int,
     if n_blocks * f_blk + N_AUX > C:
         raise ValueError("arena channels too small for feature layout")
     Fp = n_blocks * f_blk
+    # the rewritten group [Fp, Fp+8) holds the code planes, the stale
+    # residue planes and the rowid hi/mid planes: all inside N_AUX
+    assert _PAY_ROWS <= N_AUX and Fp % _PAY_ROWS == 0
     Mc, N = 3 * hi_n * m, lo_n * m
     n = codes.shape[1]
     n_al = -(-n // tile) * tile
@@ -1178,6 +1220,8 @@ def fused_refresh_histogram(arena, codes, start, cnt, num_features: int,
         scratch_shapes=[
             pltpu.VMEM((2, Fp, tile), ARENA_DT),
             pltpu.VMEM((2, 2, tile), ARENA_DT),
+            pltpu.VMEM((2, _PAY_ROWS, tile), ARENA_DT),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -1244,7 +1288,8 @@ def _cost_fused_root(rows: int, features: int, max_bin: int) -> KernelCost:
     schedule's plane update (read codes + write planes) AND the full
     arena row stripe of the f32 root segment_histogram."""
     n, F, B = int(rows), int(features), int(max_bin)
-    row_b = _ARENA_B * (feature_channels(F) + 2 + 2)   # feats + code r/w
+    # feature rows + code read + the payload group read and written back
+    row_b = _ARENA_B * (feature_channels(F) + 2 + 2 * _PAY_ROWS)
     return KernelCost("partition/fused_root",
                       n * row_b + F * B * 3 * 4, 3 * n * F,
                       "one fused pass, %dB/row vs %dB separate"

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.backend import pallas_interpret
 from .split import K_EPSILON, K_MIN_SCORE, PerFeatureSplit, SplitParams
 
 NEG = -1e38        # in-kernel "no split" sentinel (python float: a
@@ -416,9 +417,8 @@ def scan_single(hist, sum_g, sum_h, cnt, params: SplitParams,
     and voting scans in ops/grow.py — the two call sites must stay
     bit-identical (voting elects against serial gains) so the argument
     massaging lives HERE once."""
-    import jax as _jax
     if interpret is None:
-        interpret = _jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if fvec_pre is not None:
         fvec = fvec_pre
     else:

@@ -52,21 +52,11 @@ from ..utils import log
 #: the 1-D model-parallel mesh axis every learner shard_maps over
 AXIS = "mp"
 
-# jax moved shard_map out of experimental (and renamed check_rep to
-# check_vma) across the versions this repo meets; resolve once here so
-# every build site works on either spelling
-try:
-    from jax import shard_map as _shard_map
-    _SHARD_CHECK_KW = "check_vma"
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_CHECK_KW = "check_rep"
-
-
 def shard_mapped(fn, mesh, in_specs, out_specs):
-    """shard_map under either jax spelling (see _SHARD_CHECK_KW above)."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SHARD_CHECK_KW: False})
+    """jax.shard_map with the varying-manual-axes check off (the grow
+    programs mix replicated and sharded values freely)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------------- #
@@ -516,10 +506,7 @@ def _mesh_devices_available() -> int:
     chaos = os.environ.get("LGBM_TPU_CHAOS", "")
     if chaos.split(":")[0] == "mesh_unavailable":
         return 0
-    try:
-        return jax.device_count()
-    except Exception:  # noqa: BLE001 — no backend at all
-        return 0
+    return jax.device_count()
 
 
 def resolve_backend(config) -> str:

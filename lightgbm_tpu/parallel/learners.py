@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import grow as grow_ops
 from ..utils import log
+from ..utils.backend import pallas_interpret
 from . import collective as coll_mod
 from .collective import AXIS  # noqa: F401 — canonical home moved there
 from .collective import shard_mapped as _shard_mapped
@@ -98,7 +99,7 @@ class ParallelGrower:
         # partition (arena) engine fast path — opted in by the GBDT
         # driver when the dataset is eligible (f32, max_bin<=256, n<2^24,
         # no forced splits); all three modes run on it, the label engine
-        # stays as the fully-general fallback
+        # serves the configs that are not eligible
         self._partition = None
         self._pcache = {}
         self._arena = None
@@ -114,8 +115,15 @@ class ParallelGrower:
         self._audited = set()
 
     # ------------------------------------------------------------------ #
-    def enable_partition(self, hist_slots: int = 0):
-        self._partition = dict(hist_slots=hist_slots)
+    def enable_partition(self, arena_factor: int, hist_slots: int = 0):
+        """arena_factor: local arena columns per local row (config
+        tpu_arena_factor).  The root compaction takes 2n, the rest is the
+        bump region; under row sharding the overflow bound is the LOCAL
+        PARENT size, so at the sizing minimum of 3 a shard of millions
+        of rows stops at 3 leaves (four-chip run, PR 21 — the 16-tile
+        tail hides it at test sizes)."""
+        self._partition = dict(hist_slots=hist_slots,
+                               arena_factor=arena_factor)
 
     def disable_partition(self):
         self._partition = None
@@ -173,30 +181,14 @@ class ParallelGrower:
                              "EFB-bundled datasets")
         d = self.d
         if self._partition is not None:
-            try:
-                return self._call_partition(
-                    bins, grad, hess, row_leaf_init, feature_mask,
-                    num_bins, default_bins, missing_types, params,
-                    monotone, penalty, is_categorical, bundle,
-                    max_leaves=max_leaves, max_depth=max_depth,
-                    max_bin=max_bin, max_cat_threshold=max_cat_threshold,
-                    quantized=quantized, quant_scales=quant_scales)
-            except Exception as exc:
-                from ..resilience.comm import WorldChangedError
-                if isinstance(exc, WorldChangedError):
-                    raise          # elastic fence — never degrade past it
-                if (self.mesh is None or quantized
-                        or self.collective.backend == "hybrid"):
-                    # socket/hybrid worlds and quantized codes have no
-                    # label-engine equivalent; the driver owns the
-                    # fallback
-                    raise
-                log.warning(
-                    "partition engine failed under %s-parallel (%s: %s); "
-                    "falling back to the label engine for this grower",
-                    self.mode, type(exc).__name__,
-                    str(exc).split("\n")[0][:200])
-                self.disable_partition()
+            # the engine was chosen at setup; a failure here propagates
+            return self._call_partition(
+                bins, grad, hess, row_leaf_init, feature_mask,
+                num_bins, default_bins, missing_types, params,
+                monotone, penalty, is_categorical, bundle,
+                max_leaves=max_leaves, max_depth=max_depth,
+                max_bin=max_bin, max_cat_threshold=max_cat_threshold,
+                quantized=quantized, quant_scales=quant_scales)
         if quantized:
             raise RuntimeError("quantized codes require the partition "
                                "engine; it is not enabled on this grower")
@@ -287,14 +279,10 @@ class ParallelGrower:
                 return jax.sharding.NamedSharding(self.mesh, spec)
             jit_kw = dict(in_shardings=tuple(_ns(s) for s in in_specs),
                           out_shardings=tuple(_ns(s) for s in out_specs))
-        # donate_argnums=(0,): the arena is the ONLY donatable input.
-        # bins_t / grad / hess / row_leaf_init look like candidates but
-        # are semantically resident: bins_t and the bag mask persist
-        # across rounds, and grad/hess are re-used by BOTH degrade paths
-        # after a failed call (the quantized retry in gbdt._grow_tree and
-        # the label-engine fallback in grow()) — donating them would
-        # hand those paths deleted buffers on a real TPU.  The donation
-        # audit marks them resident instead of un-donated.
+        # donate_argnums=(0,): the arena is the ONLY donated input.
+        # bins_t and the bag mask persist across rounds; grad/hess are
+        # the caller's (the driver's score update may still read them).
+        # The donation audit marks them resident instead of un-donated.
         fn = jax.jit(_shard_mapped(shard_fn, self.mesh, in_specs,
                                    out_specs),
                      donate_argnums=(0,), **jit_kw)
@@ -386,7 +374,8 @@ class ParallelGrower:
         n_pad, F_pad = n + pad_r, F + pad_f
         n_loc = n_pad // d if row_shard else n_pad
         G_pad = G + pad_f                  # G == F for FP (no EFB)
-        C, cap = pp.arena_geometry(n_loc, G_pad)
+        C, cap = pp.arena_geometry(n_loc, G_pad,
+                                   self._partition["arena_factor"])
 
         # the key holds a STRONG reference to the bins array: a bare
         # id() could be recycled after a dataset swap + GC, silently
@@ -420,7 +409,7 @@ class ParallelGrower:
             if is_categorical is not None:
                 is_categorical = jnp.pad(is_categorical, (0, pad_f))
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         statics = (max_leaves, max_depth, max_bin, max_cat_threshold, C,
                    cap, self._partition["hist_slots"], interpret,
                    bool(quantized))
@@ -451,10 +440,8 @@ class ParallelGrower:
             # the collective byte accounting; post-call it is a cache hit
             from ..obs import device as obs_device
             # resident leaves 1-4: bins_t (dataset plane), grad/hess
-            # (reused by the quantized-retry and label-fallback degrade
-            # paths after a failed call), row_leaf_init (the bag mask,
-            # reused until the next bagging round) — donation is
-            # semantically impossible for all four.  call_args[0] was
+            # (the caller's), row_leaf_init (the bag mask, reused until
+            # the next bagging round).  call_args[0] was
             # donated into the call just made; lower with the
             # (identically-shaped) output arena instead
             obs_device.donation_audit(

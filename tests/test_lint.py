@@ -435,7 +435,7 @@ def test_parse_error_becomes_finding(tmp_path):
 # -- the CLI, without jax -------------------------------------------------
 
 def _cli(args, env_extra=None, poison_jax=True, tmp_path=None):
-    """Run tools/lint.py in a subprocess with -S (no sitecustomize) and
+    """Run tools/lint.py in a subprocess with -S (no site imports) and
     a poisoned `jax` module on PYTHONPATH: any jax import anywhere in
     the lint path explodes loudly."""
     env = dict(os.environ)

@@ -251,6 +251,26 @@ def test_end_to_end_partition_parallel(rng, mode):
     assert np.mean(np.abs(ps - pp)) < 0.02
 
 
+def test_parallel_grower_arena_uses_configured_factor(rng):
+    """The shard-local arenas are sized from tpu_arena_factor like the
+    serial arena, not from arena_geometry's minimum of 3 — at 3 the
+    row-sharded overflow bound (the local PARENT size) stops a shard of
+    millions of rows at 3 leaves, which the 16-tile tail hides at test
+    sizes (four-chip run, PR 21)."""
+    from lightgbm_tpu.ops import partition_pallas as pp_mod
+    n, d = 512, 4
+    X = rng.randn(n, 6)
+    y = (X[:, 0] > 0).astype(float)
+    par = lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1,
+                     "min_data_in_leaf": 5, "tree_learner": "data",
+                     "num_machines": d, "tpu_tree_engine": "partition",
+                     "tpu_arena_factor": 8},
+                    lgb.Dataset(X, y), num_boost_round=1)
+    g = par._gbdt._grower
+    assert g._partition is not None
+    assert g._arena.shape == (d,) + pp_mod.arena_geometry(n // d, 6, 8)
+
+
 def test_partition_engine_data_parallel(rng):
     """The partition (arena) engine under shard_map with rows sharded:
     psum'd histograms must reproduce the serial partition trees."""

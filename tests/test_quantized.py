@@ -323,13 +323,23 @@ class TestByteFloors:
                        max_bin=255)
         assert kq.hbm_bytes <= 0.55 * kf.hbm_bytes
 
-    def test_fused_root_below_separate_passes(self):
-        # fusing the code refresh into the root histogram must beat the
-        # two-pass alternative (write planes, then re-read the arena)
+    def test_fused_root_is_one_stripe_pass(self):
+        # the fused root reads the same 40-row stripe the quantized
+        # segment histogram reads (feature rows + the payload group),
+        # plus the fresh codes in and the 8-row payload group back out —
+        # Mosaic takes no 2-row DMA slice, so the group is rewritten
+        # whole (partition_pallas._PAY_ROWS).  Still one pass over the
+        # rows where the f32 schedule makes two (refresh six residue
+        # planes, then stream the full arena row).
         from lightgbm_tpu.obs import perf
+        from lightgbm_tpu.ops import partition_pallas as pp
         perf.cost_models()
-        fused = perf.cost("partition/fused_root", rows=4_194_304,
-                          features=28, max_bin=255)
-        hist = perf.cost("partition/hist_quantized", rows=4_194_304,
-                         features=28, max_bin=255)
-        assert fused.hbm_bytes < hist.hbm_bytes + 4_194_304 * 2 * 2
+        n = 4_194_304
+        fused = perf.cost("partition/fused_root", rows=n, features=28,
+                          max_bin=255)
+        hist_q = perf.cost("partition/hist_quantized", rows=n, features=28,
+                           max_bin=255)
+        hist_f = perf.cost("partition/hist", rows=n, features=28,
+                           max_bin=255)
+        assert fused.hbm_bytes == hist_q.hbm_bytes + n * 2 * (2 + pp._PAY_ROWS)
+        assert fused.hbm_bytes < hist_f.hbm_bytes + n * 2 * 6

@@ -170,35 +170,36 @@ class TestForcedSplitAbandonment:
         assert int(np.asarray(tree.right_child)[0]) == 1
 
 
-class TestEngineFallback:
-    def test_partition_failure_falls_back_to_label(self, monkeypatch):
-        """A lowering/runtime failure in the partition fast path must
-        degrade to the label engine with a warning, not kill training
-        (the round-2 bench crash mode).  Both partition entries are
-        broken: the fused single-dispatch iteration AND the plain
-        per-tree grow."""
+class TestEngineFailurePropagates:
+    def test_partition_failure_raises(self, monkeypatch):
+        """A lowering/runtime failure in the partition fast path raises
+        with its message — the fused single-dispatch iteration AND the
+        plain per-tree grow.  (It used to demote the booster to the label
+        engine with a warning; a booster that quietly changed engine gets
+        measured as something it is not.)"""
         from lightgbm_tpu.ops import grow_partition as gp_mod
         rng = np.random.default_rng(0)
         X = rng.normal(size=(500, 6)).astype(np.float32)
         y = (X[:, 0] > 0).astype(np.float32)
-        ds = lgb.Dataset(X, label=y)
-        bst = lgb.Booster(params={"objective": "binary", "verbose": -1,
-                                  "tpu_tree_engine": "partition"},
-                          train_set=ds)
 
         def boom(*a, **k):
             raise RuntimeError("simulated Mosaic lowering failure")
 
-        g = bst._gbdt
-        # the guard is only meaningful when the engine is actually active
-        assert g._use_partition_engine, "partition engine not selected"
-        monkeypatch.setattr(gp_mod, "grow_tree_partition_impl", boom)
-        monkeypatch.setattr(gp_mod, "grow_tree_partition", boom)
-        g._grow_partition = boom
-        for _ in range(2):
-            bst.update()
-        assert bst.num_trees() == 2
-        assert not g._use_partition_engine
+        for extra in ({}, {"bagging_fraction": 0.8, "bagging_freq": 1}):
+            bst = lgb.Booster(params=dict({"objective": "binary",
+                                           "verbose": -1,
+                                           "tpu_tree_engine": "partition"},
+                                          **extra),
+                              train_set=lgb.Dataset(X, label=y))
+            g = bst._gbdt
+            assert g._use_partition_engine, "partition engine not selected"
+            monkeypatch.setattr(gp_mod, "grow_tree_partition_impl", boom)
+            monkeypatch.setattr(gp_mod, "grow_tree_partition", boom)
+            g._grow_partition = boom
+            with pytest.raises(RuntimeError, match="simulated Mosaic"):
+                bst.update()
+            assert g._use_partition_engine     # not demoted either
+            monkeypatch.undo()
 
 
 class TestResetTrainingDataInvalidatesFusedTrace:

@@ -16,10 +16,11 @@ Run standalone (prints one JSON line) or via bench.py's
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         JAX_PLATFORMS=cpu python tools/mesh_bench.py --rows 4096
 
-Off-TPU the numbers are a smoke (interpret-mode kernels), but the
+Off-TPU the numbers are a smoke (interpret-mode kernels) that shows the
 scaling STRUCTURE — every world size trains, quantized_active stays
-true, the mesh backend engages — is exactly what MULTICHIP_r10.json
-records.
+true, the mesh backend engages — and nothing about speed; the output
+names the backend it ran on.  A world larger than the device count is
+an error, never a silently smaller sweep.
 """
 from __future__ import annotations
 
@@ -61,9 +62,15 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
     from lightgbm_tpu.obs import scaling as obs_scaling
     from lightgbm_tpu.utils import log as lgb_log
 
-    lgb_log.set_level(-1)
+    lgb_log.set_level(0)        # warnings on: an engine change says so
     n_dev = jax.device_count()
-    worlds = [w for w in worlds if w <= n_dev]
+    too_big = [w for w in worlds if w > n_dev]
+    if too_big:
+        raise ValueError(
+            "world size(s) %s exceed the %d %s device(s) visible; ask for "
+            "worlds that fit (or, on the CPU, set XLA_FLAGS="
+            "--xla_force_host_platform_device_count)"
+            % (too_big, n_dev, jax.default_backend()))
     rng = np.random.RandomState(7)
     X = rng.randn(n_rows, n_features).astype(np.float32)
     wvec = rng.randn(n_features)
@@ -103,12 +110,14 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
             float(jax.numpy.sum(g.train_state.score))
             dt = time.perf_counter() - t0
             g.finish_telemetry()
+            g._sync_model()
             decs = _read_decomps(tel_path)[1:]  # drop the compile round
             try:
                 os.remove(tel_path)
             except OSError:
                 pass
             grower = g._grower
+            mem = [d.memory_stats() or {} for d in jax.local_devices()]
             engine_on = (grower._partition is not None if grower is not None
                          else g._use_partition_engine)
             key = "w%d_%s" % (world, "int8" if quant else "f32")
@@ -119,8 +128,19 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                 "elapsed_s": round(dt, 3),
                 "quantized_active": bool(getattr(g, "_quantized", False)),
                 "engine": "partition" if engine_on else "label",
+                # a truncated tree is a smaller model than the one asked
+                # for: its time is not this configuration's time
+                "last_tree_leaves": int(g.models[-1].num_leaves),
+                "truncated": bool(g._truncation_warned),
                 "comm_backend": (grower.collective.backend
                                  if grower is not None else "serial"),
+                # one entry per local device: a sharded arena shows as
+                # `world` devices holding a share each (None where the
+                # backend reports no memory stats, i.e. the CPU)
+                "device_bytes_in_use": [m.get("bytes_in_use") for m in mem],
+                # high-water mark of the process so far, not of this run
+                "device_peak_bytes_in_use": [m.get("peak_bytes_in_use")
+                                             for m in mem],
             }
             mean = obs_scaling.mean_decomposition(decs)
             if mean is not None:
@@ -142,6 +162,8 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                     sync_events=sum(int(d.get("sync_events", 0))
                                     for d in decs),
                 )
+            # the next run's arena must not sit next to this one's
+            del booster, g, grower, ds
     # scaling efficiency against the world=1 run of the same dtype
     for kind in ("f32", "int8"):
         base = out["runs"].get("w1_%s" % kind)
@@ -153,11 +175,14 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                 speedup = r["mrows_iter_s"] / base["mrows_iter_s"]
                 r["speedup"] = round(speedup, 3)
                 r["efficiency"] = round(speedup / world, 3)
-    top = out["runs"].get("w%d_int8" % max(worlds)) or {}
-    out["mesh8_mrows_iter_s"] = top.get("mrows_iter_s")
-    out["mesh8_quantized_active"] = top.get("quantized_active")
-    out["mesh8_f32_speedup"] = (out["runs"].get("w%d_f32" % max(worlds))
-                                or {}).get("speedup")
+    # headline keys carry the world they were taken at: mesh8_* exists
+    # only when world 8 ran
+    w_top = max(worlds)
+    top = out["runs"].get("w%d_int8" % w_top) or {}
+    out["mesh%d_mrows_iter_s" % w_top] = top.get("mrows_iter_s")
+    out["mesh%d_quantized_active" % w_top] = top.get("quantized_active")
+    out["mesh%d_f32_speedup" % w_top] = (
+        out["runs"].get("w%d_f32" % w_top) or {}).get("speedup")
     return out
 
 
@@ -175,7 +200,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    on_tpu = jax.default_backend() == "tpu"
+    from lightgbm_tpu.utils.backend import on_tpu as _on_tpu
+    on_tpu = _on_tpu()
     worlds = sorted({int(w) for w in args.worlds.split(",")})
     rows = args.rows if args.rows else (2_000_000 if on_tpu else 4096)
     iters = args.iters if args.iters else (50 if on_tpu else 2)

@@ -160,7 +160,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    on_tpu = jax.default_backend() == "tpu"
+    from lightgbm_tpu.utils.backend import on_tpu as _on_tpu
+    on_tpu = _on_tpu()
     worlds = sorted({int(w) for w in
                      (args.worlds or ("1,2,4,8" if on_tpu else "1,2,4")
                       ).split(",")})

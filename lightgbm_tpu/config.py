@@ -790,6 +790,14 @@ class Config:
 
     def set(self, params: Dict[str, Any]) -> None:
         params = alias_transform(params)
+        if params.get("verbosity") is not None:
+            # the log level follows an explicit verbosity as the params
+            # are parsed, like the reference's Config::Set
+            # (src/io/config.cpp): < 0 fatal only, 0 warnings, 1 info,
+            # > 1 debug.  First, so that what the rest of set() and the
+            # Dataset / Booster built from this Config log is routed by it
+            log.set_level(max(log.FATAL, min(
+                _coerce("verbosity", int, params["verbosity"]), log.DEBUG)))
         for k, v in params.items():
             if k in PARAMETER_SET and v is not None:
                 setattr(self, k, _coerce(k, PARAMETER_TYPES[k], v))

@@ -440,11 +440,12 @@ class GBDT:
         F = self.train_set.num_features
         if frac >= 1.0 or F == 0:
             return self._feature_mask_all
-        used = max(1, int(round(F * frac)))
-        idx = self._feat_rng.choice(F, used, replace=False)
-        mask = np.zeros(F, bool)
-        mask[idx] = True
-        return jnp.asarray(mask)
+        with obs_tracing.span("feature_sample", "train"):
+            used = max(1, int(round(F * frac)))
+            idx = self._feat_rng.choice(F, used, replace=False)
+            mask = np.zeros(F, bool)
+            mask[idx] = True
+            return jnp.asarray(mask)
 
     # ------------------------------------------------------------------ #
     # One boosting iteration (gbdt.cpp:333-412)
@@ -720,15 +721,16 @@ class GBDT:
             olds = [getattr(h, a) for h, a in fields]
             for (h, a), v in zip(fields, field_vals):
                 setattr(h, a, v)
+            n = score.shape[1]
             try:
-                grad, hess = objective.get_gradients(
-                    score if k > 1 else score[0])
+                with jax.named_scope("lgbm.gradient"):
+                    grad, hess = objective.get_gradients(
+                        score if k > 1 else score[0])
+                    grad = jnp.asarray(grad, jnp.float32).reshape(k, n)
+                    hess = jnp.asarray(hess, jnp.float32).reshape(k, n)
             finally:
                 for (h, a), v in zip(fields, olds):
                     setattr(h, a, v)
-            n = score.shape[1]
-            grad = jnp.asarray(grad, jnp.float32).reshape(k, n)
-            hess = jnp.asarray(hess, jnp.float32).reshape(k, n)
             ivecs, fvecs, deltas = [], [], []
             for kk in range(k):
                 g_in, h_in, qsc = grad[kk], hess[kk], None
@@ -753,12 +755,14 @@ class GBDT:
                     forced_splits=self._forced_splits,
                     pristine=True, quantized=quantized,
                     quant_scales=qsc, interpret=interpret)
-                ivec, fvec = grow_ops.pack_tree_arrays(arrays)
-                ivecs.append(jnp.concatenate(
-                    [ivec, trunc.astype(jnp.int32)[None]]))
+                with jax.named_scope("lgbm.finish"):
+                    ivec, fvec = grow_ops.pack_tree_arrays(arrays)
+                    ivecs.append(jnp.concatenate(
+                        [ivec, trunc.astype(jnp.int32)[None]]))
                 fvecs.append(fvec)
                 deltas.append(delta.astype(score.dtype))
-            new_score = score + shrink * jnp.stack(deltas)
+            with jax.named_scope("lgbm.score"):
+                new_score = score + shrink * jnp.stack(deltas)
             return ivecs, fvecs, new_score, arena
 
         return jax.jit(fused, donate_argnums=(0, 2))
@@ -921,20 +925,21 @@ class GBDT:
             for (h, a), v in zip(fields_io, field_vals):
                 setattr(h, a, v)
             try:
-                score = merge(jax.lax.dynamic_slice(
-                    arena, (jnp.int32(base), root0), (3, n)))
-                off = base + 3
-                fields = []
-                for np_ in n_planes:
-                    fields.append(merge(jax.lax.dynamic_slice(
-                        arena, (jnp.int32(off), root0), (np_, n))))
-                    off += np_
-                grad, hess = objective.carry_gradients(score, fields)
+                with jax.named_scope("lgbm.gradient"):
+                    score = merge(jax.lax.dynamic_slice(
+                        arena, (jnp.int32(base), root0), (3, n)))
+                    off = base + 3
+                    fields = []
+                    for np_ in n_planes:
+                        fields.append(merge(jax.lax.dynamic_slice(
+                            arena, (jnp.int32(off), root0), (np_, n))))
+                        off += np_
+                    grad, hess = objective.carry_gradients(score, fields)
+                    g_in = jnp.asarray(grad, jnp.float32)
+                    h_in = jnp.asarray(hess, jnp.float32)
             finally:
                 for (h, a), v in zip(fields_io, olds):
                     setattr(h, a, v)
-            g_in = jnp.asarray(grad, jnp.float32)
-            h_in = jnp.asarray(hess, jnp.float32)
             qsc = None
             if quantized:
                 # grad/hess are in CARRIED (arena) row order here, and so
@@ -958,20 +963,23 @@ class GBDT:
                 quant_scales=qsc, interpret=interpret)
             # per-row leaf value over the compacted order (leaf-index
             # segments): boundary scatter + cumsum, no gather
-            lv = arrays.leaf_value.astype(jnp.float32)
-            lc = arrays.leaf_count
-            bounds = jnp.cumsum(lc)
-            diffs = jnp.zeros((n,), jnp.float32).at[0].add(lv[0])
-            diffs = diffs.at[bounds[:-1]].add(lv[1:] - lv[:-1],
-                                              mode="drop")
-            delta = jnp.cumsum(diffs)
-            sc_new = merge(jax.lax.dynamic_slice(
-                arena, (jnp.int32(base), dst), (3, n))) + shrink * delta
-            arena = jax.lax.dynamic_update_slice(
-                arena, jnp.stack(_pp.split_f32(sc_new)).astype(
-                    _pp.ARENA_DT), (jnp.int32(base), dst))
-            ivec, fvec = grow_ops.pack_tree_arrays(arrays)
-            ivec = jnp.concatenate([ivec, trunc.astype(jnp.int32)[None]])
+            with jax.named_scope("lgbm.score"):
+                lv = arrays.leaf_value.astype(jnp.float32)
+                lc = arrays.leaf_count
+                bounds = jnp.cumsum(lc)
+                diffs = jnp.zeros((n,), jnp.float32).at[0].add(lv[0])
+                diffs = diffs.at[bounds[:-1]].add(lv[1:] - lv[:-1],
+                                                  mode="drop")
+                delta = jnp.cumsum(diffs)
+                sc_new = merge(jax.lax.dynamic_slice(
+                    arena, (jnp.int32(base), dst), (3, n))) + shrink * delta
+                arena = jax.lax.dynamic_update_slice(
+                    arena, jnp.stack(_pp.split_f32(sc_new)).astype(
+                        _pp.ARENA_DT), (jnp.int32(base), dst))
+            with jax.named_scope("lgbm.finish"):
+                ivec, fvec = grow_ops.pack_tree_arrays(arrays)
+                ivec = jnp.concatenate(
+                    [ivec, trunc.astype(jnp.int32)[None]])
             return ivec, fvec, arena
 
         return jax.jit(fused, donate_argnums=(0,))
@@ -1034,8 +1042,10 @@ class GBDT:
                 return sv[None, :].astype(dtype)
 
             self._carry_mat_fn = mat
-        return self._carry_mat_fn(
-            self._arena, jnp.int32(self._carry_slots[self._carry_parity]))
+        with obs_tracing.span("materialize_score", "train"):
+            return self._carry_mat_fn(
+                self._arena,
+                jnp.int32(self._carry_slots[self._carry_parity]))
 
     def _rebuild_train_score(self):
         """Recompute training scores from the materialized model (a
@@ -1095,10 +1105,12 @@ class GBDT:
         lv = arrays.leaf_value * jnp.asarray(self.shrinkage_rate, self.dtype)
         lids = leaf_ids
         if self._bag_mask is not None:
-            walked = grow_ops.predict_leaf_inner(
-                self.train_state.bins, arrays, self.train_state.num_bins,
-                self.train_state.default_bins, self.train_state.bundle)
-            lids = jnp.where(lids >= 0, lids, walked)
+            with obs_tracing.span("oob_walk", "train"):
+                walked = grow_ops.predict_leaf_inner(
+                    self.train_state.bins, arrays,
+                    self.train_state.num_bins,
+                    self.train_state.default_bins, self.train_state.bundle)
+                lids = jnp.where(lids >= 0, lids, walked)
         self.train_state.score = self.train_state.score.at[class_id].add(
             lv[jnp.clip(lids, 0, arrays.max_leaves - 1)])
 
@@ -1609,7 +1621,8 @@ class GBDT:
     def _sync_model(self) -> None:
         """Materialize any deferred trees before the model is read; a stop
         detected here must still end training on the next update."""
-        with self.profiler.phase("drain_inflight"):
+        with obs_tracing.span("sync_model", "train"), \
+                self.profiler.phase("drain_inflight"):
             if self._drain_inflight():
                 self._deferred_stopped = True
 

@@ -10,12 +10,25 @@ straggler.  The reference's TIMETAG accumulators
 TPU-native upgrade: structured spans with monotonic clocks, emitted as
 Chrome trace-event JSON loadable in Perfetto / ``chrome://tracing``.
 
+One span path.  ``span(name)`` is the program's one span site
+(``utils.profiling.Profiler.phase`` gets its span here too).  Armed or
+not, a span enters ``jax.profiler.TraceAnnotation("lgbm:" + name)``: when
+a ``jax.profiler`` trace is being taken (``tpu_profile_trace_dir``, the
+benchmark's traced slice) the span is an event of the host plane of the
+``.xplane.pb``, on the same clock as the device's operations; when none
+is, the annotation does nothing.  When the tracer is armed
+(``tpu_trace_path``) the same span is also recorded here and written as
+Chrome JSON.  docs/Tracing.md lists the span names.
+
 Design contract (mirrors the recorder's):
 
-- ZERO-COST WHEN DISABLED: every public helper checks one attribute and
-  returns a shared ``nullcontext`` — no allocation, no lock, no clock
-  read.  Training output is bitwise-identical with tracing on or off
-  (tests/test_tracing.py asserts this, same guarantee as telemetry).
+- NOTHING OF THE TRACER WHEN DISABLED: every public helper checks one
+  attribute; a disabled ``span`` allocates nothing in the tracer, takes
+  no lock and reads no clock.  What is left is the one
+  ``TraceAnnotation`` (enter + exit measured at 0.4 us with no profiler
+  session, against 0.2 us for a ``nullcontext``).  Training output is
+  bitwise-identical with tracing on or off (tests/test_tracing.py
+  asserts this, same guarantee as telemetry).
 - THREAD-SAFE: spans nest per thread (thread-local stacks); the event
   buffer is lock-guarded because serving records from many HTTP worker
   threads and the XLA compile listener fires from whatever thread
@@ -45,8 +58,9 @@ import json
 import threading
 import time
 import uuid
-from contextlib import nullcontext
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from ..utils import log
 
@@ -56,7 +70,8 @@ SCHEMA_VERSION = 1
 # range from sub-ms host phases to multi-second compiles
 _SPAN_MS_BOUNDS = (0.05, 0.2, 1.0, 5.0, 20.0, 100.0, 500.0, 2000.0, 10000.0)
 
-_NULL_CM = nullcontext()
+#: what every span is called in a jax.profiler trace: "lgbm:" + its name
+ANNOTATION_PREFIX = "lgbm:"
 
 
 class _Span:
@@ -64,7 +79,7 @@ class _Span:
     thread's stack at enter, turned into a complete ('X') event at exit."""
 
     __slots__ = ("tracer", "name", "cat", "args", "span_id", "parent_id",
-                 "t0_us", "tid")
+                 "t0_us", "tid", "annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  args: Optional[Dict]):
@@ -72,8 +87,10 @@ class _Span:
         self.name = name
         self.cat = cat
         self.args = args
+        self.annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
 
     def __enter__(self) -> "_Span":
+        self.annotation.__enter__()
         tr = self.tracer
         stack = tr._stack()
         self.parent_id = stack[-1].span_id if stack else 0
@@ -107,6 +124,7 @@ class _Span:
                   "ts": self.t0_us, "dur": dur, "pid": tr.pid,
                   "tid": self.tid, "args": args})
         tr._observe_kind(self.cat or self.name, dur / 1e3)
+        self.annotation.__exit__(exc_type, exc, tb)
 
 
 class SpanTracer:
@@ -184,7 +202,7 @@ class SpanTracer:
     def span(self, name: str, cat: str = "",
              args: Optional[Dict] = None):
         if not self.enabled:
-            return _NULL_CM
+            return TraceAnnotation(ANNOTATION_PREFIX + name)
         return _Span(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
@@ -363,10 +381,10 @@ def configure_from_config(config) -> Optional[SpanTracer]:
 
 
 def span(name: str, cat: str = "", **args):
-    """Open a nested span on the current thread; a shared null context
-    when tracing is off (no allocation)."""
-    t = _tracer
-    return t.span(name, cat, args or None) if t.enabled else _NULL_CM
+    """Open a nested span on the current thread: an ``lgbm:<name>``
+    annotation in a jax.profiler trace, and a recorded span as well when
+    the tracer is armed."""
+    return _tracer.span(name, cat, args or None)
 
 
 def instant(name: str, cat: str = "", **args) -> None:

@@ -211,115 +211,118 @@ def grow_tree_partition_impl(
     if carried and (not full_bag or dist):
         raise ValueError("carried-arena mode requires full_bag serial")
     work0 = pp.pristine_work0(n) if pristine else 0
-    if quantized:
-        # TWO code planes at [Fp, Fp+2) (g_code, h_code as exact small
-        # integers in bf16); planes Fp+2..Fp+5 go stale and are never
-        # read — the 3-component radix stops at the count plane
-        gh = pp.pack_code_planes(grad, hess)
-    else:
-        gh = jnp.concatenate(
-            [c[None] for c in pp.split_f32(grad)]
-            + [c[None] for c in pp.split_f32(hess)], axis=0)
-    # full_bag quantized roots skip the XLA plane write entirely: the
-    # fused root kernel below DMAs the fresh codes into the arena while
-    # it streams the feature rows for the root histogram — one pass pays
-    # for both (the per-iteration byte saving iteration_budget reports)
-    fuse_root = quantized and full_bag
-    if carried:
-        # bins/rowids AND the score/label planes already sit at the
-        # carried root (compacted there by the previous tree's
-        # emit="carry"); only the g/h planes need this tree's gradients
-        arena = (arena_buf if fuse_root else
-                 jax.lax.dynamic_update_slice(
-                     arena_buf, gh, (jnp.int32(Fp),
-                                     jnp.asarray(carried_root, jnp.int32))))
-    elif pristine:
-        arena = (arena_buf if fuse_root else
-                 jax.lax.dynamic_update_slice(arena_buf, gh, (Fp, 0)))
-    else:
-        chans = [bins_t.astype(adt)]
-        if Fp > G:
-            chans.append(jnp.zeros((Fp - G, n), adt))
-        chans += [gh]
+    with jax.named_scope("lgbm.root"):
         if quantized:
-            # keep the rowid planes at their fixed rows Fp+6..Fp+8
-            chans.append(jnp.zeros((pp.N_AUX - 3 - gh.shape[0], n), adt))
-        chans += [c[None] for c in
-                  pp.split_rowid(jnp.arange(n, dtype=jnp.int32))]
-        if C > Fp + pp.N_AUX:
-            chans.append(jnp.zeros((C - Fp - pp.N_AUX, n), adt))
-        arena = jax.lax.dynamic_update_slice(
-            arena_buf, jnp.concatenate(chans, axis=0), (0, 0))
-
-    # ---- root: in-bag rows compacted into one segment --------------------
-    # decision-mode partition calls never read the pred stream; they get
-    # a tile-sized dummy (a [1, cap] buffer would be constant-sunk into
-    # the while loop and re-materialized every split)
-    pred_dummy = jnp.zeros((1, pp.TILE), dtype)
-    if full_bag:
-        # no bagging: every row is in-bag, the root segment IS the
-        # assembled prefix — skip the O(n) compaction pass and the
-        # OOB dump region entirely
-        root_c = jnp.int32(n)
+            # TWO code planes at [Fp, Fp+2) (g_code, h_code as exact small
+            # integers in bf16); planes Fp+2..Fp+5 go stale and are never
+            # read — the 3-component radix stops at the count plane
+            gh = pp.pack_code_planes(grad, hess)
+        else:
+            gh = jnp.concatenate(
+                [c[None] for c in pp.split_f32(grad)]
+                + [c[None] for c in pp.split_f32(hess)], axis=0)
+        # full_bag quantized roots skip the XLA plane write entirely: the
+        # fused root kernel below DMAs the fresh codes into the arena while
+        # it streams the feature rows for the root histogram — one pass pays
+        # for both (the per-iteration byte saving iteration_budget reports)
+        fuse_root = quantized and full_bag
         if carried:
-            root_s0 = jnp.asarray(carried_root, jnp.int32)
-            cursor0 = jnp.int32(carried_bump0)
+            # bins/rowids AND the score/label planes already sit at the
+            # carried root (compacted there by the previous tree's
+            # emit="carry"); only the g/h planes need this tree's gradients
+            arena = (arena_buf if fuse_root else
+                     jax.lax.dynamic_update_slice(
+                         arena_buf, gh,
+                         (jnp.int32(Fp),
+                          jnp.asarray(carried_root, jnp.int32))))
+        elif pristine:
+            arena = (arena_buf if fuse_root else
+                     jax.lax.dynamic_update_slice(arena_buf, gh, (Fp, 0)))
         else:
-            root_s0 = jnp.int32(0)
-            cursor0 = jnp.int32(work0 + n_al if pristine else n_al + pp.TILE)
-    else:
-        in_bag = (row_leaf_init == 0)
-        pred0 = jnp.pad(in_bag.astype(dtype), (0, cap - n))[None, :]
-        # pristine: in-bag rows copied to the work region (pristine rows
-        # intact for the next tree); legacy: compacted in place
-        bag_dst = work0 if pristine else 0
-        oob_dst = bag_dst + n_al
-        # fused compaction + in-bag (stream A) histogram: the root
-        # histogram covers every row the pass reads anyway, so here the
-        # fusion is pure saving (one full-n re-read + a launch)
-        arena, counts0, root_hist_b = part(
-            arena, pred0, jnp.int32(0), jnp.int32(n),
-            jnp.int32(bag_dst), jnp.int32(oob_dst), hist_stream=0,
-            num_features=G, max_bin=max_bin, quantized=quantized)
-        root_c = counts0[0]
-        root_s0 = jnp.int32(bag_dst)
-        cursor0 = jnp.int32(oob_dst + n_al)  # past the oob dump space
+            chans = [bins_t.astype(adt)]
+            if Fp > G:
+                chans.append(jnp.zeros((Fp - G, n), adt))
+            chans += [gh]
+            if quantized:
+                # keep the rowid planes at their fixed rows Fp+6..Fp+8
+                chans.append(jnp.zeros((pp.N_AUX - 3 - gh.shape[0], n), adt))
+            chans += [c[None] for c in
+                      pp.split_rowid(jnp.arange(n, dtype=jnp.int32))]
+            if C > Fp + pp.N_AUX:
+                chans.append(jnp.zeros((C - Fp - pp.N_AUX, n), adt))
+            arena = jax.lax.dynamic_update_slice(
+                arena_buf, jnp.concatenate(chans, axis=0), (0, 0))
 
-    if full_bag:
-        if quantized:
-            # fused mega-kernel (ISSUE 8 tentpole): ONE double-buffered
-            # pass over the root segment writes the fresh code planes
-            # AND accumulates the root histogram — replacing the XLA
-            # plane update plus a separate full-read seg() launch.
-            # Unlike the per-child fusion dead end below (the fh gate),
-            # the root histogram covers every row the refresh touches
-            # anyway, so this fusion is pure byte saving (the same
-            # argument as the bagging hist_stream above).
-            arena, root_hist = pp.fused_refresh_histogram(
-                arena, gh, root_s0, root_c, num_features=G,
-                max_bin=max_bin, interpret=interpret)
+        # ---- root: in-bag rows compacted into one segment ----------------
+        # decision-mode partition calls never read the pred stream; they get
+        # a tile-sized dummy (a [1, cap] buffer would be constant-sunk into
+        # the while loop and re-materialized every split)
+        pred_dummy = jnp.zeros((1, pp.TILE), dtype)
+        if full_bag:
+            # no bagging: every row is in-bag, the root segment IS the
+            # assembled prefix — skip the O(n) compaction pass and the
+            # OOB dump region entirely
+            root_c = jnp.int32(n)
+            if carried:
+                root_s0 = jnp.asarray(carried_root, jnp.int32)
+                cursor0 = jnp.int32(carried_bump0)
+            else:
+                root_s0 = jnp.int32(0)
+                cursor0 = jnp.int32(work0 + n_al if pristine
+                                    else n_al + pp.TILE)
         else:
-            root_hist = seg(arena, root_s0, root_c)
-    else:
-        root_hist = root_hist_b.astype(dtype)
-    root_c_local = root_c
-    if dp:
-        # DP: one histogram allreduce; global sums/counts fall out of it.
-        # The psum runs BEFORE dequantization: integer code sums reduce
-        # exactly in f32, so the global quantized histogram is bitwise a
-        # single encoder's sums (the module docstring's contract); the
-        # unquantized histogram is f32 either way.
-        root_hist = coll.psum(root_hist, axis_name)
-        root_c = coll.psum(root_c, axis_name)
-    root_hist = deq(root_hist)
-    root_g = jnp.sum(root_hist[0, :, 0])
-    root_h = jnp.sum(root_hist[0, :, 1])
-    if vp:
-        # voting keeps histograms LOCAL; only the scalar root stats ride
-        # an allreduce (data_parallel_tree_learner.cpp:116-142)
-        root_g = coll.psum(root_g, axis_name)
-        root_h = coll.psum(root_h, axis_name)
-        root_c = coll.psum(root_c, axis_name)
+            in_bag = (row_leaf_init == 0)
+            pred0 = jnp.pad(in_bag.astype(dtype), (0, cap - n))[None, :]
+            # pristine: in-bag rows copied to the work region (pristine rows
+            # intact for the next tree); legacy: compacted in place
+            bag_dst = work0 if pristine else 0
+            oob_dst = bag_dst + n_al
+            # fused compaction + in-bag (stream A) histogram: the root
+            # histogram covers every row the pass reads anyway, so here the
+            # fusion is pure saving (one full-n re-read + a launch)
+            arena, counts0, root_hist_b = part(
+                arena, pred0, jnp.int32(0), jnp.int32(n),
+                jnp.int32(bag_dst), jnp.int32(oob_dst), hist_stream=0,
+                num_features=G, max_bin=max_bin, quantized=quantized)
+            root_c = counts0[0]
+            root_s0 = jnp.int32(bag_dst)
+            cursor0 = jnp.int32(oob_dst + n_al)  # past the oob dump space
+
+        if full_bag:
+            if quantized:
+                # fused mega-kernel (ISSUE 8 tentpole): ONE double-buffered
+                # pass over the root segment writes the fresh code planes
+                # AND accumulates the root histogram — replacing the XLA
+                # plane update plus a separate full-read seg() launch.
+                # Unlike the per-child fusion dead end below (the fh gate),
+                # the root histogram covers every row the refresh touches
+                # anyway, so this fusion is pure byte saving (the same
+                # argument as the bagging hist_stream above).
+                arena, root_hist = pp.fused_refresh_histogram(
+                    arena, gh, root_s0, root_c, num_features=G,
+                    max_bin=max_bin, interpret=interpret)
+            else:
+                root_hist = seg(arena, root_s0, root_c)
+        else:
+            root_hist = root_hist_b.astype(dtype)
+        root_c_local = root_c
+        if dp:
+            # DP: one histogram allreduce; global sums/counts fall out of it.
+            # The psum runs BEFORE dequantization: integer code sums reduce
+            # exactly in f32, so the global quantized histogram is bitwise a
+            # single encoder's sums (the module docstring's contract); the
+            # unquantized histogram is f32 either way.
+            root_hist = coll.psum(root_hist, axis_name)
+            root_c = coll.psum(root_c, axis_name)
+        root_hist = deq(root_hist)
+        root_g = jnp.sum(root_hist[0, :, 0])
+        root_h = jnp.sum(root_hist[0, :, 1])
+        if vp:
+            # voting keeps histograms LOCAL; only the scalar root stats ride
+            # an allreduce (data_parallel_tree_learner.cpp:116-142)
+            root_g = coll.psum(root_g, axis_name)
+            root_h = coll.psum(root_h, axis_name)
+            root_c = coll.psum(root_c, axis_name)
 
     def unbundle(hist, sum_g, sum_h, cnt):
         from .grow import unbundle_hist
@@ -354,11 +357,12 @@ def grow_tree_partition_impl(
     else:
         scan_feature_mask = feature_mask
     fvec1 = fvec2 = None
-    if use_scan_kernel:
-        fvec1 = sp_pl.build_feature_statics(
-            num_bins, default_bins, missing_types, monotone=monotone,
-            penalty=penalty, feature_mask=scan_feature_mask, children=1)
-        fvec2 = jnp.concatenate([fvec1, fvec1], axis=0)
+    with jax.named_scope("lgbm.root"):
+        if use_scan_kernel:
+            fvec1 = sp_pl.build_feature_statics(
+                num_bins, default_bins, missing_types, monotone=monotone,
+                penalty=penalty, feature_mask=scan_feature_mask, children=1)
+            fvec2 = jnp.concatenate([fvec1, fvec1], axis=0)
 
     def _patch_cegb(fvec, used, children):
         if cegb_coupled is None or used is None:
@@ -567,57 +571,61 @@ def grow_tree_partition_impl(
             rows = _fp_sync(rows)
         return _gate(rows, depth_ok)
 
-    cegb_used0 = (cegb_used_init if cegb_used_init is not None
-                  else jnp.zeros(F, bool))
-    ninf = jnp.asarray(-jnp.inf, dtype)
-    pinf = jnp.asarray(jnp.inf, dtype)
-    root_row = single_best_row(root_hist, root_g, root_h, root_c,
-                               jnp.asarray(0, jnp.int32), used=cegb_used0,
-                               minc=ninf, maxc=pinf)
+    with jax.named_scope("lgbm.root"):
+        cegb_used0 = (cegb_used_init if cegb_used_init is not None
+                      else jnp.zeros(F, bool))
+        ninf = jnp.asarray(-jnp.inf, dtype)
+        pinf = jnp.asarray(jnp.inf, dtype)
+        root_row = single_best_row(root_hist, root_g, root_h, root_c,
+                                   jnp.asarray(0, jnp.int32), used=cegb_used0,
+                                   minc=ninf, maxc=pinf)
 
-    # histogram slot cache: K < L spills by LRU (hist_slots; 0 = one slot
-    # per leaf, never spills — leaf-indexed, no lookup machinery traced)
-    K = max(min(hist_slots, L), 4) if hist_slots and hist_slots > 0 else L
-    pooled = K < L
-    if forced_splits and pooled:
-        raise ValueError("forced_splits require the dense histogram cache "
-                         "(hist_slots=0): the injection indexes it by leaf")
-    hist_cache = jnp.zeros((K,) + root_hist.shape, dtype).at[0].set(root_hist)
-    if pooled:
-        slot_leaf0 = jnp.full(K, -1, jnp.int32).at[0].set(0)
-        slot_tick0 = jnp.zeros(K, jnp.int32).at[0].set(1)
-    else:
-        slot_leaf0 = jnp.zeros(1, jnp.int32)    # placeholders (untraced)
-        slot_tick0 = jnp.zeros(1, jnp.int32)
-    split_cache0 = (jnp.zeros((L, RWC), dtype)
-                    .at[:, sp_pl._OG].set(NEGF)
-                    .at[:, sp_pl._OF].set(-1.0)
-                    .at[0].set(root_row))
-    # leaf_mat lanes: value, count, parent, depth, min, max, start, local
-    leaf_mat0 = (jnp.zeros((L, 8), dtype)
-                 .at[:, 2].set(-1.0)
-                 .at[:, 4].set(-jnp.inf)
-                 .at[:, 5].set(jnp.inf)
-                 .at[0].set(jnp.stack([
-                     jnp.asarray(0.0, dtype), root_c.astype(dtype),
-                     jnp.asarray(-1.0, dtype), jnp.asarray(0.0, dtype),
-                     ninf, pinf, root_s0.astype(dtype),
-                     root_c_local.astype(dtype)])))
+        # histogram slot cache: K < L spills by LRU (hist_slots; 0 = one slot
+        # per leaf, never spills — leaf-indexed, no lookup machinery traced)
+        K = max(min(hist_slots, L), 4) if hist_slots and hist_slots > 0 else L
+        pooled = K < L
+        if forced_splits and pooled:
+            raise ValueError(
+                "forced_splits require the dense histogram cache "
+                "(hist_slots=0): the injection indexes it by leaf")
+        hist_cache = jnp.zeros((K,) + root_hist.shape,
+                               dtype).at[0].set(root_hist)
+        if pooled:
+            slot_leaf0 = jnp.full(K, -1, jnp.int32).at[0].set(0)
+            slot_tick0 = jnp.zeros(K, jnp.int32).at[0].set(1)
+        else:
+            slot_leaf0 = jnp.zeros(1, jnp.int32)    # placeholders (untraced)
+            slot_tick0 = jnp.zeros(1, jnp.int32)
+        split_cache0 = (jnp.zeros((L, RWC), dtype)
+                        .at[:, sp_pl._OG].set(NEGF)
+                        .at[:, sp_pl._OF].set(-1.0)
+                        .at[0].set(root_row))
+        # leaf_mat lanes: value, count, parent, depth, min, max, start, local
+        leaf_mat0 = (jnp.zeros((L, 8), dtype)
+                     .at[:, 2].set(-1.0)
+                     .at[:, 4].set(-jnp.inf)
+                     .at[:, 5].set(jnp.inf)
+                     .at[0].set(jnp.stack([
+                         jnp.asarray(0.0, dtype), root_c.astype(dtype),
+                         jnp.asarray(-1.0, dtype), jnp.asarray(0.0, dtype),
+                         ninf, pinf, root_s0.astype(dtype),
+                         root_c_local.astype(dtype)])))
 
-    state = PartState(
-        node_mat=jnp.zeros((N, 16), dtype),
-        leaf_mat=leaf_mat0,
-        node_cat=jnp.zeros((N, cat_w), dtype),
-        nl=jnp.asarray(1, jnp.int32),
-        arena=arena, cursor=cursor0,
-        hist_cache=hist_cache, slot_leaf=slot_leaf0, slot_tick=slot_tick0,
-        tick=jnp.asarray(2, jnp.int32),
-        split_cache=split_cache0,
-        done=jnp.asarray(False), cegb_used=cegb_used0,
-        truncated=jnp.asarray(False))
+        state = PartState(
+            node_mat=jnp.zeros((N, 16), dtype),
+            leaf_mat=leaf_mat0,
+            node_cat=jnp.zeros((N, cat_w), dtype),
+            nl=jnp.asarray(1, jnp.int32),
+            arena=arena, cursor=cursor0,
+            hist_cache=hist_cache, slot_leaf=slot_leaf0, slot_tick=slot_tick0,
+            tick=jnp.asarray(2, jnp.int32),
+            split_cache=split_cache0,
+            done=jnp.asarray(False), cegb_used=cegb_used0,
+            truncated=jnp.asarray(False))
 
     def cond(state: PartState):
-        return (~state.done) & (state.nl < L)
+        with jax.named_scope("lgbm.grow.book"):
+            return (~state.done) & (state.nl < L)
 
     def body(state: PartState) -> PartState:
         # The arena flows UNCONDITIONALLY through the (aliased) partition
@@ -626,83 +634,86 @@ def grow_tree_partition_impl(
         # split.  When no split applies (done, or the bump allocator is
         # full) the partition degenerates to cnt=0 — a no-op pass — and the
         # small state is masked instead.
-        best_leaf = jnp.argmax(
-            state.split_cache[:, sp_pl._OG]).astype(jnp.int32)
-        row = state.split_cache[best_leaf]                     # [RWC]
-        gain = row[sp_pl._OG]
-        no_split = gain <= NEG_GATE
+        with jax.named_scope("lgbm.grow.book"):
+            best_leaf = jnp.argmax(
+                state.split_cache[:, sp_pl._OG]).astype(jnp.int32)
+            row = state.split_cache[best_leaf]                     # [RWC]
+            gain = row[sp_pl._OG]
+            no_split = gain <= NEG_GATE
 
-        nl = state.nl
-        node = nl - 1
-        new_leaf = nl
-        feat = jnp.maximum(row[sp_pl._OF].astype(jnp.int32), 0)
-        thr = row[sp_pl._OT].astype(jnp.int32)
-        dl = row[sp_pl._ODL] > 0.5
-        lg, lh = row[sp_pl._OLG], row[sp_pl._OLH]
-        lc_f, lo = row[sp_pl._OLC], row[sp_pl._OLO]
-        rg, rh = row[sp_pl._ORG], row[sp_pl._ORH]
-        rc_f, ro = row[sp_pl._ORC], row[sp_pl._ORO]
-        lc_i = lc_f.astype(jnp.int32)
-        rc_i = rc_f.astype(jnp.int32)
+            nl = state.nl
+            node = nl - 1
+            new_leaf = nl
+            feat = jnp.maximum(row[sp_pl._OF].astype(jnp.int32), 0)
+            thr = row[sp_pl._OT].astype(jnp.int32)
+            dl = row[sp_pl._ODL] > 0.5
+            lg, lh = row[sp_pl._OLG], row[sp_pl._OLH]
+            lc_f, lo = row[sp_pl._OLC], row[sp_pl._OLO]
+            rg, rh = row[sp_pl._ORG], row[sp_pl._ORH]
+            rc_f, ro = row[sp_pl._ORC], row[sp_pl._ORO]
+            lc_i = lc_f.astype(jnp.int32)
+            rc_i = rc_f.astype(jnp.int32)
 
-        lrow = state.leaf_mat[best_leaf]                       # [8]
-        old_value = lrow[0]
-        parent_of = lrow[2].astype(jnp.int32)
-        depth = lrow[3]
-        minP, maxP = lrow[4], lrow[5]
-        s0 = lrow[6].astype(jnp.int32)
-        cntP_local = lrow[7].astype(jnp.int32)
+            lrow = state.leaf_mat[best_leaf]                       # [8]
+            old_value = lrow[0]
+            parent_of = lrow[2].astype(jnp.int32)
+            depth = lrow[3]
+            minP, maxP = lrow[4], lrow[5]
+            s0 = lrow[6].astype(jnp.int32)
+            cntP_local = lrow[7].astype(jnp.int32)
 
-        left_smaller = lc_i <= rc_i
-        small_cnt = jnp.minimum(lc_i, rc_i)
-        # bump-allocator overflow: stop growing this tree (the arena
-        # budget covers balanced trees; pathological shapes truncate —
-        # the flag is surfaced so the driver can warn the user to raise
-        # tpu_arena_factor).  Serial: the smaller-child count is exact.
-        # Data-parallel/voting: the LOCAL smaller-child size is only
-        # known after the kernel runs, so the bound is the local parent
-        # size; the flag is all-reduced so every shard truncates
-        # together.  Feature-parallel replicates data, so counts (and
-        # the overflow decision) are identical on every device.
-        if axis_name is None or fp:
-            need_bound = _align(small_cnt, ALLOC)
-        else:
-            need_bound = _align(cntP_local, ALLOC)
-        overflow = (~no_split) & (state.cursor + need_bound + pp.TILE > cap)
-        if dp or vp:
-            overflow = coll.psum(overflow.astype(jnp.int32),
-                                    axis_name) > 0
-        no_split = no_split | overflow
+            left_smaller = lc_i <= rc_i
+            small_cnt = jnp.minimum(lc_i, rc_i)
+            # bump-allocator overflow: stop growing this tree (the arena
+            # budget covers balanced trees; pathological shapes truncate —
+            # the flag is surfaced so the driver can warn the user to raise
+            # tpu_arena_factor).  Serial: the smaller-child count is exact.
+            # Data-parallel/voting: the LOCAL smaller-child size is only
+            # known after the kernel runs, so the bound is the local parent
+            # size; the flag is all-reduced so every shard truncates
+            # together.  Feature-parallel replicates data, so counts (and
+            # the overflow decision) are identical on every device.
+            if axis_name is None or fp:
+                need_bound = _align(small_cnt, ALLOC)
+            else:
+                need_bound = _align(cntP_local, ALLOC)
+            overflow = (~no_split) & (
+                state.cursor + need_bound + pp.TILE > cap)
+            if dp or vp:
+                overflow = coll.psum(overflow.astype(jnp.int32),
+                                        axis_name) > 0
+            no_split = no_split | overflow
 
-        cntP = jnp.where(no_split, 0, cntP_local)
-        dstB = state.cursor
-        if pristine:
-            # the pristine row block is read-only: the first split of the
-            # root (s0 inside pristine) writes its larger child to the
-            # start of the work region instead of in place
-            dstA = jnp.where(s0 < work0, jnp.int32(work0), s0)
-        else:
-            dstA = s0
+            cntP = jnp.where(no_split, 0, cntP_local)
+            dstB = state.cursor
+            if pristine:
+                # the pristine row block is read-only: the first split of the
+                # root (s0 inside pristine) writes its larger child to the
+                # start of the work region instead of in place
+                dstA = jnp.where(s0 < work0, jnp.int32(work0), s0)
+            else:
+                dstA = s0
 
-        if pooled:
-            # parent histogram: slot-cache lookup (HistogramPool::Get),
-            # with a recompute from the parent's STILL-INTACT segment on
-            # miss — this must run before the partition overwrites the
-            # segment.  The recompute kernel degenerates to cnt=0 (free)
-            # on a hit.
-            in_slot = state.slot_leaf == best_leaf
-            found = jnp.any(in_slot)
-            pslot = jnp.argmax(in_slot).astype(jnp.int32)
-            recomputed = seg(state.arena, s0,
-                             jnp.where(found | no_split, 0,
-                                       cntP_local))
-            # under DP the recompute's allreduce is BATCHED with the
-            # smaller-child histogram's below (one collective per split
-            # even in pooled mode); only the kernel must run pre-split
-        else:
-            # dense cache (one slot per leaf): direct index, no extra
-            # kernel or collective on the split critical path
-            parent_hist = state.hist_cache[best_leaf]
+        with jax.named_scope("lgbm.grow.cache"):
+            if pooled:
+                # parent histogram: slot-cache lookup (HistogramPool::Get),
+                # with a recompute from the parent's STILL-INTACT segment on
+                # miss — this must run before the partition overwrites the
+                # segment.  The recompute kernel degenerates to cnt=0 (free)
+                # on a hit.
+                in_slot = state.slot_leaf == best_leaf
+                found = jnp.any(in_slot)
+                pslot = jnp.argmax(in_slot).astype(jnp.int32)
+                recomputed = seg(state.arena, s0,
+                                 jnp.where(found | no_split, 0,
+                                           cntP_local))
+                # under DP the recompute's allreduce is BATCHED with the
+                # smaller-child histogram's below (one collective per split
+                # even in pooled mode); only the kernel must run pre-split
+            else:
+                # dense cache (one slot per leaf): direct index, no extra
+                # kernel or collective on the split critical path
+                parent_hist = state.hist_cache[best_leaf]
 
         # the go-left decision is evaluated INSIDE the kernel via a
         # [1, B] mask vector over arena bin values — built here to encode
@@ -712,144 +723,157 @@ def grow_tree_partition_impl(
         # XLA-side per-row predicate would cost an O(cap) pass per split.
         # Stream A (in place over the parent) takes the LARGER child:
         # go_left XOR left_smaller == "row goes to the larger side".
-        bv = jnp.arange(256, dtype=jnp.int32)
-        if bundle is None:
-            chan = feat
-            fbin = bv
-        else:
-            chan = bundle.feat_col[feat]
-            inside = (bv >= bundle.feat_lo[feat]) & (bv < bundle.feat_hi[feat])
-            fbin = jnp.where(inside, bv - bundle.feat_shift[feat],
-                             default_bins[feat])
-        mt = missing_types[feat]
-        db = default_bins[feat]
-        mb = num_bins[feat] - 1
-        is_missing = ((mt == MISSING_ZERO) & (fbin == db)) | \
-                     ((mt == MISSING_NAN) & (fbin == mb))
-        go_left = jnp.where(is_missing, dl, fbin <= thr)
-        if is_categorical is not None:
-            cm = jnp.pad(row[RW:] > 0.5, (0, 256 - cat_w))
-            go_left = jnp.where(is_categorical[feat],
-                                cm[jnp.clip(fbin, 0, 255)], go_left)
-        decision = (chan, go_left.astype(jnp.float32),
-                    left_smaller.astype(jnp.int32))
-        # NOT fused with the histogram: slope-corrected round-4 profiling
-        # (tools/kernel_slope.py — the earlier "fusion is free" reading
-        # came from fetch-latency-biased microbenches) confirms the fused
-        # pass pays the radix contraction over the WHOLE parent stream
-        # (+6.9 ms/4M rows) while the separate kernel touches only the
-        # compacted smaller child — O(small) beats O(parent) here.
-        # Round 5 re-tested a PARENT-SIZE-GATED fusion (in-kernel fh
-        # gate + small-parent fused path, partition_pallas fused_gate/
-        # raw_hist): ~10% WORSE end-to-end — requesting the hist output
-        # on every partition launch adds its buffer setup/writeback to
-        # all ~254 splits, which costs more than the separate kernel's
-        # fixed cost ever did.  Two launches stay the right shape here.
-        arena, counts = part(state.arena, pred_dummy, s0, cntP, dstA, dstB,
-                             decision=decision)
-        small_hist = seg(arena, dstB, jnp.where(no_split, 0, counts[1]))
-        if dp:
-            # DP: ONE collective per split — the smaller child's histogram
-            # allreduce (the sibling still comes from subtraction, §3.4.2);
-            # in pooled mode the parent recompute rides the same allreduce.
-            # Voting and feature-parallel skip this: voting keeps local
-            # histograms (the election psums only elected features),
-            # feature-parallel's histograms are replicated already.
-            # As with the root, the psum reduces the raw (code-sum)
-            # histograms so quantized DP stays bitwise-serial.
-            if pooled:
-                both_h = coll.psum(jnp.stack([small_hist, recomputed]),
-                                      axis_name)
-                small_hist, recomputed = both_h[0], both_h[1]
+        with jax.named_scope("lgbm.grow.partition"):
+            bv = jnp.arange(256, dtype=jnp.int32)
+            if bundle is None:
+                chan = feat
+                fbin = bv
             else:
-                small_hist = coll.psum(small_hist, axis_name)
-        small_hist = deq(small_hist)
-        if pooled:
-            parent_hist = jnp.where(found, state.hist_cache[pslot],
-                                    deq(recomputed).astype(dtype))
-        large_hist = parent_hist - small_hist
-        left_hist = jnp.where(left_smaller, small_hist, large_hist)
-        right_hist = jnp.where(left_smaller, large_hist, small_hist)
-        if pooled:
-            # store both children: the parent's slot (if cached) is
-            # reused for the left child, the right child evicts the
-            # least-recently-written slot (HistogramPool::Move + LRU)
-            slotL = jnp.where(found, pslot,
-                              jnp.argmin(state.slot_tick).astype(jnp.int32))
-            tickL = state.slot_tick.at[slotL].set(state.tick)
-            slotR = jnp.argmin(tickL).astype(jnp.int32)
-            hist_cache = state.hist_cache.at[slotL].set(left_hist)
-            hist_cache = hist_cache.at[slotR].set(right_hist)
-            slot_leaf = state.slot_leaf.at[slotL].set(best_leaf)
-            slot_leaf = slot_leaf.at[slotR].set(new_leaf)
-            slot_tick = tickL.at[slotR].set(state.tick + 1)
-            tick = state.tick + 2
-        else:
-            hist_cache = state.hist_cache.at[best_leaf].set(left_hist)
-            hist_cache = hist_cache.at[new_leaf].set(right_hist)
-            slot_leaf, slot_tick, tick = (state.slot_leaf, state.slot_tick,
-                                          state.tick)
-
-        startL = jnp.where(left_smaller, dstB, dstA).astype(dtype)
-        startR = jnp.where(left_smaller, dstA, dstB).astype(dtype)
-        localL = jnp.where(left_smaller, counts[1], counts[0]).astype(dtype)
-        localR = jnp.where(left_smaller, counts[0], counts[1]).astype(dtype)
-        cursor = dstB + _align(counts[1], ALLOC)
-
-        # monotone mid-constraint propagation (serial_tree_learner.cpp:
-        # 837-846); categorical splits never carry monotone constraints
-        minL, maxL, minR, maxR = minP, maxP, minP, maxP
-        if monotone is not None:
-            mono_t = monotone[feat].astype(jnp.int32)
+                chan = bundle.feat_col[feat]
+                inside = ((bv >= bundle.feat_lo[feat])
+                          & (bv < bundle.feat_hi[feat]))
+                fbin = jnp.where(inside, bv - bundle.feat_shift[feat],
+                                 default_bins[feat])
+            mt = missing_types[feat]
+            db = default_bins[feat]
+            mb = num_bins[feat] - 1
+            is_missing = ((mt == MISSING_ZERO) & (fbin == db)) | \
+                         ((mt == MISSING_NAN) & (fbin == mb))
+            go_left = jnp.where(is_missing, dl, fbin <= thr)
             if is_categorical is not None:
-                mono_t = jnp.where(is_categorical[feat], 0, mono_t)
-            mid = ((lo + ro) / 2).astype(dtype)
-            maxL = jnp.where(mono_t > 0, mid, maxP)
-            minR = jnp.where(mono_t > 0, mid, minP)
-            minL = jnp.where(mono_t < 0, mid, minP)
-            maxR = jnp.where(mono_t < 0, mid, maxP)
+                cm = jnp.pad(row[RW:] > 0.5, (0, 256 - cat_w))
+                go_left = jnp.where(is_categorical[feat],
+                                    cm[jnp.clip(fbin, 0, 255)], go_left)
+            decision = (chan, go_left.astype(jnp.float32),
+                        left_smaller.astype(jnp.int32))
+            # NOT fused with the histogram: slope-corrected round-4 profiling
+            # (tools/kernel_slope.py — the earlier "fusion is free" reading
+            # came from fetch-latency-biased microbenches) confirms the fused
+            # pass pays the radix contraction over the WHOLE parent stream
+            # (+6.9 ms/4M rows) while the separate kernel touches only the
+            # compacted smaller child — O(small) beats O(parent) here.
+            # Round 5 re-tested a PARENT-SIZE-GATED fusion (in-kernel fh
+            # gate + small-parent fused path, partition_pallas fused_gate/
+            # raw_hist): ~10% WORSE end-to-end — requesting the hist output
+            # on every partition launch adds its buffer setup/writeback to
+            # all ~254 splits, which costs more than the separate kernel's
+            # fixed cost ever did.  Two launches stay the right shape here.
+            arena, counts = part(state.arena, pred_dummy, s0, cntP, dstA, dstB,
+                                 decision=decision)
+        with jax.named_scope("lgbm.grow.hist"):
+            small_hist = seg(arena, dstB, jnp.where(no_split, 0, counts[1]))
+            if dp:
+                # DP: ONE collective per split — the smaller child's
+                # histogram allreduce (the sibling still comes from
+                # subtraction, §3.4.2); in pooled mode the parent
+                # recompute rides the same allreduce.
+                # Voting and feature-parallel skip this: voting keeps local
+                # histograms (the election psums only elected features),
+                # feature-parallel's histograms are replicated already.
+                # As with the root, the psum reduces the raw (code-sum)
+                # histograms so quantized DP stays bitwise-serial.
+                if pooled:
+                    both_h = coll.psum(jnp.stack([small_hist, recomputed]),
+                                          axis_name)
+                    small_hist, recomputed = both_h[0], both_h[1]
+                else:
+                    small_hist = coll.psum(small_hist, axis_name)
+            small_hist = deq(small_hist)
+        with jax.named_scope("lgbm.grow.cache"):
+            if pooled:
+                parent_hist = jnp.where(found, state.hist_cache[pslot],
+                                        deq(recomputed).astype(dtype))
+        with jax.named_scope("lgbm.grow.hist"):
+            large_hist = parent_hist - small_hist
+            left_hist = jnp.where(left_smaller, small_hist, large_hist)
+            right_hist = jnp.where(left_smaller, large_hist, small_hist)
+        with jax.named_scope("lgbm.grow.cache"):
+            if pooled:
+                # store both children: the parent's slot (if cached) is
+                # reused for the left child, the right child evicts the
+                # least-recently-written slot (HistogramPool::Move + LRU)
+                slotL = jnp.where(
+                    found, pslot,
+                    jnp.argmin(state.slot_tick).astype(jnp.int32))
+                tickL = state.slot_tick.at[slotL].set(state.tick)
+                slotR = jnp.argmin(tickL).astype(jnp.int32)
+                hist_cache = state.hist_cache.at[slotL].set(left_hist)
+                hist_cache = hist_cache.at[slotR].set(right_hist)
+                slot_leaf = state.slot_leaf.at[slotL].set(best_leaf)
+                slot_leaf = slot_leaf.at[slotR].set(new_leaf)
+                slot_tick = tickL.at[slotR].set(state.tick + 1)
+                tick = state.tick + 2
+            else:
+                hist_cache = state.hist_cache.at[best_leaf].set(left_hist)
+                hist_cache = hist_cache.at[new_leaf].set(right_hist)
+                slot_leaf, slot_tick, tick = (state.slot_leaf, state.slot_tick,
+                                              state.tick)
 
-        # -- tree bookkeeping (Tree::Split, tree.h:393-423): one node row
-        # + two leaf rows + the parent's child-pointer fix-up ------------
-        node_f = node.astype(dtype)
-        safe_p = jnp.maximum(parent_of, 0)
-        prow = state.node_mat[safe_p]
-        was_left = prow[4] == -(best_leaf + 1).astype(dtype)
-        node_mat = state.node_mat.at[safe_p, 4].set(
-            jnp.where((parent_of >= 0) & was_left, node_f, prow[4]))
-        node_mat = node_mat.at[safe_p, 5].set(
-            jnp.where((parent_of >= 0) & ~was_left, node_f, prow[5]))
-        is_cat_f = (is_categorical[feat].astype(dtype)
-                    if is_categorical is not None
-                    else jnp.asarray(0.0, dtype))
-        nrow = jnp.concatenate([jnp.stack([
-            feat.astype(dtype), thr.astype(dtype), dl.astype(dtype),
-            missing_types[feat].astype(dtype),
-            -(best_leaf + 1).astype(dtype), -(new_leaf + 1).astype(dtype),
-            gain, old_value, lc_f + rc_f, is_cat_f]),
-            jnp.zeros(6, dtype)])
-        node_mat = node_mat.at[node].set(nrow)
-        node_cat = state.node_cat
-        if cat_w:
-            node_cat = node_cat.at[node].set(row[RW:])
+        with jax.named_scope("lgbm.grow.book"):
+            startL = jnp.where(left_smaller, dstB, dstA).astype(dtype)
+            startR = jnp.where(left_smaller, dstA, dstB).astype(dtype)
+            localL = jnp.where(left_smaller, counts[1],
+                               counts[0]).astype(dtype)
+            localR = jnp.where(left_smaller, counts[0],
+                               counts[1]).astype(dtype)
+            cursor = dstB + _align(counts[1], ALLOC)
 
-        lrow_l = jnp.stack([lo, lc_f, node_f, depth + 1, minL, maxL,
-                            startL, localL])
-        lrow_r = jnp.stack([ro, rc_f, node_f, depth + 1, minR, maxR,
-                            startR, localR])
-        leaf_mat = state.leaf_mat.at[best_leaf].set(lrow_l) \
-                                 .at[new_leaf].set(lrow_r)
+            # monotone mid-constraint propagation (serial_tree_learner.cpp:
+            # 837-846); categorical splits never carry monotone constraints
+            minL, maxL, minR, maxR = minP, maxP, minP, maxP
+            if monotone is not None:
+                mono_t = monotone[feat].astype(jnp.int32)
+                if is_categorical is not None:
+                    mono_t = jnp.where(is_categorical[feat], 0, mono_t)
+                mid = ((lo + ro) / 2).astype(dtype)
+                maxL = jnp.where(mono_t > 0, mid, maxP)
+                minR = jnp.where(mono_t > 0, mid, minP)
+                minL = jnp.where(mono_t < 0, mid, minP)
+                maxR = jnp.where(mono_t < 0, mid, maxP)
 
-        used2 = state.cegb_used.at[feat].set(True)
+            # -- tree bookkeeping (Tree::Split, tree.h:393-423): one node row
+            # + two leaf rows + the parent's child-pointer fix-up ------------
+            node_f = node.astype(dtype)
+            safe_p = jnp.maximum(parent_of, 0)
+            prow = state.node_mat[safe_p]
+            was_left = prow[4] == -(best_leaf + 1).astype(dtype)
+            node_mat = state.node_mat.at[safe_p, 4].set(
+                jnp.where((parent_of >= 0) & was_left, node_f, prow[4]))
+            node_mat = node_mat.at[safe_p, 5].set(
+                jnp.where((parent_of >= 0) & ~was_left, node_f, prow[5]))
+            is_cat_f = (is_categorical[feat].astype(dtype)
+                        if is_categorical is not None
+                        else jnp.asarray(0.0, dtype))
+            nrow = jnp.concatenate([jnp.stack([
+                feat.astype(dtype), thr.astype(dtype), dl.astype(dtype),
+                missing_types[feat].astype(dtype),
+                -(best_leaf + 1).astype(dtype), -(new_leaf + 1).astype(dtype),
+                gain, old_value, lc_f + rc_f, is_cat_f]),
+                jnp.zeros(6, dtype)])
+            node_mat = node_mat.at[node].set(nrow)
+            node_cat = state.node_cat
+            if cat_w:
+                node_cat = node_cat.at[node].set(row[RW:])
+
+            lrow_l = jnp.stack([lo, lc_f, node_f, depth + 1, minL, maxL,
+                                startL, localL])
+            lrow_r = jnp.stack([ro, rc_f, node_f, depth + 1, minR, maxR,
+                                startR, localR])
+            leaf_mat = state.leaf_mat.at[best_leaf].set(lrow_l) \
+                                     .at[new_leaf].set(lrow_r)
+
+            used2 = state.cegb_used.at[feat].set(True)
         # ONE scan over both children (single Pallas launch incl. the
         # cross-feature select on the numerical path)
-        rows2 = pair_best_rows(
-            jnp.stack([left_hist, right_hist]),
-            jnp.stack([lg, rg]), jnp.stack([lh, rh]),
-            jnp.stack([lc_f, rc_f]), depth + 1, used2,
-            jnp.stack([minL, minR]), jnp.stack([maxL, maxR]))
-        split_cache = state.split_cache.at[best_leaf].set(rows2[0]) \
-                                       .at[new_leaf].set(rows2[1])
+        with jax.named_scope("lgbm.grow.scan"):
+            rows2 = pair_best_rows(
+                jnp.stack([left_hist, right_hist]),
+                jnp.stack([lg, rg]), jnp.stack([lh, rh]),
+                jnp.stack([lc_f, rc_f]), depth + 1, used2,
+                jnp.stack([minL, minR]), jnp.stack([maxL, maxR]))
+        with jax.named_scope("lgbm.grow.book"):
+            split_cache = state.split_cache.at[best_leaf].set(rows2[0]) \
+                                           .at[new_leaf].set(rows2[1])
 
         # merge: arena is already unchanged when no_split (cnt=0 pass);
         # mask every small field back to its previous value
@@ -858,20 +882,24 @@ def grow_tree_partition_impl(
         def sel(old_v, new_v):
             return jnp.where(keep, old_v, new_v)
 
-        return PartState(
-            node_mat=sel(state.node_mat, node_mat),
-            leaf_mat=sel(state.leaf_mat, leaf_mat),
-            node_cat=(sel(state.node_cat, node_cat) if cat_w
-                      else state.node_cat),
-            nl=sel(nl, nl + 1),
-            arena=arena, cursor=sel(state.cursor, cursor),
-            hist_cache=sel(state.hist_cache, hist_cache),
-            slot_leaf=sel(state.slot_leaf, slot_leaf),
-            slot_tick=sel(state.slot_tick, slot_tick),
-            tick=sel(state.tick, tick),
-            split_cache=sel(state.split_cache, split_cache),
-            done=keep, cegb_used=sel(state.cegb_used, used2),
-            truncated=state.truncated | overflow)
+        with jax.named_scope("lgbm.grow.cache"):
+            hist_cache = sel(state.hist_cache, hist_cache)
+            slot_leaf = sel(state.slot_leaf, slot_leaf)
+            slot_tick = sel(state.slot_tick, slot_tick)
+        with jax.named_scope("lgbm.grow.book"):
+            return PartState(
+                node_mat=sel(state.node_mat, node_mat),
+                leaf_mat=sel(state.leaf_mat, leaf_mat),
+                node_cat=(sel(state.node_cat, node_cat) if cat_w
+                          else state.node_cat),
+                nl=sel(nl, nl + 1),
+                arena=arena, cursor=sel(state.cursor, cursor),
+                hist_cache=hist_cache,
+                slot_leaf=slot_leaf, slot_tick=slot_tick,
+                tick=sel(state.tick, tick),
+                split_cache=sel(state.split_cache, split_cache),
+                done=keep, cegb_used=sel(state.cegb_used, used2),
+                truncated=state.truncated | overflow)
 
     # Forced splits first (trace-time unrolled, same scheme as the label
     # engine: inject a +inf-gain forced row into the split cache and
@@ -925,76 +953,82 @@ def grow_tree_partition_impl(
             leafmap = leafmap.at[f_leaf].set(
                 jnp.where(applied, dyn_leaf, -1))
 
-    state = jax.lax.while_loop(cond, body, state)
+    # the loop itself under a scope: what the compiler does at the loop's
+    # level (copies and selects of carried state, above all of the
+    # histogram cache) takes the while's op_name, not the body's
+    with jax.named_scope("lgbm.grow.carry"):
+        state = jax.lax.while_loop(cond, body, state)
 
     # ---- materialize TreeArrays from the packed tables -------------------
-    nm, lm = state.node_mat, state.leaf_mat
-    tree = TreeArrays(
-        split_feature=nm[:, 0].astype(jnp.int32),
-        threshold_bin=nm[:, 1].astype(jnp.int32),
-        default_left=nm[:, 2] > 0.5,
-        missing_type=nm[:, 3].astype(jnp.int32),
-        left_child=nm[:, 4].astype(jnp.int32),
-        right_child=nm[:, 5].astype(jnp.int32),
-        split_gain=nm[:, 6].astype(dtype),
-        internal_value=nm[:, 7].astype(dtype),
-        internal_count=nm[:, 8].astype(jnp.int32),
-        leaf_value=lm[:, 0].astype(dtype),
-        leaf_count=lm[:, 1].astype(jnp.int32),
-        leaf_parent=lm[:, 2].astype(jnp.int32),
-        leaf_depth=lm[:, 3].astype(jnp.int32),
-        num_leaves=state.nl,
-        is_cat=nm[:, 9] > 0.5,
-        cat_mask=state.node_cat > 0.5)
+    with jax.named_scope("lgbm.finish"):
+        nm, lm = state.node_mat, state.leaf_mat
+        tree = TreeArrays(
+            split_feature=nm[:, 0].astype(jnp.int32),
+            threshold_bin=nm[:, 1].astype(jnp.int32),
+            default_left=nm[:, 2] > 0.5,
+            missing_type=nm[:, 3].astype(jnp.int32),
+            left_child=nm[:, 4].astype(jnp.int32),
+            right_child=nm[:, 5].astype(jnp.int32),
+            split_gain=nm[:, 6].astype(dtype),
+            internal_value=nm[:, 7].astype(dtype),
+            internal_count=nm[:, 8].astype(jnp.int32),
+            leaf_value=lm[:, 0].astype(dtype),
+            leaf_count=lm[:, 1].astype(jnp.int32),
+            leaf_parent=lm[:, 2].astype(jnp.int32),
+            leaf_depth=lm[:, 3].astype(jnp.int32),
+            num_leaves=state.nl,
+            is_cat=nm[:, 9] > 0.5,
+            cat_mask=state.node_cat > 0.5)
 
-    if emit == "carry":
-        # carried-arena boundary: compact the live segments (leaf-index
-        # order, full channels incl. score/label planes) into the other
-        # root slot — NO row-order recovery, NO sort; the caller updates
-        # the score planes from leaf_value/leaf_count and roots the next
-        # tree at carry_dst (per-row leaf values derive from
-        # cumsum(leaf_count) over the same leaf order)
-        arena2, used = pp.compact_carry(
+        if emit == "carry":
+            # carried-arena boundary: compact the live segments (leaf-index
+            # order, full channels incl. score/label planes) into the other
+            # root slot — NO row-order recovery, NO sort; the caller updates
+            # the score planes from leaf_value/leaf_count and roots the next
+            # tree at carry_dst (per-row leaf values derive from
+            # cumsum(leaf_count) over the same leaf order)
+            arena2, used = pp.compact_carry(
+                state.arena, lm[:, 6].astype(jnp.int32),
+                lm[:, 7].astype(jnp.int32), state.nl,
+                jnp.asarray(carry_dst, jnp.int32), interpret=interpret)
+            return tree, used, arena2, state.truncated
+
+        # ---- recover per-row outputs from the final segments -------------
+        # The compact kernel streams ONLY the live segments (O(n) work,
+        # independent of cap — the old step-function recovery paid three
+        # cumsums plus a scatter over the whole ~6n-column arena) and emits a
+        # dense (rowid, value) stream; one n-sized scatter finishes the job.
+        capn = -(-n // pp.TILE) * pp.TILE + L * pp.TILE
+        vals = (lm[:, 0].astype(jnp.float32) if emit == "score"
+                else jnp.arange(L, dtype=jnp.int32).astype(jnp.float32))
+        stream, used = pp.compact_segments(
             state.arena, lm[:, 6].astype(jnp.int32),
-            lm[:, 7].astype(jnp.int32), state.nl,
-            jnp.asarray(carry_dst, jnp.int32), interpret=interpret)
-        return tree, used, arena2, state.truncated
-
-    # ---- recover per-row outputs from the final segments -----------------
-    # The compact kernel streams ONLY the live segments (O(n) work,
-    # independent of cap — the old step-function recovery paid three
-    # cumsums plus a scatter over the whole ~6n-column arena) and emits a
-    # dense (rowid, value) stream; one n-sized scatter finishes the job.
-    capn = -(-n // pp.TILE) * pp.TILE + L * pp.TILE
-    vals = (lm[:, 0].astype(jnp.float32) if emit == "score"
-            else jnp.arange(L, dtype=jnp.int32).astype(jnp.float32))
-    stream, used = pp.compact_segments(
-        state.arena, lm[:, 6].astype(jnp.int32), lm[:, 7].astype(jnp.int32),
-        vals, state.nl, n, G, capn, interpret=interpret)
-    # positions >= used are never written by the kernel (garbage, not
-    # dummy) — mask them to the dummy rowid before the reorder
-    written = jnp.arange(capn, dtype=jnp.int32) < used[0]
-    rid = jnp.where(written, stream[0].astype(jnp.int32), n)
-    if full_bag:
-        # every rowid in [0, n) appears exactly once (segments partition
-        # the full root segment), so a key/value sort puts the values in
-        # row order directly — measured ~2x faster than the XLA scatter
-        # (TPU scatters serialize; sort is a fast bitonic primitive)
-        _, sv = jax.lax.sort((rid, stream[1]), num_keys=1)
+            lm[:, 7].astype(jnp.int32),
+            vals, state.nl, n, G, capn, interpret=interpret)
+        # positions >= used are never written by the kernel (garbage, not
+        # dummy) — mask them to the dummy rowid before the reorder
+        written = jnp.arange(capn, dtype=jnp.int32) < used[0]
+        rid = jnp.where(written, stream[0].astype(jnp.int32), n)
+        if full_bag:
+            # every rowid in [0, n) appears exactly once (segments partition
+            # the full root segment), so a key/value sort puts the values in
+            # row order directly — measured ~2x faster than the XLA scatter
+            # (TPU scatters serialize; sort is a fast bitonic primitive)
+            _, sv = jax.lax.sort((rid, stream[1]), num_keys=1)
+            if emit == "score":
+                return tree, sv[:n].astype(dtype), state.arena, state.truncated
+            return (tree, jnp.round(sv[:n]).astype(jnp.int32), state.arena,
+                    state.truncated)
         if emit == "score":
-            return tree, sv[:n].astype(dtype), state.arena, state.truncated
-        return (tree, jnp.round(sv[:n]).astype(jnp.int32), state.arena,
-                state.truncated)
-    if emit == "score":
-        # scatter each row's LEAF VALUE directly — the driver's separate
-        # 255-table leaf_value[leaf_ids] gather is a pure serial-gather
-        # cost on TPU and is skipped entirely
-        delta = jnp.zeros(n + 1, dtype).at[rid].set(
-            stream[1].astype(dtype), mode="drop")[:n]
-        return tree, delta, state.arena, state.truncated
-    leaf_ids = jnp.full(n + 1, -1, jnp.int32).at[rid].set(
-        stream[1].astype(jnp.int32), mode="drop")[:n]
-    return tree, leaf_ids, state.arena, state.truncated
+            # scatter each row's LEAF VALUE directly — the driver's separate
+            # 255-table leaf_value[leaf_ids] gather is a pure serial-gather
+            # cost on TPU and is skipped entirely
+            delta = jnp.zeros(n + 1, dtype).at[rid].set(
+                stream[1].astype(dtype), mode="drop")[:n]
+            return tree, delta, state.arena, state.truncated
+        leaf_ids = jnp.full(n + 1, -1, jnp.int32).at[rid].set(
+            stream[1].astype(jnp.int32), mode="drop")[:n]
+        return tree, leaf_ids, state.arena, state.truncated
 
 
 # donate_argnums=(0,): the arena is the only donated input — bins_t and

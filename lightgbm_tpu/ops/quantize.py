@@ -68,13 +68,14 @@ def quantize_gradients(grad, hess, key):
     small integers so they can be cast losslessly to the bf16 arena
     payload planes (bf16 represents every integer up to 256 exactly).
     """
-    g = jnp.asarray(grad, jnp.float32)
-    h = jnp.asarray(hess, jnp.float32)
-    g_scale = jnp.maximum(jnp.max(jnp.abs(g)), 1e-30) / CODE_MAX
-    h_scale = jnp.maximum(jnp.max(jnp.abs(h)), 1e-30) / CODE_MAX
-    u = jax.random.uniform(key, g.shape, jnp.float32)
-    g_code = jnp.clip(jnp.floor(g / g_scale + u), -CODE_MAX, CODE_MAX)
-    h_code = jnp.clip(jnp.round(h / h_scale), -CODE_MAX, CODE_MAX)
+    with jax.named_scope("lgbm.quantize"):
+        g = jnp.asarray(grad, jnp.float32)
+        h = jnp.asarray(hess, jnp.float32)
+        g_scale = jnp.maximum(jnp.max(jnp.abs(g)), 1e-30) / CODE_MAX
+        h_scale = jnp.maximum(jnp.max(jnp.abs(h)), 1e-30) / CODE_MAX
+        u = jax.random.uniform(key, g.shape, jnp.float32)
+        g_code = jnp.clip(jnp.floor(g / g_scale + u), -CODE_MAX, CODE_MAX)
+        h_code = jnp.clip(jnp.round(h / h_scale), -CODE_MAX, CODE_MAX)
     return g_code, h_code, g_scale, h_scale
 
 

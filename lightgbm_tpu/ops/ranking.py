@@ -126,10 +126,12 @@ def _lambda_bucket(score_pad, lab, gains, real, inv_mdcg, disc, sigmoid,
         hes = jnp.take_along_axis(hes_s, inv_order, axis=1)
         return lam, hes
 
-    lam, hes = jax.lax.map(one, (shape(score_pad), shape(lab), shape(gains),
-                                 shape(real), shape(inv_mdcg)))
-    lam = lam.reshape(-1, S)[:Q]
-    hes = hes.reshape(-1, S)[:Q]
+    with jax.named_scope("lgbm.gradient.pairs"):
+        lam, hes = jax.lax.map(one, (shape(score_pad), shape(lab),
+                                     shape(gains), shape(real),
+                                     shape(inv_mdcg)))
+        lam = lam.reshape(-1, S)[:Q]
+        hes = hes.reshape(-1, S)[:Q]
     return lam, hes
 
 
@@ -170,14 +172,16 @@ class DeviceLambdarank:
         grad = jnp.zeros(self.n + 1, self.dtype)
         hess = jnp.zeros(self.n + 1, self.dtype)
         for b in self._buckets:
-            sp = ext[b["idx"]]
+            with jax.named_scope("lgbm.gradient.scatter"):
+                sp = ext[b["idx"]]
             lam, hes = _lambda_bucket(sp, b["lab"], b["gains"], b["real"],
                                       b["inv"], b["disc"],
                                       jnp.asarray(self.sigmoid, self.dtype),
                                       chunk=b["chunk"])
-            flat = jnp.where(b["real"], b["idx"], self.n).reshape(-1)
-            grad = grad.at[flat].add(lam.reshape(-1), mode="drop")
-            hess = hess.at[flat].add(hes.reshape(-1), mode="drop")
+            with jax.named_scope("lgbm.gradient.scatter"):
+                flat = jnp.where(b["real"], b["idx"], self.n).reshape(-1)
+                grad = grad.at[flat].add(lam.reshape(-1), mode="drop")
+                hess = hess.at[flat].add(hes.reshape(-1), mode="drop")
         return grad[:self.n], hess[:self.n]
 
 

@@ -562,13 +562,14 @@ class ContinuousLearningSupervisor:
             self.base_dataset is not None
             and getattr(self.base_dataset, "_binned", None) is not None) \
             else None
-        ds = Dataset(X, label=y, weight=w, params=params, reference=ref)
-        new = engine.train(params, ds,
-                           num_boost_round=cfg.tpu_refit_rounds,
-                           init_model=live_booster, verbose_eval=False)
-        new._gbdt._sync_model()
-        merged = Booster(model_str=live_booster.model_to_string(),
-                         params=params)
+        with log.keep_level():      # train_params' verbosity is ours alone
+            ds = Dataset(X, label=y, weight=w, params=params, reference=ref)
+            new = engine.train(params, ds,
+                               num_boost_round=cfg.tpu_refit_rounds,
+                               init_model=live_booster, verbose_eval=False)
+            new._gbdt._sync_model()
+            merged = Booster(model_str=live_booster.model_to_string(),
+                             params=params)
         merged._gbdt.models.extend(new._gbdt.models)
         return merged
 
@@ -722,8 +723,9 @@ class ContinuousLearningSupervisor:
             raise ValueError("force_promote needs exactly one of "
                              "model_str / booster")
         if booster is None:
-            booster = Booster(model_str=model_str,
-                              params=dict(self.train_params))
+            with log.keep_level():
+                booster = Booster(model_str=model_str,
+                                  params=dict(self.train_params))
         booster._gbdt._sync_model()
         with self._tick_lock:
             now = time.monotonic()
@@ -796,7 +798,7 @@ class ContinuousLearningSupervisor:
             cand_path = os.path.join(self.root, CANDIDATE_FILE)
             if os.path.exists(cand_path):
                 try:
-                    with open(cand_path) as f:
+                    with open(cand_path) as f, log.keep_level():
                         cand = Booster(model_str=f.read(),
                                        params=dict(self.train_params))
                     with self._state_lock:

@@ -34,8 +34,15 @@ def configure_compile_cache() -> None:
     directory is set in code.  Unset: `DEFAULT_CACHE_DIR`.  Either way
     every program is kept, however short its compile: one training run
     is a few large programs plus some hundred sub-second ones, which
-    JAX's default 1 s floor would recompile in every process."""
+    JAX's default 1 s floor would recompile in every process; and an
+    entry is found again only by a program with the same op metadata."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # The program names its device work through op metadata
+    # (jax.named_scope -> HLO op_name -> the profiler's tf_op).  JAX's
+    # default cache key leaves metadata out, so an executable cached
+    # before a scope was added or moved would come back with the old
+    # names in every later trace; with it in the key such an entry misses.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)

@@ -16,6 +16,7 @@ from __future__ import annotations
 import json
 import sys
 import time
+from contextlib import contextmanager
 from typing import Any, Callable, Dict, Optional
 
 FATAL = -1
@@ -47,6 +48,20 @@ def set_level(level: int) -> None:
 
 def get_level() -> int:
     return _level
+
+
+@contextmanager
+def keep_level():
+    """Put the level back on exit.  `verbosity` in a parameter set moves
+    the process-wide level (Config.set); a component that builds boosters
+    from parameters of its own inside a longer-lived process (the
+    continuous-learning supervisor inside a server) wraps that work in
+    this, so that its quiet training does not silence its host."""
+    level = _level
+    try:
+        yield
+    finally:
+        set_level(level)
 
 
 def set_level_by_name(name: str) -> None:

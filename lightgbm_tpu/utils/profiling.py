@@ -3,21 +3,25 @@
 The reference accumulates per-phase std::chrono durations in the tree
 learner and prints them at destruction (serial_tree_learner.cpp:15-42)
 plus per-iteration wall clock in GBDT::Train (gbdt.cpp:251-254).  On TPU
-the compute phases live inside ONE compiled lax.while_loop, so in-graph
-phase attribution is impossible from the host; the subsystem therefore
-has two halves:
+the compute phases live inside ONE compiled lax.while_loop, which the
+host's clock cannot see into; so time is named in two places:
 
 - this module: host-side phase accumulators around every dispatch the
   driver makes (gradients / grow / drain / score / eval), with an
   optional per-phase device sync so the numbers mean device time and
   not dispatch time.  Enabled via Config.tpu_profile; report printed at
   booster teardown (GBDT.__del__) or on demand via profile_report().
-- tools/phase_bench.py: standalone microbenchmarks of the device
-  kernels (partition / segment-histogram / split-scan / label recovery)
-  at real workload shapes — the in-loop attribution the host cannot see.
+  Every phase is also a span of obs/tracing.py (``lgbm:<phase>`` in a
+  jax.profiler trace), whether the accumulators are on or not.
+- inside the device programs, ``jax.named_scope("lgbm.<purpose>")``
+  around the gradient, the quantisation, each part of the growth loop
+  and the score update: in a jax.profiler trace every device operation
+  carries its scope in its ``tf_op`` path, so the in-loop attribution
+  comes from the trace of the real iteration (docs/Tracing.md lists the
+  names; ``benchmarks/readers/trace_scope.py`` sums them).
 
 jax.profiler traces: set Config.tpu_profile_trace_dir to wrap training
-in start_trace/stop_trace for TensorBoard-level analysis.
+in start_trace/stop_trace; the ``.xplane.pb`` holds both kinds of name.
 """
 from __future__ import annotations
 
@@ -54,41 +58,32 @@ class Profiler:
 
     @contextmanager
     def phase(self, name: str):
-        # every phase site doubles as a span site: the tracer records a
-        # nested span for this phase even when the accumulators are off,
-        # so tpu_trace_path alone yields a full timeline.  The span
-        # closes AFTER sync_fn, so it covers device time like the clock.
-        tracer = tracing.get_tracer()
-        span = tracer.span(name, "phase") if tracer.enabled else None
-        if not self.enabled and span is None:
-            yield
-            return
-        if span is not None:
-            span.__enter__()
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            if self.sync_fn is not None:
-                try:
-                    self.sync_fn()
-                except Exception as exc:  # noqa: BLE001 — must not kill train
-                    log.debug("profiler sync failed: %s", exc)
-            if span is not None:
-                try:
-                    span.__exit__(None, None, None)
-                except Exception as exc:  # noqa: BLE001
-                    log.debug("profiler span exit failed: %s", exc)
-            dt = time.perf_counter() - start
+        # every phase site is a span site (obs/tracing.span): an
+        # lgbm:<name> annotation in a jax.profiler trace, and a recorded
+        # span when tpu_trace_path arms the tracer, with the accumulators
+        # on or off.  The span closes AFTER sync_fn, so it covers device
+        # time like the clock.
+        with tracing.span(name, "phase"):
             if not self.enabled:
+                yield
                 return
-            with self._lock:
-                self.totals[name] = self.totals.get(name, 0.0) + dt
-                self.counts[name] = self.counts.get(name, 0) + 1
-                if dt < self.mins.get(name, float("inf")):
-                    self.mins[name] = dt
-                if dt > self.maxs.get(name, float("-inf")):
-                    self.maxs[name] = dt
+            start = time.perf_counter()
+            try:
+                yield
+            finally:
+                if self.sync_fn is not None:
+                    try:
+                        self.sync_fn()
+                    except Exception as exc:  # noqa: BLE001 — must not kill train
+                        log.debug("profiler sync failed: %s", exc)
+                dt = time.perf_counter() - start
+                with self._lock:
+                    self.totals[name] = self.totals.get(name, 0.0) + dt
+                    self.counts[name] = self.counts.get(name, 0) + 1
+                    if dt < self.mins.get(name, float("inf")):
+                        self.mins[name] = dt
+                    if dt > self.maxs.get(name, float("-inf")):
+                        self.maxs[name] = dt
 
     def reset(self) -> None:
         """Zero every accumulator and restart the wall clock — serving

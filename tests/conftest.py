@@ -65,6 +65,16 @@ def _clear_jax_caches_per_module():
     jax.clear_caches()
 
 
+@pytest.fixture(autouse=True)
+def _log_level_per_test():
+    """`verbosity` sets the process-wide log level (Config.set, as in the
+    reference); a test that trains with verbose=-1 must not silence the
+    warnings the next test in its worker asserts on."""
+    from lightgbm_tpu.utils import log
+    log.set_level(log.INFO)
+    yield
+
+
 def pytest_sessionstart(session):
     assert jax.default_backend() == "cpu", (
         "tests must run on the virtual CPU platform, got %s" % jax.default_backend())

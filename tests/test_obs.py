@@ -314,6 +314,29 @@ def test_log_set_level_by_name(capsys):
         log.set_level_by_name("chatty")
 
 
+@pytest.mark.parametrize("verbosity,level", [
+    (-1, log.FATAL), (0, log.WARNING), (1, log.INFO), (2, log.DEBUG),
+    ("3", log.DEBUG), (-7, log.FATAL)])
+def test_verbosity_sets_the_level_and_keep_level_restores_it(
+        capsys, verbosity, level):
+    """An explicit verbosity moves the process-wide level as the params
+    are parsed (the reference's Config::Set); a Config without one leaves
+    it; keep_level() hands the level back (the supervisor's quiet refits
+    inside a server)."""
+    from lightgbm_tpu.config import Config
+    with log.keep_level():
+        Config({"verbose": verbosity})
+        assert log.get_level() == level
+        Config({"num_leaves": 7})
+        assert log.get_level() == level
+        log.warning("inside %s", verbosity)
+    assert log.get_level() == log.INFO
+    log.warning("outside")
+    err = capsys.readouterr().err
+    assert ("inside" in err) == (level >= log.WARNING)
+    assert "outside" in err
+
+
 def test_profiler_reset_and_minmax():
     from lightgbm_tpu.utils.profiling import Profiler
     p = Profiler(enabled=True)

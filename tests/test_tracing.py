@@ -94,16 +94,30 @@ def test_span_nesting_and_file_format(tmp_path):
     assert {"process_name", "thread_name", "process_sort_index"} <= m_names
 
 
-def test_zero_cost_when_disabled():
+def test_zero_cost_when_disabled(monkeypatch):
+    """Nothing of the tracer when it is off: no span object, no event, no
+    lock, no clock read.  What a disabled span leaves is the one
+    jax.profiler.TraceAnnotation every span enters (``lgbm:<name>`` in a
+    profiler trace; a no-op while no profiler session runs)."""
+    from jax.profiler import TraceAnnotation
     tr = tracing.get_tracer()
     assert not tr.enabled
-    cm1 = tracing.span("x", "y")
-    cm2 = tracing.span("z")
-    assert cm1 is cm2                   # the one shared nullcontext
-    with cm1:
-        pass
+
+    def forbidden(*args, **kwargs):
+        raise AssertionError("a disabled span reached into the tracer")
+
+    monkeypatch.setattr(tracing, "_Span", forbidden)        # no allocation
+    monkeypatch.setattr(tracing, "time", None)              # no clock read
+    monkeypatch.setattr(tr, "_lock", None)                  # no lock
+    events = len(tr._events)
+    cm = tracing.span("x", "y", iter=3)
+    assert type(cm) is TraceAnnotation
+    with cm:
+        with tracing.span("z"):
+            pass
     tracing.instant("nope")
     tracing.complete("nope", 0.1)
+    assert len(tr._events) == events
     assert tracing.current_context() == ("", 0)
     assert tracing.flush() is None
 
@@ -459,7 +473,7 @@ class _FakeGBDT:
 def test_recorder_midwrite_failure_degrades_to_warning(tmp_path, capsys):
     from lightgbm_tpu.obs.recorder import TrainingRecorder
     path = str(tmp_path / "t.jsonl")
-    rec = TrainingRecorder(path, Config({"verbose": "-1"}))
+    rec = TrainingRecorder(path, Config({"verbose": "0"}))  # warnings on
     g = _FakeGBDT()
     rec.on_iteration(g, 0, 0.01, False)
     rec.on_iteration(g, 1, 0.01, False)     # flushes iter 0 to disk

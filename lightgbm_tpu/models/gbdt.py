@@ -1325,8 +1325,9 @@ class GBDT:
             fused_vmem = (
                 2 * C * pp.TILE * 2                       # in_buf bf16
                 + (pp.TILE // pp.SUB) * pp.SUB * 2 * pp.SUB * 2   # P_all
-                + 2 * C * pp.CARRY_W * 4                  # carries f32
-                + 4 * C * pp.FLUSH_W * 2                  # flush bufs
+                + 2 * C * pp.FLUSH_W * 4                  # carries f32
+                # (the flush staging is not in this sum: the kernel asks
+                # for more VMEM when it needs it, pp._partition_vmem_limit)
                 + 2 * pp.TILE * 4                         # pred bufs
                 + nb_r * (f_blk // m_r) * payload * hi_n * m_r * 128 * 4)
             bounds = (

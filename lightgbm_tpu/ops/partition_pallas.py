@@ -34,14 +34,17 @@ reference GPU learner's single-precision default.
 
 Pipeline invariant in both kernels: tile j's read is complete when its
 loop iteration starts; iteration j issues read j+1, computes j (overlapped
-with that read), then waits read j+1.  In `partition_segment` the output
-writes are issued only after that wait, which makes the in-place stream
-safe: writes span at most (j+1)*tile + SUB columns past the segment start
-while reads through (j+2)*tile have completed.
+with that read), then waits read j+1.  `partition_segment` issues tile
+j's output writes (the FLUSH_W chunks its appends completed) at the end
+of iteration j's compute, beside read j+1 still in flight.  The in-place
+stream is safe all the same: those writes end at most (j+1)*tile columns
+past the segment start, columns read before iteration j began, and read
+j+1 covers the tile after them.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +151,10 @@ def _align8(rows: int) -> int:
     return -(-rows // 8) * 8
 
 
+_VMEM_DEFAULT = 16 << 20     # Mosaic's scoped VMEM limit when none is given
+_VMEM_PER_CHANNEL = 40 << 10  # partition_segment's VMEM per arena channel
+
+
 def _side_effect_params():
     """The kernels that write HBM through manual DMAs must not be
     dead-code-eliminated or reordered as pure functions."""
@@ -183,7 +190,10 @@ FLUSH_W = SUB          # flush chunk width; all HBM write offsets are
 #                        128 RE-TESTED with the sort-P kernel (round 5):
 #                        21.8 vs 22.9 Mrows*iter/s — narrower carries
 #                        don't pay for the doubled flush DMAs here either
-CARRY_W = FLUSH_W + SUB    # per-stream carry width (append window)
+CARRY_W = FLUSH_W + SUB    # compact_carry's carry width (append window)
+# partition_segment's append rotates inside one FLUSH_W window and completes
+# at most one chunk
+assert FLUSH_W == SUB
 
 
 def _sort_P(pref2, pred2, K: int):
@@ -215,6 +225,69 @@ def _sort_P(pref2, pred2, K: int):
         jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
 
 
+def _decide(block, feat_onehot_ref, mask_ref, xr):
+    """In-kernel split decision of one tile: [1, T] f32, 1.0 -> stream A.
+
+    The arena column of the split feature is read with a one-hot matvec
+    over channels (feat_onehot_ref [1, C]; bins < 256 are bf16-exact),
+    then routed through the go-left MASK VECTOR (mask_ref [1, MB]:
+    mask[v] == 1 -> bin value v goes left), XOR'd with xr.  The mask is
+    built in XLA per split and encodes ALL decision semantics — numerical
+    threshold + missing direction (NumericalDecision, tree.h:429-465),
+    categorical bitsets (CategoricalDecision, tree.h:259-273) and EFB
+    bundle-local bin ranges — so the kernel needs no per-kind logic."""
+    tile = block.shape[1]
+    col = jnp.round(jax.lax.dot(feat_onehot_ref[:], block,
+                                preferred_element_type=jnp.float32)
+                    ).astype(jnp.int32)                   # [1, T]
+    MB = mask_ref.shape[1]
+    col_onehot = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (MB, tile), 0)
+        == col.reshape(1, tile),
+        jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
+    go_left_f = jax.lax.dot(mask_ref[:], col_onehot,
+                            preferred_element_type=jnp.float32)
+    xr_f = jnp.float32(xr)
+    return go_left_f + xr_f - 2.0 * go_left_f * xr_f      # xor
+
+
+def _append_plan(fill, counts):
+    """Scalar plan of one stream's K appends of a tile, straight-line from
+    the tile-start `fill` (< FLUSH_W) and the sub-blocks' row counts: per
+    append the carry fill it meets, whether it completes a FLUSH_W chunk
+    (at most one: an append adds <= SUB = FLUSH_W rows) and how many
+    chunks the tile completed before it.  Returns (fills, flushed,
+    chunk_no, end) with `end` = fill + sum(counts)."""
+    fills, flushed, chunk_no = [], [], []
+    t = fill
+    for c in counts:
+        done = jax.lax.div(t, jnp.int32(FLUSH_W))
+        fills.append(t - done * FLUSH_W)
+        chunk_no.append(done)
+        t = t + c
+        flushed.append(jax.lax.div(t, jnp.int32(FLUSH_W)) > done)
+    return fills, flushed, chunk_no, t
+
+
+def _staging_shape(C: int, tile: int) -> tuple:
+    """partition_segment's staging slots: (stream, parity, append k)."""
+    return (2, 2, tile // SUB, C, FLUSH_W)
+
+
+def _partition_vmem_limit(C: int, hist_shapes):
+    """None (Mosaic's default) while the kernel fits it, else what it
+    needs.  Measured by compiling for a v5e: 17.75 MB at C = 512 without
+    the histogram output, under 16 MiB at C = 448; _VMEM_PER_CHANNEL
+    leaves 15 % over that slope, and the fused histogram's accumulator
+    counts twice (the output and the products added into it).  The limit
+    is raised only when it must be: XLA runs the fusions around the
+    kernel slower under a larger one (higgs, C = 48: 0.8 ms per
+    iteration in `broadcast_select_fusion`, PERF.md PR 26)."""
+    need = C * _VMEM_PER_CHANNEL + sum(
+        2 * 4 * math.prod(h.shape) for h in hist_shapes)
+    return need if need > _VMEM_DEFAULT else None
+
+
 def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
                       out_any, cnt_ref, *rest,
                       C: int, tile: int, hist_plan=None):
@@ -223,45 +296,58 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
     bump allocator aligns).
     arena_any/out_any: [C, cap] bf16 in HBM, aliased (same buffer).
     Routing: mode=0 reads pred_any ([1, cap] f32, 1.0 -> stream A);
-    mode=1 computes the split decision in-kernel — the feature row is
-    extracted with a one-hot matvec (feat_onehot_ref [1, C], bins < 256
-    are bf16-exact) and routed through mask_ref ([1, 256] bf16 0/1:
-    mask[v] == 1 -> arena value v goes left), XOR'd with xr (1 when the
-    left child is the smaller/bump-allocated stream-B side).  The caller
-    bakes ALL decision semantics (numerical threshold, missing
-    direction, categorical bitsets, EFB ranges) into the mask.
+    mode=1 computes the split decision in-kernel (`_decide`), XOR'd with
+    xr (1 when the left child is the smaller/bump-allocated stream-B
+    side).  The caller bakes ALL decision semantics (numerical threshold,
+    missing direction, categorical bitsets, EFB ranges) into the mask.
     cnt_ref (SMEM out [2] i32): rows written to A and B.
 
     Each SUB-lane sub-block is compacted with an MXU permutation matmul
-    and appended into a narrow per-stream VMEM carry via dynamic-shift
-    roll + add (appends are disjoint); whenever a carry holds FLUSH_W
-    rows, that chunk is DMA'd to the stream's next FLUSH_W-aligned arena
-    columns.  Stream A may write over the parent segment in place: flushed
-    columns [dstA + wA, +FLUSH_W) always lie within the rows already read,
-    because wA + FLUSH_W <= rows consumed so far <= (j+1)*tile and tile j
-    is fully read before its sub-blocks are appended.
+    and appended onto its stream's carry, a [C, FLUSH_W] f32 window that
+    holds the rows not yet written (fill < FLUSH_W between appends).  The
+    tile's 2K appends are STRAIGHT-LINE code: every shift, fill, flush
+    predicate and flush destination is a function of the tile-start
+    (fill, written) and the sub-blocks' counts, all known from the prefix
+    scan before the first matmul (`_append_plan`), so no scalar
+    recurrence and no conditional region sits between two appends.  An
+    append rolls its chunk to the carry's fill (a rotation inside the
+    FLUSH_W window: what wraps past the window's end is the start of the
+    next chunk), adds the unwrapped part, stores the window — complete or
+    not — as bf16 into a staging slot with a static index (stream,
+    parity, k), and keeps as the new carry the window (no chunk
+    completed) or the wrapped part (chunk completed).  After the last
+    append the completed slots are DMA'd to the stream's next
+    FLUSH_W-aligned arena columns, under their predicates; those DMAs are
+    waited, under the same predicates (one bit mask per stream and tile in
+    the loop state), before the parity is staged again two tiles later,
+    and after the loop: exactly one wait per started DMA.
+
+    Stream A may write over the parent segment in place: tile j's flushes
+    reach at most dstA + wA + FLUSH_W <= start + (j+1)*tile, columns whose
+    reads (tiles 0..j) completed before iteration j began, and the read of
+    tile j+1 in flight beside them is disjoint from them.
     """
     if hist_plan is None:
         hist_ref = None
-        (in_buf, pred_buf, carryA, carryB, flush_buf,
+        (in_buf, pred_buf, carryA, carryB, stage,
          read_sems, pred_sems, write_sems) = rest
     else:
         # fused smaller-child histogram: one extra VMEM output, stream-B
         # rows accumulated with the radix contraction while they are
         # already in VMEM for compaction — saves the separate
         # segment_histogram kernel launch AND its re-read of the child
-        (hist_ref, in_buf, pred_buf, carryA, carryB, flush_buf,
+        (hist_ref, in_buf, pred_buf, carryA, carryB, stage,
          read_sems, pred_sems, write_sems) = rest
         hist_ref[:] = jnp.zeros_like(hist_ref)
     s, cnt = sc_ref[0], sc_ref[1]
-    dstA, dstB = sc_ref[2], sc_ref[3]
+    dsts = (sc_ref[2], sc_ref[3])
     mode = sc_ref[4]
     xr = sc_ref[5]    # XOR'd into the decision: 1 when the left child is
     #                   the smaller (stream-B) side
     hs = sc_ref[6]    # fused-histogram stream: 1 -> B, 0 -> A
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
     K = tile // SUB
-    lane_w = jax.lax.broadcasted_iota(jnp.int32, (C, CARRY_W), 1)
+    lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
 
     def read_dmas(j, slot):
         src = pl.multiple_of(s + j * tile, 128)
@@ -278,11 +364,20 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
                     pred_any.at[:, pl.ds(pl.multiple_of(psrc, 128), tile)],
                     pred_buf.at[slot], pred_sems.at[slot]))
 
-    def flush_dma(stream, slot, dst_col):
+    def flush_dma(stream, slot, k, dst_col):
         return pltpu.make_async_copy(
-            flush_buf.at[stream, slot],
+            stage.at[stream, slot, k],
             out_any.at[:, pl.ds(pl.multiple_of(dst_col, 128), FLUSH_W)],
-            write_sems.at[stream, slot])
+            write_sems.at[stream, slot, k])
+
+    def wait_flushes(slot, pending):
+        """Wait the flushes a tile started from parity `slot`; `pending`
+        holds per stream the bit mask of the appends that flushed."""
+        for stream in range(2):
+            for k in range(K):
+                @pl.when(((pending[stream] >> k) & 1) == 1)
+                def _(stream=stream, k=k):
+                    flush_dma(stream, slot, k, 0).wait()
 
     @pl.when(n_tiles > 0)
     def _():
@@ -290,54 +385,32 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
             d.start()
         for d in read_dmas(0, 0):
             d.wait()
-    carryA[:] = jnp.zeros((C, CARRY_W), jnp.float32)
-    carryB[:] = jnp.zeros((C, CARRY_W), jnp.float32)
+    carryA[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
+    carryB[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
 
-    def append_and_flush(carry, chunk, lo, ck, fill, written, dst, stream,
-                         fslot):
+    def append(carry, chunk, lo, fill, flushed, stage_at):
         """chunk ([C, SUB] f32) holds this stream's rows at lanes
-        [lo, lo+ck), zeros elsewhere (masked OFF the serial chain, in
-        the parallel region after the sort matmuls); circular-roll them
-        onto carry lanes [fill, fill+ck) (fill + ck <= CARRY_W by the
-        flush invariant, so the rotation never wraps values).  Then
-        flush filled FLUSH_W chunks (up to ceil(SUB/FLUSH_W) per append
-        when FLUSH_W < SUB).  The carry is f32 precisely so the
-        positioning can be a dynamic pltpu.roll (32-bit-only op)
-        instead of MXU MACs; values are exact bf16 payloads so the
-        f32->bf16 cast at flush is lossless.
-        Returns (fill', written', fslot')."""
-        padded = jnp.concatenate(
-            [chunk, jnp.zeros((C, CARRY_W - SUB), jnp.float32)], axis=1)
-        shift = jax.lax.rem(fill - lo + jnp.int32(CARRY_W),
-                            jnp.int32(CARRY_W))
-        carry[:] = carry[:] + pltpu.roll(padded, shift, axis=1)
-        fill = fill + ck
+        [lo, lo+ck), zeros elsewhere; rotate them onto window lanes
+        [fill, fill+ck) mod FLUSH_W.  Lanes >= fill continue the carry's
+        chunk, lanes < fill are what wrapped: the head of the next chunk.
+        The carry is f32 precisely so the positioning can be a dynamic
+        pltpu.roll (32-bit-only op) instead of MXU MACs; values are exact
+        bf16 payloads so the f32->bf16 cast at the staging store is
+        lossless.  Returns the new carry."""
+        rolled = pltpu.roll(chunk, (fill - lo) & (FLUSH_W - 1), axis=1)
+        tail = lane_s >= fill
+        window = carry + jnp.where(tail, rolled, jnp.float32(0.0))
+        stage[stage_at] = window.astype(ARENA_DT)
+        keep = lane_s < jnp.where(flushed, 0, FLUSH_W)
+        return jnp.where(keep, window,
+                         jnp.where(tail, jnp.float32(0.0), rolled))
 
-        for _ in range(-(-SUB // FLUSH_W)):
-            @pl.when(fill >= FLUSH_W)
-            def _(fill=fill, written=written, fslot=fslot):
-                # previous flush of this slot (2 flushes ago) must have landed
-                @pl.when(written >= 2 * FLUSH_W)
-                def _():
-                    flush_dma(stream, fslot, 0).wait()
-                flush_buf[stream, fslot] = carry[:, 0:FLUSH_W].astype(ARENA_DT)
-                flush_dma(stream, fslot, dst + written).start()
-                shifted = jnp.concatenate(
-                    [carry[:, FLUSH_W:CARRY_W],
-                     jnp.zeros((C, FLUSH_W), jnp.float32)], axis=1)
-                carry[:] = jnp.where(lane_w < fill - FLUSH_W, shifted,
-                                     jnp.float32(0.0))
-
-            flushed = fill >= FLUSH_W
-            fill = jnp.where(flushed, fill - FLUSH_W, fill)
-            written = jnp.where(flushed, written + FLUSH_W, written)
-            fslot = jnp.where(flushed, 1 - fslot, fslot)
-        return fill, written, fslot
-
-    def loop(j, carry_state):
-        fillA, wA, fsA, fillB, wB, fsB = carry_state
+    def loop(j, state):
+        fills, written, pending, pending2 = state
         slot = jax.lax.rem(j, jnp.int32(2))
-        nslot = jax.lax.rem(j + jnp.int32(1), jnp.int32(2))
+        nslot = 1 - slot
+        # tile j-2 staged this parity: its flushes must have landed
+        wait_flushes(slot, pending2)
 
         @pl.when(j + 1 < n_tiles)
         def _():
@@ -347,28 +420,9 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
         valid = jax.lax.broadcasted_iota(
             jnp.int32, (1, tile), 1) < (cnt - j * tile)
         block = in_buf[slot]
-        # in-kernel split decision (mode 1): the arena column is read with
-        # a one-hot matvec over channels, then routed through the go-left
-        # MASK VECTOR (mask_ref [1, MB]: mask[v] == 1 -> bin value v goes
-        # left).  The mask is built in XLA per split and encodes ALL
-        # decision semantics — numerical threshold + missing direction
-        # (NumericalDecision, tree.h:429-465), categorical bitsets
-        # (CategoricalDecision, tree.h:259-273) and EFB bundle-local bin
-        # ranges — so the kernel needs no per-kind logic.
-        col = jnp.round(jax.lax.dot(feat_onehot_ref[:], block,
-                                    preferred_element_type=jnp.float32)
-                        ).astype(jnp.int32)                   # [1, T]
-        MB = mask_ref.shape[1]
-        col_onehot = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (MB, tile), 0)
-            == col.reshape(1, tile),
-            jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
-        go_left_f = jax.lax.dot(mask_ref[:], col_onehot,
-                                preferred_element_type=jnp.float32)
-        xr_f = jnp.float32(xr)
-        decide_f = go_left_f + xr_f - 2.0 * go_left_f * xr_f   # xor
         mode_f = jnp.float32(mode)
-        on_f = mode_f * decide_f + (1.0 - mode_f) * pred_buf[slot]
+        on_f = (mode_f * _decide(block, feat_onehot_ref, mask_ref, xr)
+                + (1.0 - mode_f) * pred_buf[slot])
         on = on_f > 0.5
         predA = jnp.where(valid & on, jnp.float32(1.0), jnp.float32(0.0))
         predB = jnp.where(valid & ~on, jnp.float32(1.0), jnp.float32(0.0))
@@ -382,13 +436,10 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
                               payload=pay_h)
 
         # ONE batched prefix scan for all subblocks of both streams — the
-        # per-subblock scans were 2*K*log2(SUB) serial roll steps, the
-        # kernel's dominant latency.  Then ONE batched P build and K
-        # dependency-free SORT matmuls ([C,S]@[S,S]: A-prefix + B-suffix
-        # in a single product — half the MACs of the dual-stream [S,2S]
-        # build): nothing on the MXU path waits on the serial carry/fill
-        # chain (that chain is cheap VPU mask/roll/add work), so the
-        # systolic array stays fed.
+        # per-subblock scans were 2*K*log2(SUB) serial roll steps.  Then
+        # ONE batched P build and K dependency-free SORT matmuls
+        # ([C,S]@[S,S]: A-prefix + B-suffix in a single product — half the
+        # MACs of the dual-stream [S,2S] build).
         pred2 = jnp.concatenate(
             [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
         pref2 = _prefix_scan_lanes(pred2)                  # [2K, SUB]
@@ -397,58 +448,73 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
         comps = [jax.lax.dot(block[:, k * SUB:(k + 1) * SUB], P_all[k],
                              preferred_element_type=jnp.float32)
                  for k in range(K)]                        # [C, S] f32
-        # split each sorted block into its A-prefix / B-suffix OFF the
-        # serial carry chain (depends only on cnt2, not on fill); the
+        # split each sorted block into its A-prefix / B-suffix; the
         # B chunk is a subtraction, not a second select
-        lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
-        chunksA = [jnp.where(lane_s < cnt2[k], comps[k], jnp.float32(0.0))
+        cA = [cnt2[k] for k in range(K)]
+        cB = [cnt2[K + k] for k in range(K)]
+        chunksA = [jnp.where(lane_s < cA[k], comps[k], jnp.float32(0.0))
                    for k in range(K)]
         chunksB = [comps[k] - chunksA[k] for k in range(K)]
+
+        plans = (_append_plan(fills[0], cA), _append_plan(fills[1], cB))
+        zero = jnp.int32(0)
+        carries = [carryA[:], carryB[:]]
         for k in range(K):
-            ca, cb = cnt2[k], cnt2[K + k]
-            fillA, wA, fsA = append_and_flush(
-                carryA, chunksA[k], jnp.int32(0), ca, fillA, wA, dstA, 0,
-                fsA)
-            fillB, wB, fsB = append_and_flush(
-                carryB, chunksB[k], ca, cb, fillB, wB, dstB, 1, fsB)
+            for stream, chunk, lo in ((0, chunksA[k], zero),
+                                      (1, chunksB[k], cA[k])):
+                p_fill, p_flushed, _, _ = plans[stream]
+                carries[stream] = append(
+                    carries[stream], chunk, lo, p_fill[k], p_flushed[k],
+                    (stream, slot, k))
+        carryA[:], carryB[:] = carries
+
+        new_fills, new_written, new_pending = [], [], []
+        for stream in range(2):
+            _, p_flushed, p_chunk_no, end = plans[stream]
+            bits = zero
+            for k in range(K):
+                @pl.when(p_flushed[k])
+                def _(stream=stream, k=k, chunk_no=p_chunk_no[k]):
+                    flush_dma(stream, slot, k,
+                              dsts[stream] + written[stream]
+                              + chunk_no * FLUSH_W).start()
+                bits = bits | (p_flushed[k].astype(jnp.int32) << k)
+            done = jax.lax.div(end, jnp.int32(FLUSH_W))
+            new_fills.append(end - done * FLUSH_W)
+            new_written.append(written[stream] + done * FLUSH_W)
+            new_pending.append(bits)
 
         @pl.when(j + 1 < n_tiles)
         def _():
             for d in read_dmas(j + 1, nslot):
                 d.wait()
-        return fillA, wA, fsA, fillB, wB, fsB
+        return (tuple(new_fills), tuple(new_written), tuple(new_pending),
+                pending)
 
-    z = jnp.int32(0)
-    fillA, wA, fsA, fillB, wB, fsB = jax.lax.fori_loop(
-        0, n_tiles, loop, (z, z, z, z, z, z))
+    z2 = (jnp.int32(0), jnp.int32(0))
+    fills, written, pending, pending2 = jax.lax.fori_loop(
+        0, n_tiles, loop, (z2, z2, z2, z2))
 
-    # Final partial flush, then drain every in-flight DMA.  With c = w /
-    # FLUSH_W loop flushes, the in-loop waits consumed the signals of
-    # flushes 0..c-3; flushes c-2 (slot fslot) and c-1 (slot 1-fslot) are
-    # still outstanding and every one must be waited before kernel exit.
-    for stream, carry, fill, w, dst, fslot in (
-            (0, carryA, fillA, wA, dstA, fsA),
-            (1, carryB, fillB, wB, dstB, fsB)):
-        @pl.when(fill > 0)
-        def _(stream=stream, carry=carry, fill=fill, w=w, dst=dst,
-              fslot=fslot):
-            @pl.when(w >= 2 * FLUSH_W)
-            def _():
-                flush_dma(stream, fslot, 0).wait()     # flush c-2
-            flush_buf[stream, fslot] = carry[:, 0:FLUSH_W].astype(ARENA_DT)
-            flush_dma(stream, fslot, dst + w).start()
-            flush_dma(stream, fslot, 0).wait()         # the final flush
+    # Drain the last two tiles' flushes (parities of tiles n-2 and n-1),
+    # then write each stream's partial chunk from a staging slot that is
+    # free again.
+    last = jax.lax.rem(n_tiles + jnp.int32(1), jnp.int32(2))
+    wait_flushes(1 - last, pending2)
+    wait_flushes(last, pending)
+    for stream, carry in ((0, carryA), (1, carryB)):
+        @pl.when(fills[stream] > 0)
+        def _(stream=stream, carry=carry):
+            # + 0.0: rows that wrapped in the tile's last flushing append
+            # have not passed through an add yet (-0.0 -> +0.0, as every
+            # other row)
+            stage[stream, 0, 0] = (carry[:] + jnp.float32(0.0)
+                                   ).astype(ARENA_DT)
+            final = flush_dma(stream, 0, 0, dsts[stream] + written[stream])
+            final.start()
+            final.wait()
 
-        @pl.when((fill == 0) & (w >= 2 * FLUSH_W))
-        def _(stream=stream, fslot=fslot):
-            flush_dma(stream, fslot, 0).wait()         # flush c-2
-
-        @pl.when(w >= FLUSH_W)
-        def _(stream=stream, fslot=fslot):
-            flush_dma(stream, 1 - fslot, 0).wait()     # flush c-1
-
-    cnt_ref[0] = wA + fillA
-    cnt_ref[1] = wB + fillB
+    cnt_ref[0] = written[0] + fills[0]
+    cnt_ref[1] = written[1] + fills[1]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret",
@@ -535,15 +601,17 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
         scratch_shapes=[
             pltpu.VMEM((2, C, tile), ARENA_DT),
             pltpu.VMEM((2, 1, tile), jnp.float32),
-            pltpu.VMEM((C, CARRY_W), jnp.float32),
-            pltpu.VMEM((C, CARRY_W), jnp.float32),
-            pltpu.VMEM((2, 2, C, FLUSH_W), ARENA_DT),
+            pltpu.VMEM((C, FLUSH_W), jnp.float32),
+            pltpu.VMEM((C, FLUSH_W), jnp.float32),
+            pltpu.VMEM(_staging_shape(C, tile), ARENA_DT),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2, 2, tile // SUB)),
         ],
         input_output_aliases={3: 0},
-        compiler_params=_side_effect_params(),
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True,
+            vmem_limit_bytes=_partition_vmem_limit(C, out_shape[2:])),
         interpret=interpret,
     )(sc, feat_onehot, goleft, arena, pred)
     if not with_hist:
@@ -1245,9 +1313,11 @@ _ARENA_B = 2  # bf16 arena element
 def _cost_partition(rows: int, features: int) -> KernelCost:
     """Stream a parent segment once and write both children (same total
     rows): 2x the segment's arena footprint plus the pred plane slice.
-    The per-sub-block permutation matmuls are DMA-overlapped, so FLOPs
-    count only the 2*SUB MACs per row that fill otherwise-idle lanes —
-    this kernel lives on the bandwidth roof by design."""
+    FLOPs count the 2*SUB MACs per row of the permutation matmuls.  The
+    bytes are the kernel's floor, not its cost: on the v5e the tile body
+    (decision, P build, the sort matmuls, the appends) takes several
+    times the tile's DMA time, so the kernel runs at a small share of
+    this roofline (PERF.md, section 5, has the measured share)."""
     n = int(rows)
     row_b = _ARENA_B * arena_channels(int(features))
     return KernelCost("partition/segment", 2 * n * row_b + n * 4,

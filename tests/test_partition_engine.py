@@ -10,10 +10,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-# interpret-mode Pallas dominates these — excluded from the
-# fast tier (pytest -m 'not slow'); run the full suite before
-# committing engine changes
-pytestmark = pytest.mark.slow
+# interpret-mode Pallas dominates the whole-tree tests — they are excluded
+# from the fast tier (pytest -m 'not slow'); run the full suite before
+# committing engine changes.  The kernel-level tests at the end are fast.
+slow = pytest.mark.slow
 
 from lightgbm_tpu.ops import grow as g
 from lightgbm_tpu.ops import grow_partition as gp
@@ -65,6 +65,7 @@ def _case(rng, n=2500, F=6, B=48):
     return bins, grad, hess, nb, db, mt
 
 
+@slow
 def test_matches_label_engine(rng):
     bins, grad, hess, nb, db, mt = _case(rng)
     row0 = np.zeros(len(grad), np.int32)
@@ -75,6 +76,7 @@ def test_matches_label_engine(rng):
     np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
 
 
+@slow
 def test_matches_with_bagging(rng):
     bins, grad, hess, nb, db, mt = _case(rng)
     row0 = np.zeros(len(grad), np.int32)
@@ -85,6 +87,7 @@ def test_matches_with_bagging(rng):
     np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
 
 
+@slow
 def test_early_stop_dead_slots(rng):
     """Leaves < max_leaves leaves unused slots whose start=0 must not shadow
     the live segment at position 0 during label recovery."""
@@ -97,6 +100,7 @@ def test_early_stop_dead_slots(rng):
     np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
 
 
+@slow
 def test_missing_handling(rng):
     from lightgbm_tpu.ops.grow import MISSING_NAN, MISSING_ZERO
     bins, grad, hess, nb, db, mt = _case(rng)
@@ -110,6 +114,7 @@ def test_missing_handling(rng):
     np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
 
 
+@slow
 def test_max_depth(rng):
     bins, grad, hess, nb, db, mt = _case(rng)
     row0 = np.zeros(len(grad), np.int32)
@@ -120,6 +125,7 @@ def test_max_depth(rng):
     _assert_trees_equal(t1, t2)
 
 
+@slow
 def test_end_to_end_train_partition_engine(rng):
     """Full driver with tpu_tree_engine=partition (interpret on CPU)."""
     import lightgbm_tpu as lgb
@@ -156,6 +162,7 @@ def test_end_to_end_train_partition_engine(rng):
         assert acc > 0.85, (eng, acc)
 
 
+@slow
 def test_partition_kernel_stability(rng):
     """Sequence of in-place partitions preserves payloads exactly."""
     F = 4
@@ -192,6 +199,7 @@ def test_partition_kernel_stability(rng):
         cursor += ((nB + pp.FLUSH_W - 1) // pp.FLUSH_W) * pp.FLUSH_W
 
 
+@slow
 def test_deferred_stop_matches_eager(rng):
     """The deferred-tree pipeline must stop training on degenerate
     iterations exactly like the eager path (same model length and
@@ -246,6 +254,7 @@ def _train_both(X, y, extra=None, rounds=3, **ds_kw):
     return outs
 
 
+@slow
 def test_categorical_parity():
     """Partition engine handles categorical (bitset) splits via the
     go-left mask decision; trees must match the label engine."""
@@ -265,6 +274,7 @@ def test_categorical_parity():
     assert outs["partition"] == outs["label"]
 
 
+@slow
 def test_efb_bundle_parity():
     """EFB-bundled datasets run on the partition engine through the
     bundle-aware mask build + unbundled scans."""
@@ -290,6 +300,7 @@ def test_efb_bundle_parity():
     assert outs["partition"] == outs["label"]
 
 
+@slow
 def test_hist_pool_spill_matches_dense(rng):
     """A tiny slot cache (spill + recompute on every other split) must
     grow exactly the tree the unlimited cache grows."""
@@ -312,6 +323,7 @@ def test_hist_pool_spill_matches_dense(rng):
     np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
 
 
+@slow
 def test_hist_pool_booster_wide(rng):
     """histogram_pool_size engages the pooled cache at the Booster level
     and training still works."""
@@ -330,3 +342,185 @@ def test_hist_pool_booster_wide(rng):
     assert bst.num_trees() == 3
     pred = bst.predict(X)
     assert np.mean((pred > 0.5) == y) > 0.9
+
+
+# --------------------------------------------------------------------- #
+# partition_segment against a numpy stable partition (interpret mode)
+# --------------------------------------------------------------------- #
+_SHARES = ("none", "all", "half", "one_row", "all_but_one", "straddle")
+_COUNTS = (0, 1, 255, 256, 257, 2047, 2048, 2049, 3 * 2048 + 5)
+_CAP = 16 * pp.TILE
+_START, _DST_A, _DST_B = pp.TILE, 6 * pp.TILE, 11 * pp.TILE - 3 * pp.FLUSH_W
+
+
+def _go_left(share, cnt):
+    """Stream-A membership of the segment's rows."""
+    i = np.arange(cnt)
+    if share == "none":
+        return np.zeros(cnt, bool)
+    if share == "all":
+        return np.ones(cnt, bool)
+    if share == "half":
+        return np.random.RandomState(cnt).rand(cnt) < 0.5
+    if share == "one_row":
+        return i == cnt // 2
+    if share == "all_but_one":
+        return i != cnt // 3
+    # every sub-block gives one stream SUB - 1 rows: from the second on,
+    # each of its appends crosses a FLUSH_W boundary of the carry
+    return i % pp.SUB != 77
+
+
+_BASE = {}
+
+
+def _base_arena(C):
+    if C not in _BASE:
+        _BASE[C] = np.random.RandomState(C).randint(
+            0, 250, (C, _CAP)).astype(np.float32)
+    return _BASE[C].copy()
+
+
+def _aligned(n):
+    return -(-n // pp.FLUSH_W) * pp.FLUSH_W
+
+
+def _run_partition(F, cnt, share, in_place, mode, hist_stream=None,
+                   max_bin=0):
+    """Run the kernel on a random arena; check streams, order, counts and
+    that no column outside align(count, FLUSH_W) of each dst changed.
+    Returns (before, go_to_A, outputs)."""
+    C = pp.arena_channels(F)
+    arena = _base_arena(C)
+    go = _go_left(share, cnt)
+    dstA = _START if in_place else _DST_A
+    kw = {}
+    if mode == 0:
+        pred = np.zeros((1, _CAP), np.float32)
+        pred[0, _START:_START + cnt] = go
+        to_A = go
+    else:
+        # bin value < 100 goes left; xr = 1 sends the left rows to B
+        xr = int(not in_place)
+        arena[0, _START:_START + cnt] = np.where(go, 10, 200)
+        pred = np.zeros((1, pp.TILE), np.float32)
+        kw["decision"] = (0, jnp.asarray(np.arange(256) < 100, jnp.float32),
+                          xr)
+        to_A = ~go if xr else go
+    if hist_stream is not None:
+        kw.update(hist_stream=hist_stream, num_features=F, max_bin=max_bin)
+    out = pp.partition_segment(jnp.asarray(arena, pp.ARENA_DT),
+                               jnp.asarray(pred), _START, cnt, dstA, _DST_B,
+                               interpret=True, **kw)
+    got = np.asarray(out[0].astype(jnp.float32))
+    seg = arena[:, _START:_START + cnt]
+    nA, nB = int(to_A.sum()), int((~to_A).sum())
+    assert list(np.asarray(out[1])) == [nA, nB]
+    np.testing.assert_array_equal(got[:, dstA:dstA + nA], seg[:, to_A])
+    np.testing.assert_array_equal(got[:, _DST_B:_DST_B + nB], seg[:, ~to_A])
+    untouched = np.ones(_CAP, bool)
+    untouched[dstA:dstA + _aligned(nA)] = False
+    untouched[_DST_B:_DST_B + _aligned(nB)] = False
+    np.testing.assert_array_equal(got[:, untouched], arena[:, untouched])
+    return seg, to_A, out
+
+
+@pytest.mark.parametrize("mode", [0, 1])
+@pytest.mark.parametrize("in_place", [True, False])
+@pytest.mark.parametrize("share", _SHARES)
+@pytest.mark.parametrize("cnt", _COUNTS)
+@pytest.mark.parametrize("F", [28, 137])        # C = 48 (higgs), 160 (MSLR)
+def test_partition_segment_is_stable_partition(F, cnt, share, in_place,
+                                               mode):
+    _run_partition(F, cnt, share, in_place, mode)
+
+
+@pytest.mark.parametrize("mode", [0, 1])
+@pytest.mark.parametrize("cnt", [257, 3 * 2048 + 5])
+@pytest.mark.parametrize("hist_stream", [0, 1])
+def test_partition_segment_fused_histogram(hist_stream, cnt, mode):
+    """The hist_stream variant partitions as the plain kernel does and
+    returns the chosen stream's [F, max_bin, 3] histogram."""
+    F, B = 28, 255
+    Fp = pp.feature_channels(F)
+    seg, to_A, out = _run_partition(F, cnt, "half", True, mode,
+                                    hist_stream=hist_stream, max_bin=B)
+    rows = seg[:, ~to_A if hist_stream else to_A]
+    want = np.zeros((F, B, 3))
+    g = rows[Fp:Fp + 3].sum(0)
+    h = rows[Fp + 3:Fp + 6].sum(0)
+    for f in range(F):
+        b = rows[f].astype(int)
+        np.add.at(want[f, :, 0], b, g)
+        np.add.at(want[f, :, 1], b, h)
+        np.add.at(want[f, :, 2], b, 1.0)
+    np.testing.assert_array_equal(np.asarray(out[2], np.float64), want)
+
+
+def _numpy_partition_segment(arena, pred, start, cnt, dstA, dstB,
+                             decision=None, hist_stream=None, **_):
+    """partition_segment's contract on the host: a stable partition of
+    columns [start, start+cnt), each stream written as whole zero-padded
+    FLUSH_W chunks at its dst."""
+    assert hist_stream is None
+    by_pred = decision is None
+    feat, mask, xr = (0, np.zeros(256), 0) if by_pred else decision
+
+    def host(arena, pred, start, cnt, dstA, dstB, feat, mask, xr):
+        out = np.array(arena)
+        start, cnt = int(start), int(cnt)
+        seg = out[:, start:start + cnt].copy()
+        if by_pred:
+            to_A = np.asarray(pred)[0, start:start + cnt] > 0.5
+        else:
+            left = np.asarray(mask)[seg[int(feat)].astype(np.int64)] > 0.5
+            to_A = left ^ bool(xr)
+        for dst, rows in ((int(dstA), seg[:, to_A]),
+                          (int(dstB), seg[:, ~to_A])):
+            n = rows.shape[1]
+            out[:, dst:dst + _aligned(n)] = 0
+            out[:, dst:dst + n] = rows
+        return out, np.asarray([to_A.sum(), cnt - to_A.sum()], np.int32)
+
+    return jax.pure_callback(
+        host, (jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+               jax.ShapeDtypeStruct((2,), jnp.int32)),
+        arena, pred, start, cnt, dstA, dstB, feat,
+        jnp.asarray(mask, jnp.float32), xr)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_model_text_equals_numpy_partition_oracle(monkeypatch, quantized):
+    """A small training through the kernel (interpret mode) writes the
+    model text, byte for byte, that the same training writes with the
+    kernel replaced by a numpy stable partition."""
+    import lightgbm_tpu as lgb
+
+    rng = np.random.RandomState(7)
+    n, F = 5000, 6
+    X = rng.randn(n, F).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(n) > 0
+         ).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": 63,
+              "learning_rate": 0.2, "min_data_in_leaf": 5, "verbose": -1,
+              "tpu_tree_engine": "partition",
+              "tpu_quantized_grad": quantized}
+
+    def train():
+        jax.clear_caches()      # the grower's trace holds the kernel
+        bst = lgb.train(params, lgb.Dataset(X, y), num_boost_round=3)
+        assert bst._gbdt._use_partition_engine
+        assert [t.num_leaves for t in bst._gbdt.models] == [15] * 3
+        return bst.model_to_string()
+
+    with_kernel = train()
+    calls = []
+
+    def oracle(*args, **kwargs):
+        calls.append(1)
+        return _numpy_partition_segment(*args, **kwargs)
+    monkeypatch.setattr(pp, "partition_segment", oracle)
+    assert train() == with_kernel
+    assert calls
+    monkeypatch.undo()
+    jax.clear_caches()

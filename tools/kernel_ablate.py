@@ -2,7 +2,7 @@
 cost of each pipeline stage by compiling stripped variants (a checksum
 into cnt_ref keeps Mosaic from DCE-ing live stages).
 
-Usage: python tools/kernel_ablate.py [rows_millions]
+Usage: python tools/kernel_ablate.py [rows_millions [features]]
 """
 import functools
 import sys
@@ -18,21 +18,25 @@ sys.path.insert(0, ".")
 from lightgbm_tpu.ops import partition_pallas as pp  # noqa: E402
 
 SUB, TILE = pp.SUB, pp.TILE
-FLUSH_W, CARRY_W = pp.FLUSH_W, pp.CARRY_W
 ARENA_DT = pp.ARENA_DT
 
-STAGES = ("dma", "decide", "scan", "pbuild", "matmul", "full")
+# cumulative stages of the tile body.  A stage's checksum reads one element
+# of what it computed, so the compiler may drop the rest: `pbuild` may keep
+# one of the K permutation one-hots; `matmul` reads one element of every
+# product, which keeps all K builds and matmuls; `chunks` adds the A/B split
+# and is everything but the appends; `full` is the shipped kernel itself
+# (pp.partition_segment), appends, flushes and write-back included
+STAGES = ("dma", "decide", "scan", "pbuild", "matmul", "chunks", "full")
 
 
 def _kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, out_any, cnt_ref,
-            in_buf, carryA, carryB, flush_buf, read_sems, write_sems,
-            *, C: int, tile: int, stage: str):
+            in_buf, read_sems, *, C: int, tile: int, stage: str):
+    """The shipped kernel's read pipeline and parallel region, cut after
+    `stage`; nothing is written back."""
     s, cnt = sc_ref[0], sc_ref[1]
-    dstA, dstB = sc_ref[2], sc_ref[3]
     xr = sc_ref[5]
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
     K = tile // SUB
-    lane_w = jax.lax.broadcasted_iota(jnp.int32, (C, CARRY_W), 1)
 
     def read_dma(j, slot):
         src = pl.multiple_of(s + j * tile, 128)
@@ -40,49 +44,40 @@ def _kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, out_any, cnt_ref,
             arena_any.at[:, pl.ds(src, tile)], in_buf.at[slot],
             read_sems.at[slot])
 
-    def flush_dma(stream, slot, dst_col):
-        return pltpu.make_async_copy(
-            flush_buf.at[stream, slot],
-            out_any.at[:, pl.ds(pl.multiple_of(dst_col, 128), FLUSH_W)],
-            write_sems.at[stream, slot])
-
     @pl.when(n_tiles > 0)
     def _():
         read_dma(0, 0).start()
         read_dma(0, 0).wait()
-    carryA[:] = jnp.zeros((C, CARRY_W), jnp.float32)
-    carryB[:] = jnp.zeros((C, CARRY_W), jnp.float32)
 
-    def append_and_flush(carry, chunk, lo, ck, fill, written, dst, stream,
-                         fslot):
-        padded = jnp.concatenate(
-            [chunk, jnp.zeros((C, CARRY_W - SUB), jnp.float32)], axis=1)
-        shift = jax.lax.rem(fill - lo + jnp.int32(CARRY_W),
-                            jnp.int32(CARRY_W))
-        carry[:] = carry[:] + pltpu.roll(padded, shift, axis=1)
-        fill = fill + ck
+    def after_read(block, valid):
+        if stage == "dma":
+            return jnp.sum(block[0:1, 0:1].astype(jnp.float32))
+        on = pp._decide(block, feat_onehot_ref, mask_ref, xr) > 0.5
+        predA = jnp.where(valid & on, jnp.float32(1.0), jnp.float32(0.0))
+        predB = jnp.where(valid & ~on, jnp.float32(1.0), jnp.float32(0.0))
+        if stage == "decide":
+            return jnp.sum(predA)
+        pred2 = jnp.concatenate(
+            [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
+        pref2 = pp._prefix_scan_lanes(pred2)
+        if stage == "scan":
+            return pref2[0, 0]
+        P_all = pp._sort_P(pref2, pred2, K)
+        if stage == "pbuild":
+            return jnp.sum(P_all[0, 0:1, 0:1].astype(jnp.float32))
+        comps = [jax.lax.dot(block[:, k * SUB:(k + 1) * SUB], P_all[k],
+                             preferred_element_type=jnp.float32)
+                 for k in range(K)]
+        if stage == "matmul":
+            return sum(c[0, 0] for c in comps)
+        cnt2 = pref2[:, SUB - 1].astype(jnp.int32)
+        lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
+        chunksA = [jnp.where(lane_s < cnt2[k], comps[k], jnp.float32(0.0))
+                   for k in range(K)]
+        chunksB = [comps[k] - chunksA[k] for k in range(K)]
+        return jnp.sum(sum(chunksA) - sum(chunksB))
 
-        @pl.when(fill >= FLUSH_W)
-        def _(fill=fill, written=written, fslot=fslot):
-            @pl.when(written >= 2 * FLUSH_W)
-            def _():
-                flush_dma(stream, fslot, 0).wait()
-            flush_buf[stream, fslot] = carry[:, 0:FLUSH_W].astype(ARENA_DT)
-            flush_dma(stream, fslot, dst + written).start()
-            shifted = jnp.concatenate(
-                [carry[:, FLUSH_W:CARRY_W],
-                 jnp.zeros((C, FLUSH_W), jnp.float32)], axis=1)
-            carry[:] = jnp.where(lane_w < fill - FLUSH_W, shifted,
-                                 jnp.float32(0.0))
-
-        flushed = fill >= FLUSH_W
-        fill = jnp.where(flushed, fill - FLUSH_W, fill)
-        written = jnp.where(flushed, written + FLUSH_W, written)
-        fslot = jnp.where(flushed, 1 - fslot, fslot)
-        return fill, written, fslot
-
-    def loop(j, carry_state):
-        fillA, wA, fsA, fillB, wB, fsB, chk = carry_state
+    def loop(j, chk):
         slot = jax.lax.rem(j, jnp.int32(2))
         nslot = jax.lax.rem(j + jnp.int32(1), jnp.int32(2))
 
@@ -92,106 +87,34 @@ def _kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, out_any, cnt_ref,
 
         valid = jax.lax.broadcasted_iota(
             jnp.int32, (1, tile), 1) < (cnt - j * tile)
-        block = in_buf[slot]
-        if stage == "dma":
-            chk = chk + jnp.sum(block[0:1, 0:1].astype(jnp.float32))
-        else:
-            col = jnp.round(jax.lax.dot(feat_onehot_ref[:], block,
-                                        preferred_element_type=jnp.float32)
-                            ).astype(jnp.int32)
-            MB = mask_ref.shape[1]
-            col_onehot = jnp.where(
-                jax.lax.broadcasted_iota(jnp.int32, (MB, tile), 0)
-                == col.reshape(1, tile),
-                jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
-            go_left_f = jax.lax.dot(mask_ref[:], col_onehot,
-                                    preferred_element_type=jnp.float32)
-            xr_f = jnp.float32(xr)
-            on_f = go_left_f + xr_f - 2.0 * go_left_f * xr_f
-            on = on_f > 0.5
-            predA = jnp.where(valid & on, jnp.float32(1.0), jnp.float32(0.0))
-            predB = jnp.where(valid & ~on, jnp.float32(1.0), jnp.float32(0.0))
-            if stage == "decide":
-                chk = chk + jnp.sum(predA)
-            else:
-                pred2 = jnp.concatenate(
-                    [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
-                pref2 = pp._prefix_scan_lanes(pred2)
-                cnt2 = pref2[:, SUB - 1].astype(jnp.int32)
-                if stage == "scan":
-                    chk = chk + pref2[0, 0]
-                else:
-                    P_all = pp._sort_P(pref2, pred2, K)
-                    if stage == "pbuild":
-                        chk = chk + jnp.sum(P_all[0, 0:1, 0:1].astype(jnp.float32))
-                    else:
-                        comps = [jax.lax.dot(
-                            block[:, k * SUB:(k + 1) * SUB], P_all[k],
-                            preferred_element_type=jnp.float32)
-                            for k in range(K)]
-                        if stage == "matmul":
-                            chk = chk + comps[0][0, 0]
-                        else:
-                            lane_s = jax.lax.broadcasted_iota(
-                                jnp.int32, (1, SUB), 1)
-                            chunksA = [jnp.where(lane_s < cnt2[k],
-                                                 comps[k], jnp.float32(0.0))
-                                       for k in range(K)]
-                            chunksB = [comps[k] - chunksA[k]
-                                       for k in range(K)]
-                            for k in range(K):
-                                ca, cb = cnt2[k], cnt2[K + k]
-                                fillA, wA, fsA = append_and_flush(
-                                    carryA, chunksA[k], jnp.int32(0), ca,
-                                    fillA, wA, dstA, 0, fsA)
-                                fillB, wB, fsB = append_and_flush(
-                                    carryB, chunksB[k], ca, cb,
-                                    fillB, wB, dstB, 1, fsB)
+        chk = chk + after_read(in_buf[slot], valid)
 
         @pl.when(j + 1 < n_tiles)
         def _():
             read_dma(j + 1, nslot).wait()
-        return fillA, wA, fsA, fillB, wB, fsB, chk
+        return chk
 
-    z = jnp.int32(0)
-    fillA, wA, fsA, fillB, wB, fsB, chk = jax.lax.fori_loop(
-        0, n_tiles, loop, (z, z, z, z, z, z, jnp.float32(0.0)))
-
-    if stage == "full":
-        for stream, carry, fill, w, dst, fslot in (
-                (0, carryA, fillA, wA, dstA, fsA),
-                (1, carryB, fillB, wB, dstB, fsB)):
-            @pl.when(fill > 0)
-            def _(stream=stream, carry=carry, fill=fill, w=w, dst=dst,
-                  fslot=fslot):
-                @pl.when(w >= 2 * FLUSH_W)
-                def _():
-                    flush_dma(stream, fslot, 0).wait()
-                flush_buf[stream, fslot] = carry[:, 0:FLUSH_W].astype(ARENA_DT)
-                flush_dma(stream, fslot, dst + w).start()
-                flush_dma(stream, fslot, 0).wait()
-
-            @pl.when((fill == 0) & (w >= 2 * FLUSH_W))
-            def _(stream=stream, fslot=fslot):
-                flush_dma(stream, fslot, 0).wait()
-
-            @pl.when(w >= FLUSH_W)
-            def _(stream=stream, fslot=fslot):
-                flush_dma(stream, 1 - fslot, 0).wait()
-
-    cnt_ref[0] = (wA + fillA) + chk.astype(jnp.int32)
-    cnt_ref[1] = wB + fillB
+    chk = jax.lax.fori_loop(0, n_tiles, loop, jnp.float32(0.0))
+    cnt_ref[0] = chk.astype(jnp.int32)
+    cnt_ref[1] = jnp.int32(0)
 
 
 @functools.partial(jax.jit, static_argnames=("stage", "n", "reps"))
 def run_stage(arena, decision, *, stage, n, reps):
     C, cap = arena.shape
     feat, mask_vec, xr = decision
+    dstB = ((n + TILE - 1) // TILE) * TILE + TILE
+    if stage == "full":
+        pred = jnp.zeros((1, TILE), jnp.float32)
+        return jax.lax.fori_loop(
+            0, reps,
+            lambda i, ar: pp.partition_segment(ar, pred, 0, n, 0, dstB,
+                                               decision=decision)[0],
+            arena)
     feat_onehot = (jnp.arange(C, dtype=jnp.int32)[None, :]
                    == feat).astype(ARENA_DT)
     mv = jnp.asarray(mask_vec, jnp.float32).reshape(1, -1)
     goleft = jnp.pad(mv, ((0, 0), (0, 256 - mv.shape[1]))).astype(ARENA_DT)
-    dstB = ((n + TILE - 1) // TILE) * TILE + TILE
     sc = jnp.asarray([0, n, 0, dstB, 1, 0, 0], jnp.int32)
     kernel = functools.partial(_kernel, C=C, tile=TILE, stage=stage)
 
@@ -208,11 +131,7 @@ def run_stage(arena, decision, *, stage, n, reps):
                        jax.ShapeDtypeStruct((2,), jnp.int32)),
             scratch_shapes=[
                 pltpu.VMEM((2, C, TILE), ARENA_DT),
-                pltpu.VMEM((C, CARRY_W), jnp.float32),
-                pltpu.VMEM((C, CARRY_W), jnp.float32),
-                pltpu.VMEM((2, 2, C, FLUSH_W), ARENA_DT),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2, 2)),
             ],
             input_output_aliases={3: 0},
             compiler_params=pltpu.CompilerParams(has_side_effects=True),
@@ -223,12 +142,11 @@ def run_stage(arena, decision, *, stage, n, reps):
 
 def main():
     n = int(float(sys.argv[1]) * 1e6) if len(sys.argv) > 1 else 4_000_000
-    F = 28
+    F = int(sys.argv[2]) if len(sys.argv) > 2 else 28
     B = 255
     rng = np.random.default_rng(0)
     C, cap = pp.arena_geometry(n, F)
-    print(f"n={n} C={C} SUB={SUB} TILE={TILE} FLUSH_W={FLUSH_W} "
-          f"CARRY_W={CARRY_W}")
+    print(f"n={n} C={C} SUB={SUB} TILE={TILE} FLUSH_W={pp.FLUSH_W}")
     arena = jnp.asarray(
         rng.integers(0, B, size=(C, cap)).astype(np.float32), ARENA_DT)
     float(jnp.sum(arena[:, :1]))

@@ -311,6 +311,34 @@ def kernel_coverage(lgb, jax, jnp, args):
         lgb, jax, Xr, lab, dict(force, objective="lambdarank",
                                 metric="ndcg", tpu_quantized_grad=True),
         group=group))
+    # wide dense data: 2 000 columns are 2 016 arena channels, past what one
+    # [C, tile] slab of VMEM holds, so every arena kernel runs in channel or
+    # feature blocks (ops/partition_pallas.engine_plan) and `auto` must
+    # still choose the partition engine.  Fused + carried int8 (partition,
+    # segment and fused root histograms, carry compaction, the blocked
+    # scan), then bagged (the pred-routed root partition with its
+    # histogram, leaf ids through compact_segments).  Off the chip the
+    # interpreter gets 520 columns: blocked all the same, in a tenth of
+    # the time.
+    from lightgbm_tpu.ops import partition_pallas as pp
+    wide_f = 2000 if jax.default_backend() == "tpu" else 520
+    Xw, yw, _, _ = bench.higgs_data(min(n, 32_768), 16, seed=7)
+    rng_w = np.random.RandomState(11)
+    Xw = np.concatenate([Xw, rng_w.randn(len(Xw), wide_f - Xw.shape[1])
+                         .astype(np.float32)], axis=1)
+    wide = {"max_bin": 63, "tpu_quantized_grad": True,
+            "tpu_tree_engine": ("auto" if jax.default_backend() == "tpu"
+                                else "partition")}
+    for name, extra in (("wide-int8", {}), ("wide-bagging-int8", bag)):
+        b = _train_small(lgb, jax, Xw, yw, dict(wide, **extra), rounds=2)
+        plan = b._gbdt._engine_plan
+        assert plan["channels"] == pp.arena_channels(wide_f) \
+            and plan["partition_blocks"] > 1 and plan["hist_steps"] > 1, plan
+        check(name, b)
+    _say("kernels", wide_columns=wide_f, **{
+        k: plan[k] for k in ("channels", "partition_block",
+                             "hist_features_per_step")})
+    del Xw
     # label engine with the masked Pallas histogram
     check("label-pallas", _train_small(
         lgb, jax, X, y, {"tpu_tree_engine": "label",

@@ -143,7 +143,8 @@ _SCHEMA = [
     ("tpu_histogram_impl", str, "auto"),     # auto|compact|onehot|scatter|pallas
     ("tpu_rows_per_tile", int, 2048),        # Pallas row-tile size
     ("tpu_tree_engine", str, "auto"),        # auto|label|partition — partition =
-    #   arena-resident pallas engine (O(child) per split); label = masked-pass
+    #   arena-resident pallas engine (O(child) per split; any width device
+    #   memory holds: wide arenas run in channel blocks); label = masked-pass
     #   engine (works everywhere: CPU, f64, categorical, distributed)
     ("tpu_arena_factor", int, 6),            # partition-engine arena size, x rows
     ("tpu_profile", bool, False),            # per-phase host timers, report at teardown

@@ -42,16 +42,24 @@ def _double_equal_ordered(a: float, b: float) -> bool:
 def greedy_find_bin(distinct_values, counts, max_bin: int, total_cnt: int,
                     min_data_in_bin: int) -> List[float]:
     """Equal-frequency greedy binning over (distinct value, count) pairs;
-    behavioral port of GreedyFindBin (src/io/bin.cpp:73-149)."""
+    behavioral port of GreedyFindBin (src/io/bin.cpp:73-149).
+
+    The reference walks every distinct value; a continuous column has as
+    many of them as sampled rows, so here the walk jumps from one bin
+    boundary to the next over cumulative counts (a binary search per
+    bin): the same boundaries, found in O(max_bin log n)."""
+    distinct_values = np.asarray(distinct_values, np.float64)
+    counts = np.asarray(counts, np.int64)
     num_distinct = len(distinct_values)
     assert max_bin > 0
     bin_upper_bound: List[float] = []
     if num_distinct <= max_bin:
+        dv, cn = distinct_values.tolist(), counts.tolist()
         cur_cnt = 0
         for i in range(num_distinct - 1):
-            cur_cnt += counts[i]
+            cur_cnt += cn[i]
             if cur_cnt >= min_data_in_bin:
-                val = _next_after((distinct_values[i] + distinct_values[i + 1]) / 2.0)
+                val = _next_after((dv[i] + dv[i + 1]) / 2.0)
                 if not bin_upper_bound or not _double_equal_ordered(bin_upper_bound[-1], val):
                     bin_upper_bound.append(val)
                     cur_cnt = 0
@@ -61,39 +69,51 @@ def greedy_find_bin(distinct_values, counts, max_bin: int, total_cnt: int,
     if min_data_in_bin > 0:
         max_bin = max(1, min(max_bin, int(total_cnt // min_data_in_bin)))
     mean_bin_size = total_cnt / max_bin
-    rest_bin_cnt = max_bin
-    rest_sample_cnt = int(total_cnt)
-    is_big = [counts[i] >= mean_bin_size for i in range(num_distinct)]
-    for i in range(num_distinct):
-        if is_big[i]:
-            rest_bin_cnt -= 1
-            rest_sample_cnt -= counts[i]
-    mean_bin_size = rest_sample_cnt / rest_bin_cnt if rest_bin_cnt > 0 else math.inf
+    is_big = counts >= mean_bin_size
+    big_at = np.flatnonzero(is_big)
+    rest_bin_cnt = max_bin - len(big_at)
+    small_total = int(total_cnt) - int(counts[big_at].sum())
+    mean_bin_size = small_total / rest_bin_cnt if rest_bin_cnt > 0 else math.inf
+    csum = np.cumsum(counts)                          # rows up to and incl. i
+    small_csum = np.cumsum(np.where(is_big, 0, counts))
     upper_bounds = [math.inf] * max_bin
     lower_bounds = [math.inf] * max_bin
 
     bin_cnt = 0
-    lower_bounds[0] = distinct_values[0]
-    cur_cnt = 0
-    for i in range(num_distinct - 1):
+    lower_bounds[0] = float(distinct_values[0])
+    start = 0                    # first distinct value of the open bin
+    last = num_distinct - 2      # the walk's last index
+    while start <= last:
+        base = int(csum[start - 1]) if start else 0
+        # a bin closes at the first i >= start where: i is big; or the bin
+        # is filled; or i + 1 is big and the bin is at least half filled
+        # (bin.cpp:124-127)
+        i = last + 1
+        if mean_bin_size != math.inf:
+            i = int(np.searchsorted(csum, base + math.ceil(mean_bin_size),
+                                    side="left"))
+        nb = int(np.searchsorted(big_at, start, side="left"))
+        if nb < len(big_at):
+            p = int(big_at[nb])
+            if p == start:
+                i = start
+            elif i >= p:
+                half = max(1.0, mean_bin_size * np.float32(0.5))
+                i = p - 1 if int(csum[p - 1]) - base >= half else p
+        if i > last:
+            break
+        upper_bounds[bin_cnt] = float(distinct_values[i])
+        bin_cnt += 1
+        lower_bounds[bin_cnt] = float(distinct_values[i + 1])
+        if bin_cnt >= max_bin - 1:
+            break
         if not is_big[i]:
-            rest_sample_cnt -= counts[i]
-        cur_cnt += counts[i]
-        # need a new bin: big value gets its own; or bin filled; or next is
-        # big and this bin is at least half filled (bin.cpp:124-127)
-        if is_big[i] or cur_cnt >= mean_bin_size or \
-           (is_big[i + 1] and cur_cnt >= max(1.0, mean_bin_size * np.float32(0.5))):
-            upper_bounds[bin_cnt] = distinct_values[i]
-            bin_cnt += 1
-            lower_bounds[bin_cnt] = distinct_values[i + 1]
-            if bin_cnt >= max_bin - 1:
-                break
-            cur_cnt = 0
-            if not is_big[i]:
-                rest_bin_cnt -= 1
-                # C++ double division yields a benign inf at 0
-                mean_bin_size = (rest_sample_cnt / rest_bin_cnt
-                                 if rest_bin_cnt > 0 else math.inf)
+            rest_bin_cnt -= 1
+            rest_sample_cnt = small_total - int(small_csum[i])
+            # C++ double division yields a benign inf at 0
+            mean_bin_size = (rest_sample_cnt / rest_bin_cnt
+                             if rest_bin_cnt > 0 else math.inf)
+        start = i + 1
     bin_cnt += 1
     for i in range(bin_cnt - 1):
         val = _next_after((upper_bounds[i] + lower_bounds[i + 1]) / 2.0)
@@ -107,17 +127,19 @@ def find_bin_with_zero_as_one_bin(distinct_values, counts, max_bin: int,
                                   total_sample_cnt: int, min_data_in_bin: int) -> List[float]:
     """Zero always isolated in [-1e-35, 1e-35]; negatives and positives get
     proportional bin budgets (src/io/bin.cpp:151-205)."""
-    left_cnt_data = cnt_zero = right_cnt_data = 0
-    for v, c in zip(distinct_values, counts):
-        if v <= -K_ZERO_THRESHOLD:
-            left_cnt_data += c
-        elif v > K_ZERO_THRESHOLD:
-            right_cnt_data += c
-        else:
-            cnt_zero += c
-
-    left_cnt = next((i for i, v in enumerate(distinct_values) if v > -K_ZERO_THRESHOLD),
-                    len(distinct_values))
+    distinct_values = np.asarray(distinct_values, np.float64)
+    counts = np.asarray(counts, np.int64)
+    # the values are sorted: the negatives end at left_cnt, the positives
+    # start at right_start
+    left_cnt = int(np.searchsorted(distinct_values, -K_ZERO_THRESHOLD,
+                                   side="right"))
+    right_start = int(np.searchsorted(distinct_values, K_ZERO_THRESHOLD,
+                                      side="right"))
+    left_cnt_data = int(counts[:left_cnt].sum())
+    right_cnt_data = int(counts[right_start:].sum())
+    cnt_zero = int(counts[left_cnt:right_start].sum())
+    if right_start >= len(distinct_values):
+        right_start = -1
 
     bin_upper_bound: List[float] = []
     if left_cnt > 0:
@@ -127,8 +149,6 @@ def find_bin_with_zero_as_one_bin(distinct_values, counts, max_bin: int,
                                           left_max_bin, left_cnt_data, min_data_in_bin)
         bin_upper_bound[-1] = -K_ZERO_THRESHOLD
 
-    right_start = next((i for i in range(left_cnt, len(distinct_values))
-                        if distinct_values[i] > K_ZERO_THRESHOLD), -1)
     if right_start >= 0:
         right_max_bin = max_bin - 1 - len(bin_upper_bound)
         assert right_max_bin > 0
@@ -183,10 +203,12 @@ class BinMapper:
         self.default_bin = 0
         zero_cnt = int(total_sample_cnt - len(values) - na_cnt)
 
-        distinct_values, counts = self._distinct_with_zero(np.sort(values, kind="stable"),
+        # no NaN and no zero is left among the values, so any sort gives
+        # the one sorted array; the default one is vectorised
+        distinct_values, counts = self._distinct_with_zero(np.sort(values),
                                                            zero_cnt)
-        self.min_val = distinct_values[0] if distinct_values else 0.0
-        self.max_val = distinct_values[-1] if distinct_values else 0.0
+        self.min_val = float(distinct_values[0]) if len(distinct_values) else 0.0
+        self.max_val = float(distinct_values[-1]) if len(distinct_values) else 0.0
 
         cnt_in_bin: List[int] = []
         if bin_type == NUMERICAL:
@@ -202,17 +224,21 @@ class BinMapper:
                     self.missing_type = MISSING_NONE
             self.bin_upper_bound = np.array(bounds)
             self.num_bin = len(bounds)
-            cnt_in_bin = [0] * self.num_bin
-            i_bin = 0
-            for v, c in zip(distinct_values, counts):
-                while v > self.bin_upper_bound[i_bin]:
-                    i_bin += 1
-                cnt_in_bin[i_bin] += c
+            # a value's bin is the first whose upper bound is not below it
+            # (the NaN bound, where there is one, comes after +inf)
+            finite = self.num_bin - (1 if self.missing_type == MISSING_NAN
+                                     else 0)
+            at = np.searchsorted(self.bin_upper_bound[:finite],
+                                 distinct_values, side="left")
+            cnt_in_bin = np.bincount(
+                at, weights=counts, minlength=self.num_bin
+            ).astype(np.int64).tolist()
             if self.missing_type == MISSING_NAN:
                 cnt_in_bin[self.num_bin - 1] = na_cnt
             assert self.num_bin <= max_bin
         else:
-            cnt_in_bin = self._find_bin_categorical(distinct_values, counts,
+            cnt_in_bin = self._find_bin_categorical(distinct_values.tolist(),
+                                                    counts.tolist(),
                                                     total_sample_cnt, max_bin,
                                                     min_data_in_bin, na_cnt)
 
@@ -231,38 +257,46 @@ class BinMapper:
 
     @staticmethod
     def _distinct_with_zero(sorted_values: np.ndarray, zero_cnt: int
-                            ) -> Tuple[List[float], List[int]]:
-        """Distinct (value, count) pairs with the implied zeros spliced in at
-        the right position (bin.cpp:238-268).
+                            ) -> Tuple[np.ndarray, np.ndarray]:
+        """Distinct (value, count) pairs, as float64 and int64 arrays, with
+        the implied zeros spliced in at the right position
+        (bin.cpp:238-268).
 
-        Vectorized: exact-equal grouping via np.unique, then a Python merge
-        only over the (few) distinct values for the nextafter-equality chain
-        — duplicates are exactly equal, so chaining over distincts matches
-        chaining over raw samples.
-        """
+        Exact-equal grouping via np.unique; then values within one ulp of
+        the one before them chain into a group that keeps its largest
+        value (the nextafter-equality chain, which compares each distinct
+        value with its predecessor, so it is a mask over neighbours).  A
+        chain never joins a negative with a positive value, nor starts at
+        an explicit 0.0."""
         n = len(sorted_values)
-        uniq, ucnt = (np.unique(sorted_values, return_counts=True) if n
-                      else (np.empty(0), np.empty(0, dtype=int)))
-        distinct: List[float] = []
-        counts: List[int] = []
-        if n == 0 or (uniq[0] > 0.0 and zero_cnt > 0):
-            distinct.append(0.0)
-            counts.append(zero_cnt)
-        for i in range(len(uniq)):
-            cur, c = float(uniq[i]), int(ucnt[i])
-            if distinct and distinct[-1] != 0.0 and _double_equal_ordered(distinct[-1], cur) \
-               and not (distinct[-1] < 0.0 < cur):
-                distinct[-1] = cur  # keep the larger of near-equal values
-                counts[-1] += c
-            else:
-                if distinct and distinct[-1] < 0.0 and cur > 0.0:
-                    distinct.append(0.0)
-                    counts.append(zero_cnt)
-                distinct.append(cur)
-                counts.append(c)
-        if n > 0 and uniq[-1] < 0.0 and zero_cnt > 0:
-            distinct.append(0.0)
-            counts.append(zero_cnt)
+        if n == 0:
+            return np.array([0.0]), np.array([zero_cnt], np.int64)
+        # np.unique would sort again
+        starts = np.flatnonzero(np.concatenate(
+            ([True], sorted_values[1:] != sorted_values[:-1])))
+        uniq = sorted_values[starts]
+        ucnt = np.diff(np.append(starts, n))
+        prev, cur = uniq[:-1], uniq[1:]
+        joins = np.zeros(len(uniq), bool)
+        joins[1:] = ((prev != 0.0) & (cur <= np.nextafter(prev, np.inf))
+                     & ~((prev < 0.0) & (cur > 0.0)))
+        first = np.flatnonzero(~joins)                 # each group's start
+        last = np.append(first[1:], len(uniq)) - 1
+        distinct = uniq[last]
+        counts = np.add.reduceat(ucnt, first).astype(np.int64)
+        # the zeros: before an all-positive column and after an all-negative
+        # one only when there are any, between the signs always
+        neg = int(np.searchsorted(distinct, 0.0, side="left"))
+        has_zero = neg < len(distinct) and distinct[neg] == 0.0
+        if neg == 0:
+            splice = zero_cnt > 0 and not has_zero
+        elif neg == len(distinct):
+            splice = zero_cnt > 0
+        else:
+            splice = not has_zero
+        if splice:
+            distinct = np.insert(distinct, neg, 0.0)
+            counts = np.insert(counts, neg, zero_cnt)
         return distinct, counts
 
     def _find_bin_categorical(self, distinct_values, counts, total_sample_cnt: int,

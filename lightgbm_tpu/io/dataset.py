@@ -12,6 +12,8 @@ bundling (io/efb.py) keeps the column count down for sparse-wide data.
 from __future__ import annotations
 
 import json as _json
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -113,6 +115,22 @@ def validate_ingest_block(X, label=None, weight=None, *, num_features: int,
             if weight is not None:
                 weight = weight[keep]
     return X, label, weight
+
+
+_BIN_BLOCK_ROWS = 16384
+
+
+def _map_columns(fn, items) -> list:
+    """[fn(i) for i in items], the calls spread over the host's cores.
+    Finding one column's bins or binning one block of rows depends on
+    nothing else, so the results are those of the serial loop; the work
+    is numpy sorts and searches, which release the interpreter lock."""
+    items = list(items)
+    workers = min(len(items), os.cpu_count() or 1, 32)
+    if workers <= 1:
+        return [fn(i) for i in items]
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        return list(pool.map(fn, items))
 
 
 class BinnedDataset:
@@ -242,13 +260,18 @@ class BinnedDataset:
         # (dataset_loader.cpp:849-850)
         filter_cnt = max(1, int(config.min_data_in_leaf * len(sample_indices) / n))
 
+        # a dense sample is read a column at a time: transpose it once, so
+        # that a column is contiguous and not one value per cache line
+        XsT = None if _issparse(Xs) else np.ascontiguousarray(
+            np.asarray(Xs).T)
+
         def _find_one(f: int) -> BinMapper:
-            if _issparse(Xs):
+            if XsT is None:
                 # stored entries only — implicit zeros are not "nonzero"
                 col = np.asarray(
                     Xs.data[Xs.indptr[f]:Xs.indptr[f + 1]], np.float64)
             else:
-                col = np.asarray(Xs[:, f], dtype=np.float64)
+                col = np.asarray(XsT[f], dtype=np.float64)
             nonzero = col[(np.abs(col) > 1e-35) | np.isnan(col)]
             m = BinMapper()
             m.find_bin(nonzero, Xs.shape[0],
@@ -268,7 +291,8 @@ class BinnedDataset:
                               distributed=True):
                 per = -(-num_raw // world)
                 lo, hi = rank * per, min((rank + 1) * per, num_raw)
-                mine = {f: _find_one(f).to_state() for f in range(lo, hi)}
+                mine = {f: m.to_state() for f, m in zip(
+                    range(lo, hi), _map_columns(_find_one, range(lo, hi)))}
                 merged: dict = {}
                 for part in allgather(mine):
                     # normalize keys: a byte transport (e.g. JSON) may have
@@ -283,7 +307,7 @@ class BinnedDataset:
         else:
             with tracing.span("data/find_bin", "data", features=num_raw,
                               distributed=False):
-                mappers = [_find_one(f) for f in range(num_raw)]
+                mappers = _map_columns(_find_one, range(num_raw))
 
         # --- drop trivial features (dataset.cpp Construct) ----------------
         ds.used_feature_map = [-1] * num_raw
@@ -320,6 +344,18 @@ class BinnedDataset:
         from . import efb
         F = self.num_features
         S = Xs.shape[0]
+        # the sample's non-default rows per feature are on the mappers
+        # already (sparse_rate is the default bin's share of the same
+        # sample).  Two features whose counts sum past the sample plus the
+        # allowed conflicts can never share a group (efb.find_groups): when
+        # that holds for the two sparsest, no column is sparse enough to
+        # bundle, and the search, which would first bin every sampled
+        # column again and mark F * S rows, is skipped with its result.
+        dense = sorted(S * (1.0 - m.sparse_rate) for m in self.bin_mappers)
+        if dense[0] + dense[1] > S + int(S * config.max_conflict_rate) + 2:
+            log.debug("EFB skipped: no two of %d features are sparse "
+                      "enough to share a column", F)
+            return
         nonzero_rows = []
         for inner, raw in enumerate(self.real_feature_index):
             m = self.bin_mappers[inner]
@@ -396,9 +432,21 @@ class BinnedDataset:
         max_nb = max((m.num_bin for m in self.bin_mappers), default=2)
         dtype = np.uint8 if max_nb <= 256 else np.uint16
         bins = np.empty((n, F), dtype=dtype)
-        for inner, raw in enumerate(self.real_feature_index):
-            bins[:, inner] = self.bin_mappers[inner].values_to_bins(
-                np.asarray(X[:, raw], dtype=np.float64)).astype(dtype)
+        X = np.asarray(X)
+
+        # blocks of rows, each transposed so that a column is contiguous
+        # on the way in and on the way out (a column of a row-major matrix
+        # is one value per cache line), and binned concurrently: numpy's
+        # search runs outside the interpreter lock
+        def _bin_rows(lo: int) -> None:
+            hi = min(lo + _BIN_BLOCK_ROWS, n)
+            XT = np.ascontiguousarray(X[lo:hi].T, dtype=np.float64)
+            out = np.empty((F, hi - lo), dtype)
+            for inner, raw in enumerate(self.real_feature_index):
+                out[inner] = self.bin_mappers[inner].values_to_bins(XT[raw])
+            bins[lo:hi] = out.T
+
+        _map_columns(_bin_rows, range(0, n, _BIN_BLOCK_ROWS))
         return bins
 
     def _bin_all(self, X) -> None:

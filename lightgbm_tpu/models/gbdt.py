@@ -11,6 +11,7 @@ so models round-trip with the reference's parsers.
 """
 from __future__ import annotations
 
+import gc
 import time
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -1309,35 +1310,16 @@ class GBDT:
         hist_cache_bytes = (self._hist_slots or L) * entry_bytes
         arena_bytes = (C * cap * 2 + self.num_data * C * 2
                        + hist_cache_bytes)      # bf16 arena + bins_t + hists
+        # width bounds no engine choice: the arena kernels cut a wide arena
+        # into channel blocks (ops/partition_pallas.engine_plan).  What
+        # bounds the partition engine is device memory.
+        plan = None
         if eng == "auto":
-            # C also bounds the kernels' VMEM scratch (2 x C x TILE f32);
-            # the bagging root pass FUSES partition + histogram, so its
-            # combined VMEM footprint (partition scratch + radix
-            # accumulator) must fit too — a config whose kernels fit
-            # individually can still blow VMEM fused
-            from ..ops.histogram_pallas import _radix_plan
-            lo_n, hi_n, m_r = _radix_plan(max(self.max_bin, 2))
-            f_blk = max(m_r, 8)
-            nb_r = pp.feature_channels(n_groups) // f_blk
-            # quantized mode accumulates the 3-component code radix
-            # instead of the 7-component residue radix
-            payload = 3 if cfg.tpu_quantized_grad else 7
-            fused_vmem = (
-                2 * C * pp.TILE * 2                       # in_buf bf16
-                + (pp.TILE // pp.SUB) * pp.SUB * 2 * pp.SUB * 2   # P_all
-                + 2 * C * pp.FLUSH_W * 4                  # carries f32
-                # (the flush staging is not in this sum: the kernel asks
-                # for more VMEM when it needs it, pp._partition_vmem_limit)
-                + 2 * pp.TILE * 4                         # pred bufs
-                + nb_r * (f_blk // m_r) * payload * hi_n * m_r * 128 * 4)
             bounds = (
                 ("needs f32, max_bin <= 256, > 0 features and < 2^24 rows",
                  eligible),
                 ("arena %.2f GB >= %.2f GB device budget"
-                 % (arena_bytes / 1e9, budget / 1e9), arena_bytes < budget),
-                ("%d arena channels > 512" % C, C <= 512),
-                ("fused root pass needs %.1f MiB VMEM >= 13 MiB"
-                 % (fused_vmem / (1 << 20)), fused_vmem < 13 * (1 << 20)))
+                 % (arena_bytes / 1e9, budget / 1e9), arena_bytes < budget))
             failed = [why for why, ok in bounds if not ok]
             if not on_tpu():
                 eng = "label"      # Mosaic kernels lower on a TPU only
@@ -1348,6 +1330,22 @@ class GBDT:
                 eng = "label"
             else:
                 eng = "partition"
+        if eng == "partition":
+            try:
+                plan = pp.engine_plan(n_groups, max(self.max_bin, 2),
+                                      bool(cfg.tpu_quantized_grad))
+            except ValueError as e:
+                # a width the kernels cannot serve is an error, never a
+                # quiet move to the slow engine
+                log.fatal("the partition engine has no block plan for %d "
+                          "columns: %s" % (n_groups, e))
+            plan.update(arena_bytes=C * cap * 2,
+                        bins_t_bytes=self.num_data * n_groups * 2,
+                        hist_cache_bytes=hist_cache_bytes,
+                        device_budget_bytes=budget)
+            log.info("partition engine plan: %s", ", ".join(
+                "%s=%d" % kv for kv in plan.items()))
+        self._engine_plan = plan
         self._use_partition_engine = eng == "partition"
         if pooling_blocked and self._use_partition_engine:
             log.warning("forced splits disable histogram pooling (dense "
@@ -1376,13 +1374,26 @@ class GBDT:
         if self._use_partition_engine:
             from ..ops import grow_partition as gp
             from ..ops import partition_pallas as _pp
-            self._bins_t = jnp.asarray(
-                self.train_state.bins, _pp.ARENA_DT).T
-            # pristine layout: bins + rowid planes written ONCE here;
-            # per-tree assembly refreshes only the g/h payload planes and
-            # the first split is redirected off the pristine block
-            self._arena = _pp.init_pristine(
-                jnp.zeros((C, cap), _pp.ARENA_DT), self._bins_t)
+            # the span's arguments are the plan: what was derived from the
+            # width rides the profiler's trace beside what it sized
+            with obs_tracing.span("engine_plan", "setup", **plan):
+                # An arena may be most of the chip.  What an earlier
+                # booster of this process still holds through reference
+                # cycles (its own arena and histogram cache) is let go
+                # first, and the feature-major bins are one program's
+                # output, waited for: no row-major copy in the arena's
+                # type is still alive when the arena is allocated.  Else
+                # the peak follows the host's timing (400 000 x 2 000:
+                # 12.27, 13.86 or 16.36 GB of 16.9, by how long ago the
+                # collector ran; PERF.md, PR 27).
+                gc.collect()
+                self._bins_t = jax.block_until_ready(
+                    _pp.feature_major(self.train_state.bins))
+                # pristine layout: bins + rowid planes written ONCE here;
+                # per-tree assembly refreshes only the g/h payload planes
+                # and the first split is redirected off the pristine block
+                self._arena = _pp.init_pristine(
+                    jnp.zeros((C, cap), _pp.ARENA_DT), self._bins_t)
             from functools import partial as _ppart
             self._grow_partition = _ppart(gp.grow_tree_partition,
                                           pristine=True)
@@ -2198,15 +2209,18 @@ def _write_planes(arena, planes, row0: int):
 
 
 def _device_memory_budget() -> int:
-    """HBM budget for the partition engine's arena: 60% of what the
-    default device reports.  A TPU that reports nothing is an error —
+    """HBM budget for the partition engine's arena, its feature-major
+    bins and the histogram cache: three quarters of what the default
+    device reports (the rest holds the row-major bins, the scores and the
+    growth loop's transient copies of the cache; at 60 % a 400 000 x 2 000
+    data set, 11.8 GB of a v5e's 16.9, was turned away).  A TPU that reports nothing is an error —
     the arena would be sized against a guess; other backends (the CPU
     reports no stats) never run the arena at a size where it matters
     and get a nominal 8 GB."""
     stats = jax.devices()[0].memory_stats() or {}
     total = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
     if total:
-        return int(total * 0.6)
+        return int(total * 0.75)
     if on_tpu():
         raise RuntimeError(
             "TPU device %r reports no bytes_limit in memory_stats(); "

@@ -74,6 +74,17 @@ _SPAN_MS_BOUNDS = (0.05, 0.2, 1.0, 5.0, 20.0, 100.0, 500.0, 2000.0, 10000.0)
 ANNOTATION_PREFIX = "lgbm:"
 
 
+def _annotation(name: str, args: Optional[Dict]) -> TraceAnnotation:
+    """The profiler's annotation of a span; scalar arguments given at the
+    span's start ride it into the trace file (`lgbm:engine_plan`)."""
+    if not args:
+        return TraceAnnotation(ANNOTATION_PREFIX + name)
+    return TraceAnnotation(
+        ANNOTATION_PREFIX + name,
+        **{k: v for k, v in args.items()
+           if isinstance(v, (bool, int, float, str))})
+
+
 class _Span:
     """One live span: a reusable context manager pushed on the calling
     thread's stack at enter, turned into a complete ('X') event at exit."""
@@ -87,7 +98,7 @@ class _Span:
         self.name = name
         self.cat = cat
         self.args = args
-        self.annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
+        self.annotation = _annotation(name, args)
 
     def __enter__(self) -> "_Span":
         self.annotation.__enter__()
@@ -202,7 +213,7 @@ class SpanTracer:
     def span(self, name: str, cat: str = "",
              args: Optional[Dict] = None):
         if not self.enabled:
-            return TraceAnnotation(ANNOTATION_PREFIX + name)
+            return _annotation(name, args)
         return _Span(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "", **args) -> None:

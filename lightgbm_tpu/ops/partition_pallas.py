@@ -94,6 +94,15 @@ def pristine_work0(num_data: int) -> int:
     return -(-max(num_data, 1) // TILE) * TILE + TILE
 
 
+@jax.jit
+def feature_major(bins):
+    """[n, G] binned rows -> [G, n] feature-major bins in the arena's
+    type, as one program: transposed and converted eagerly, a second
+    [n, G] copy in the arena's type waits for the transposition (1.6 GB
+    at 400 000 x 2 000) while the arena is being allocated."""
+    return bins.T.astype(ARENA_DT)
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def init_pristine(arena, bins_t):
     """Write the PER-DATASET arena channels (feature bins + rowid byte
@@ -269,31 +278,73 @@ def _append_plan(fill, counts):
     return fills, flushed, chunk_no, t
 
 
-def _staging_shape(C: int, tile: int) -> tuple:
-    """partition_segment's staging slots: (stream, parity, append k)."""
-    return (2, 2, tile // SUB, C, FLUSH_W)
+def _staging_shape(rows: int, tile: int) -> tuple:
+    """partition_segment's staging slots: (stream, parity, append k) of
+    the `rows` channels the kernel holds at a time."""
+    return (2, 2, tile // SUB, rows, FLUSH_W)
 
 
 def _partition_vmem_limit(C: int, hist_shapes):
-    """None (Mosaic's default) while the kernel fits it, else what it
-    needs.  Measured by compiling for a v5e: 17.75 MB at C = 512 without
-    the histogram output, under 16 MiB at C = 448; _VMEM_PER_CHANNEL
-    leaves 15 % over that slope, and the fused histogram's accumulator
-    counts twice (the output and the products added into it).  The limit
-    is raised only when it must be: XLA runs the fusions around the
-    kernel slower under a larger one (higgs, C = 48: 0.8 ms per
-    iteration in `broadcast_select_fusion`, PERF.md PR 26)."""
+    """None (Mosaic's default) while the one-block kernel fits it, else
+    what it needs: only the fused histogram's accumulator can ask for
+    more, since a C that does not fit by itself is cut into channel
+    blocks (`_channel_block`).  The accumulator counts twice (the output
+    and the products added into it).  The limit is raised only when it
+    must be: XLA runs the fusions around the kernel slower under a larger
+    one (higgs, C = 48: 0.8 ms per iteration in `broadcast_select_fusion`,
+    PERF.md PR 26)."""
     need = C * _VMEM_PER_CHANNEL + sum(
         2 * 4 * math.prod(h.shape) for h in hist_shapes)
     return need if need > _VMEM_DEFAULT else None
 
 
+def _channel_block(C: int, per_channel: int, resident: int = 0,
+                   fixed: int = 0) -> int:
+    """Channels an arena kernel holds in VMEM at a time: the largest
+    divisor of C that is a multiple of the bf16 sublane tile and whose
+    `per_channel` bytes a channel fit Mosaic's default scoped VMEM beside
+    what the kernel keeps for every channel (`resident` bytes each) and
+    for none (`fixed`).  Derived from static shapes only; the default
+    limit is never raised for a block (see `_partition_vmem_limit` for
+    what a larger one costs)."""
+    room = _VMEM_DEFAULT - C * resident - fixed
+    for n in range(1, C // _SUBL + 1):
+        if C % (n * _SUBL) == 0 and (C // n) * per_channel <= room:
+            return C // n
+    raise ValueError(
+        "no channel block serves %d arena channels: %d B of VMEM per "
+        "channel of a block, %d B resident per channel and %d B fixed "
+        "leave no multiple of %d channels under the %d B a kernel may use"
+        % (C, per_channel, resident, fixed, _SUBL, _VMEM_DEFAULT))
+
+
+# Blocked, partition_segment holds per channel of a block the read slots
+# (8 KiB), the staging (16 KiB) and the sort products Mosaic spills (8 KiB);
+# of EVERY channel the two streams' carries; of none the tile's permutation
+# (1 MiB), the decision rows and the pred tiles.  Compiled for a v5e at
+# C = 2016: blocks of 336 fit the default limit, 20.86 MB of scratch at
+# 672 do not.
+_PART_PER_BLOCK_CHANNEL = 32 << 10
+_PART_RESIDENT = 2 * FLUSH_W * 4
+_PART_FIXED = (TILE // SUB) * SUB * SUB * 2 + (256 << 10)
+
+
+def partition_channel_block(C: int) -> int:
+    """partition_segment's channel block at C arena channels: C itself
+    up to the width whose one-block kernel fits the default VMEM."""
+    if C * _VMEM_PER_CHANNEL <= _VMEM_DEFAULT:
+        return C
+    return _channel_block(C, _PART_PER_BLOCK_CHANNEL, _PART_RESIDENT,
+                          _PART_FIXED)
+
+
 def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
                       out_any, cnt_ref, *rest,
-                      C: int, tile: int, hist_plan=None):
+                      C: int, tile: int, hist_plan=None, cb: int = 0):
     """sc_ref (SMEM [7] i32): start, cnt, dstA, dstB, mode, xr, hs —
     start, dstA and dstB must be multiples of `tile` resp. FLUSH_W (the
-    bump allocator aligns).
+    bump allocator aligns).  Blocked (cb < C), an eighth entry holds the
+    first channel of the 16-row group around the split feature's channel.
     arena_any/out_any: [C, cap] bf16 in HBM, aliased (same buffer).
     Routing: mode=0 reads pred_any ([1, cap] f32, 1.0 -> stream A);
     mode=1 computes the split decision in-kernel (`_decide`), XOR'd with
@@ -326,9 +377,39 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
     reach at most dstA + wA + FLUSH_W <= start + (j+1)*tile, columns whose
     reads (tiles 0..j) completed before iteration j began, and the read of
     tile j+1 in flight beside them is disjoint from them.
+
+    CHANNEL BLOCKS (cb < C: an arena too wide for one [C, tile] slab in
+    VMEM).  Where a row goes depends on the predicate only, so the tile's
+    decision, prefix scan, [K, SUB, SUB] permutation and append plan are
+    made ONCE per tile, from the 16-row group that holds the split
+    feature's channel (a small DMA of its own; `pred` in mode 0), and
+    every block of cb channels replays them: its own read DMA, sort
+    products, appends onto its own carries (kept for all C/cb blocks, 2 KiB
+    a channel) and flushes to its own rows.  The pipeline's unit becomes
+    the step (tile j, block b), tile-major: step t waits the flushes of
+    step t-2 (same staging parity), starts read t+1, computes, starts its
+    flushes, waits read t+1.  Fill and written are the same for every
+    block of a tile (one permutation), so they stay two scalars a stream.
+    The in-place invariant holds per block: step (j, b) writes block b's
+    rows only, at columns <= start + (j+1)*tile, which that block's reads
+    of tiles 0..j (steps (0..j, b), all complete before step (j, b)
+    starts) have consumed; the read in flight beside them is either
+    another block's rows of tile j or block 0's rows of tile j+1, and the
+    decision group of tile j+1 (read during step (j, 0)) lies in tile
+    j+1's columns too: all disjoint from every write made so far.  Blocks
+    tile C exactly (`_channel_block`): a shifted last block would re-read
+    rows an earlier block has already overwritten in place.
     """
-    if hist_plan is None:
-        hist_ref = None
+    cb = cb or C
+    n_cb = C // cb
+    blocked = n_cb > 1
+    K = tile // SUB
+    hist_ref = P_ref = dec_buf = carries = carryA = carryB = None
+    if blocked:
+        assert hist_plan is None and n_cb * cb == C
+        (in_buf, pred_buf, dec_buf, carries, stage, P_ref,
+         read_sems, pred_sems, dec_sems, write_sems) = rest
+    elif hist_plan is None:
         (in_buf, pred_buf, carryA, carryB, stage,
          read_sems, pred_sems, write_sems) = rest
     else:
@@ -346,50 +427,29 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
     #                   the smaller (stream-B) side
     hs = sc_ref[6]    # fused-histogram stream: 1 -> B, 0 -> A
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
-    K = tile // SUB
     lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
 
-    def read_dmas(j, slot):
-        src = pl.multiple_of(s + j * tile, 128)
-        # the pred stream is only consumed in mode 0; in decision mode the
-        # caller passes a [1, tile] dummy (a full [1, cap] zeros buffer
-        # gets constant-sunk into the grow while-loop by XLA and
-        # re-materialized EVERY split — measured 75 ms/iter) and the DMA
-        # pins its read to offset 0
-        psrc = jnp.where(mode == 0, src, 0)
-        return (pltpu.make_async_copy(
-                    arena_any.at[:, pl.ds(src, tile)],
-                    in_buf.at[slot], read_sems.at[slot]),
-                pltpu.make_async_copy(
-                    pred_any.at[:, pl.ds(pl.multiple_of(psrc, 128), tile)],
-                    pred_buf.at[slot], pred_sems.at[slot]))
+    def flush_dma(stream, slot, k, dst_col, row0=None):
+        cols = pl.ds(pl.multiple_of(dst_col, 128), FLUSH_W)
+        ref = (out_any.at[:, cols] if row0 is None else
+               out_any.at[pl.ds(pl.multiple_of(row0, _SUBL), cb), cols])
+        return pltpu.make_async_copy(stage.at[stream, slot, k], ref,
+                                     write_sems.at[stream, slot, k])
 
-    def flush_dma(stream, slot, k, dst_col):
-        return pltpu.make_async_copy(
-            stage.at[stream, slot, k],
-            out_any.at[:, pl.ds(pl.multiple_of(dst_col, 128), FLUSH_W)],
-            write_sems.at[stream, slot, k])
+    # the wait of a flush needs its shape only: any block's rows do
+    wait_row0 = 0 if blocked else None
 
     def wait_flushes(slot, pending):
-        """Wait the flushes a tile started from parity `slot`; `pending`
+        """Wait the flushes a step started from parity `slot`; `pending`
         holds per stream the bit mask of the appends that flushed."""
         for stream in range(2):
             for k in range(K):
                 @pl.when(((pending[stream] >> k) & 1) == 1)
                 def _(stream=stream, k=k):
-                    flush_dma(stream, slot, k, 0).wait()
-
-    @pl.when(n_tiles > 0)
-    def _():
-        for d in read_dmas(0, 0):
-            d.start()
-        for d in read_dmas(0, 0):
-            d.wait()
-    carryA[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
-    carryB[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
+                    flush_dma(stream, slot, k, 0, wait_row0).wait()
 
     def append(carry, chunk, lo, fill, flushed, stage_at):
-        """chunk ([C, SUB] f32) holds this stream's rows at lanes
+        """chunk ([rows, SUB] f32) holds this stream's rows at lanes
         [lo, lo+ck), zeros elsewhere; rotate them onto window lanes
         [fill, fill+ck) mod FLUSH_W.  Lanes >= fill continue the carry's
         chunk, lanes < fill are what wrapped: the head of the next chunk.
@@ -405,24 +465,19 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
         return jnp.where(keep, window,
                          jnp.where(tail, jnp.float32(0.0), rolled))
 
-    def loop(j, state):
-        fills, written, pending, pending2 = state
-        slot = jax.lax.rem(j, jnp.int32(2))
-        nslot = 1 - slot
-        # tile j-2 staged this parity: its flushes must have landed
-        wait_flushes(slot, pending2)
-
-        @pl.when(j + 1 < n_tiles)
-        def _():
-            for d in read_dmas(j + 1, nslot):
-                d.start()
-
+    def tile_permutation(j, rows_at, pred_at):
+        """The predicate's part of tile j, made once whatever the number
+        of channel blocks: the rows' streams, ONE batched prefix scan for
+        all subblocks of both streams (the per-subblock scans were
+        2*K*log2(SUB) serial roll steps) and ONE batched P build.
+        `rows_at()` gives the rows the decision reads.  Returns (those
+        rows, P_all [K, S, S], the 2K subblock counts)."""
         valid = jax.lax.broadcasted_iota(
             jnp.int32, (1, tile), 1) < (cnt - j * tile)
-        block = in_buf[slot]
+        rows = rows_at()
         mode_f = jnp.float32(mode)
-        on_f = (mode_f * _decide(block, feat_onehot_ref, mask_ref, xr)
-                + (1.0 - mode_f) * pred_buf[slot])
+        on_f = (mode_f * _decide(rows, feat_onehot_ref, mask_ref, xr)
+                + (1.0 - mode_f) * pred_at())
         on = on_f > 0.5
         predA = jnp.where(valid & on, jnp.float32(1.0), jnp.float32(0.0))
         predB = jnp.where(valid & ~on, jnp.float32(1.0), jnp.float32(0.0))
@@ -431,87 +486,256 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
             hs_f = hs.astype(jnp.float32)
             hmask = (hs_f * predB + (1.0 - hs_f) * predA).astype(jnp.bfloat16)
             nb_h, k_h, m_h, lo_h, hi_h, pay_h = hist_plan
-            _radix_accumulate(hist_ref, block, hmask, n_blocks=nb_h, k=k_h,
+            _radix_accumulate(hist_ref, rows, hmask, n_blocks=nb_h, k=k_h,
                               m=m_h, lo_n=lo_h, hi_n=hi_h, tile=tile,
                               payload=pay_h)
 
-        # ONE batched prefix scan for all subblocks of both streams — the
-        # per-subblock scans were 2*K*log2(SUB) serial roll steps.  Then
-        # ONE batched P build and K dependency-free SORT matmuls
-        # ([C,S]@[S,S]: A-prefix + B-suffix in a single product — half the
-        # MACs of the dual-stream [S,2S] build).
         pred2 = jnp.concatenate(
             [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
         pref2 = _prefix_scan_lanes(pred2)                  # [2K, SUB]
         cnt2 = pref2[:, SUB - 1].astype(jnp.int32)         # [2K]
-        P_all = _sort_P(pref2, pred2, K)                   # [K, S, S]
-        comps = [jax.lax.dot(block[:, k * SUB:(k + 1) * SUB], P_all[k],
+        return rows, _sort_P(pref2, pred2, K), cnt2        # P: [K, S, S]
+
+    def sort_append(block, P_at, plan_of, carries_in, slot):
+        """K dependency-free SORT matmuls ([rows,S]@[S,S]: A-prefix +
+        B-suffix in a single product — half the MACs of the dual-stream
+        [S,2S] build) and the 2K straight-line appends of one block of
+        channels.  `plan_of()` gives the tile's (cA, cB) subblock counts,
+        `plan_of(cA, cB)` its two append plans.  Returns (the two new
+        carries, the plans)."""
+        comps = [jax.lax.dot(block[:, k * SUB:(k + 1) * SUB], P_at(k),
                              preferred_element_type=jnp.float32)
-                 for k in range(K)]                        # [C, S] f32
+                 for k in range(K)]                        # [rows, S] f32
         # split each sorted block into its A-prefix / B-suffix; the
         # B chunk is a subtraction, not a second select
-        cA = [cnt2[k] for k in range(K)]
-        cB = [cnt2[K + k] for k in range(K)]
+        cA, cB = plan_of()
         chunksA = [jnp.where(lane_s < cA[k], comps[k], jnp.float32(0.0))
                    for k in range(K)]
         chunksB = [comps[k] - chunksA[k] for k in range(K)]
-
-        plans = (_append_plan(fills[0], cA), _append_plan(fills[1], cB))
+        plans = plan_of(cA, cB)
         zero = jnp.int32(0)
-        carries = [carryA[:], carryB[:]]
+        out = carries_in()
         for k in range(K):
             for stream, chunk, lo in ((0, chunksA[k], zero),
                                       (1, chunksB[k], cA[k])):
                 p_fill, p_flushed, _, _ = plans[stream]
-                carries[stream] = append(
-                    carries[stream], chunk, lo, p_fill[k], p_flushed[k],
+                out[stream] = append(
+                    out[stream], chunk, lo, p_fill[k], p_flushed[k],
                     (stream, slot, k))
-        carryA[:], carryB[:] = carries
+        return out, plans
 
+    def start_flushes(plans, written, slot, row0=None):
+        """Start the DMAs of the chunks a step's appends completed.
+        Returns per stream (fill, written) after the tile and the bit
+        mask of the appends that flushed."""
         new_fills, new_written, new_pending = [], [], []
         for stream in range(2):
             _, p_flushed, p_chunk_no, end = plans[stream]
-            bits = zero
+            bits = jnp.int32(0)
             for k in range(K):
                 @pl.when(p_flushed[k])
                 def _(stream=stream, k=k, chunk_no=p_chunk_no[k]):
                     flush_dma(stream, slot, k,
                               dsts[stream] + written[stream]
-                              + chunk_no * FLUSH_W).start()
+                              + chunk_no * FLUSH_W, row0).start()
                 bits = bits | (p_flushed[k].astype(jnp.int32) << k)
             done = jax.lax.div(end, jnp.int32(FLUSH_W))
             new_fills.append(end - done * FLUSH_W)
             new_written.append(written[stream] + done * FLUSH_W)
             new_pending.append(bits)
-
-        @pl.when(j + 1 < n_tiles)
-        def _():
-            for d in read_dmas(j + 1, nslot):
-                d.wait()
-        return (tuple(new_fills), tuple(new_written), tuple(new_pending),
-                pending)
+        return tuple(new_fills), tuple(new_written), tuple(new_pending)
 
     z2 = (jnp.int32(0), jnp.int32(0))
-    fills, written, pending, pending2 = jax.lax.fori_loop(
-        0, n_tiles, loop, (z2, z2, z2, z2))
+    if not blocked:
+        def read_dmas(j, slot):
+            src = pl.multiple_of(s + j * tile, 128)
+            # the pred stream is only consumed in mode 0; in decision mode
+            # the caller passes a [1, tile] dummy (a full [1, cap] zeros
+            # buffer gets constant-sunk into the grow while-loop by XLA and
+            # re-materialized EVERY split — measured 75 ms/iter) and the
+            # DMA pins its read to offset 0
+            psrc = jnp.where(mode == 0, src, 0)
+            return (pltpu.make_async_copy(
+                        arena_any.at[:, pl.ds(src, tile)],
+                        in_buf.at[slot], read_sems.at[slot]),
+                    pltpu.make_async_copy(
+                        pred_any.at[:, pl.ds(pl.multiple_of(psrc, 128), tile)],
+                        pred_buf.at[slot], pred_sems.at[slot]))
 
-    # Drain the last two tiles' flushes (parities of tiles n-2 and n-1),
+        @pl.when(n_tiles > 0)
+        def _():
+            for d in read_dmas(0, 0):
+                d.start()
+            for d in read_dmas(0, 0):
+                d.wait()
+        carryA[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
+        carryB[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
+
+        def loop(j, state):
+            fills, written, pending, pending2 = state
+            slot = jax.lax.rem(j, jnp.int32(2))
+            nslot = 1 - slot
+            # tile j-2 staged this parity: its flushes must have landed
+            wait_flushes(slot, pending2)
+
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                for d in read_dmas(j + 1, nslot):
+                    d.start()
+
+            block, P_all, cnt2 = tile_permutation(
+                j, lambda: in_buf[slot], lambda: pred_buf[slot])
+
+            def plan_of(cA=None, cB=None):
+                if cA is None:
+                    return ([cnt2[k] for k in range(K)],
+                            [cnt2[K + k] for k in range(K)])
+                return (_append_plan(fills[0], cA),
+                        _append_plan(fills[1], cB))
+
+            (carryA[:], carryB[:]), plans = sort_append(
+                block, lambda k: P_all[k], plan_of,
+                lambda: [carryA[:], carryB[:]], slot)
+            new_fills, new_written, new_pending = start_flushes(
+                plans, written, slot)
+
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                for d in read_dmas(j + 1, nslot):
+                    d.wait()
+            return new_fills, new_written, new_pending, pending
+
+        fills, written, pending, pending2 = jax.lax.fori_loop(
+            0, n_tiles, loop, (z2, z2, z2, z2))
+        n_steps = n_tiles
+    else:
+        grp = sc_ref[7]   # first channel of the split feature's 16-row group
+
+        def rows_dma(j, row0, rows, buf, sems, slot):
+            """Read `rows` channels from `row0` of tile j."""
+            src = pl.multiple_of(s + j * tile, 128)
+            return pltpu.make_async_copy(
+                arena_any.at[pl.ds(pl.multiple_of(row0, _SUBL), rows),
+                             pl.ds(src, tile)],
+                buf.at[slot], sems.at[slot])
+
+        def tile_dmas(j, slot):
+            """What tile j's predicate is made from: the decision's row
+            group and, for mode 0, the pred tile (pinned to offset 0 in
+            decision mode, as in the one-block kernel)."""
+            src = pl.multiple_of(s + j * tile, 128)
+            psrc = jnp.where(mode == 0, src, 0)
+            return (rows_dma(j, grp, _SUBL, dec_buf, dec_sems, slot),
+                    pltpu.make_async_copy(
+                        pred_any.at[:, pl.ds(pl.multiple_of(psrc, 128), tile)],
+                        pred_buf.at[slot], pred_sems.at[slot]))
+
+        @pl.when(n_tiles > 0)
+        def _():
+            first = tile_dmas(0, 0) + (
+                rows_dma(0, 0, cb, in_buf, read_sems, 0),)
+            for d in first:
+                d.start()
+            for d in first:
+                d.wait()
+
+        def clear(b, _):
+            carries[b] = jnp.zeros((2, cb, FLUSH_W), jnp.float32)
+            return 0
+        jax.lax.fori_loop(0, n_cb, clear, 0)
+
+        def tile_loop(j, state):
+            fills, written, pending, pending2 = state
+            tslot = jax.lax.rem(j, jnp.int32(2))
+            _, P_all, cnt2 = tile_permutation(
+                j, lambda: dec_buf[tslot], lambda: pred_buf[tslot])
+            P_ref[:] = P_all
+            cA = [cnt2[k] for k in range(K)]
+            cB = [cnt2[K + k] for k in range(K)]
+            plans = (_append_plan(fills[0], cA), _append_plan(fills[1], cB))
+            more_tiles = j + 1 < n_tiles
+
+            def plan_of(a=None, b=None):
+                return (cA, cB) if a is None else plans
+
+            def block_step(b, pend):
+                pending, pending2 = pend
+                t = j * n_cb + b
+                slot = jax.lax.rem(t, jnp.int32(2))
+                nslot = 1 - slot
+                # step t-2 staged this parity: its flushes must have landed
+                wait_flushes(slot, pending2)
+                wraps = b + 1 == n_cb
+                nj = jnp.where(wraps, j + 1, j)
+                nrow = jnp.where(wraps, 0, (b + 1) * cb)
+                has_next = nj < n_tiles
+                next_tile = more_tiles & (b == 0)
+
+                @pl.when(has_next)
+                def _():
+                    rows_dma(nj, nrow, cb, in_buf, read_sems, nslot).start()
+
+                @pl.when(next_tile)
+                def _():
+                    for d in tile_dmas(j + 1, 1 - tslot):
+                        d.start()
+
+                (carries[b, 0], carries[b, 1]), _ = sort_append(
+                    in_buf[slot], lambda k: P_ref[k], plan_of,
+                    lambda: [carries[b, 0], carries[b, 1]], slot)
+                _, _, new_pending = start_flushes(plans, written, slot,
+                                                  b * cb)
+
+                @pl.when(has_next)
+                def _():
+                    rows_dma(nj, nrow, cb, in_buf, read_sems, nslot).wait()
+
+                @pl.when(next_tile)
+                def _():
+                    for d in tile_dmas(j + 1, 1 - tslot):
+                        d.wait()
+                return new_pending, pending
+
+            pending, pending2 = jax.lax.fori_loop(
+                0, n_cb, block_step, (pending, pending2))
+            ends = [plans[stream][3] for stream in range(2)]
+            done = [jax.lax.div(e, jnp.int32(FLUSH_W)) for e in ends]
+            return (tuple(e - d * FLUSH_W for e, d in zip(ends, done)),
+                    tuple(w + d * FLUSH_W for w, d in zip(written, done)),
+                    pending, pending2)
+
+        fills, written, pending, pending2 = jax.lax.fori_loop(
+            0, n_tiles, tile_loop, (z2, z2, z2, z2))
+        n_steps = n_tiles * n_cb
+
+    # Drain the last two steps' flushes (parities of steps n-2 and n-1),
     # then write each stream's partial chunk from a staging slot that is
     # free again.
-    last = jax.lax.rem(n_tiles + jnp.int32(1), jnp.int32(2))
+    last = jax.lax.rem(n_steps + jnp.int32(1), jnp.int32(2))
     wait_flushes(1 - last, pending2)
     wait_flushes(last, pending)
-    for stream, carry in ((0, carryA), (1, carryB)):
-        @pl.when(fills[stream] > 0)
-        def _(stream=stream, carry=carry):
-            # + 0.0: rows that wrapped in the tile's last flushing append
-            # have not passed through an add yet (-0.0 -> +0.0, as every
-            # other row)
-            stage[stream, 0, 0] = (carry[:] + jnp.float32(0.0)
-                                   ).astype(ARENA_DT)
-            final = flush_dma(stream, 0, 0, dsts[stream] + written[stream])
-            final.start()
-            final.wait()
+
+    def write_partials(carry_of, row0=None):
+        for stream in range(2):
+            @pl.when(fills[stream] > 0)
+            def _(stream=stream):
+                # + 0.0: rows that wrapped in the tile's last flushing
+                # append have not passed through an add yet (-0.0 -> +0.0,
+                # as every other row)
+                stage[stream, 0, 0] = (carry_of(stream) + jnp.float32(0.0)
+                                       ).astype(ARENA_DT)
+                final = flush_dma(stream, 0, 0,
+                                  dsts[stream] + written[stream], row0)
+                final.start()
+                final.wait()
+
+    if not blocked:
+        write_partials(lambda stream: (carryA, carryB)[stream][:])
+    else:
+        def partials(b, _):
+            write_partials(lambda stream: carries[b, stream], b * cb)
+            return 0
+        jax.lax.fori_loop(0, n_cb, partials, 0)
 
     cnt_ref[0] = written[0] + fills[0]
     cnt_ref[1] = written[1] + fills[1]
@@ -542,30 +766,41 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
     the partition + histogram fusion (used for the bagging root pass;
     a parent-size-gated fusion on the split path was measured ~10%
     WORSE end-to-end in round 5 — the hist output's per-launch setup
-    outweighs the separate O(child) kernel's fixed cost).
+    outweighs the separate O(child) kernel's fixed cost).  An arena cut
+    into channel blocks (`partition_channel_block`) has its payload
+    planes in the last block only, so there the histogram is a
+    `segment_histogram` of the stream just written.
 
     Returns (new_arena, counts[2] int32[, hist]).  Writes stay within
     align(count, FLUSH_W) columns of each stream's dst; reads overrun the
     segment by < tile columns, so callers keep cap >= last segment + tile.
     """
     C, cap = arena.shape
+    cb = partition_channel_block(C)
+    blocked = cb < C
     z = jnp.int32(0)
     MB = 256   # mask lane width (any bin value < 256 fits)
+    # blocked, the kernel reads the decision from the 16-row group around
+    # the feature's channel, and the one-hot addresses a row of the group
+    W = _SUBL if blocked else C
     if decision is None:
         tail = [z, z]
-        feat_onehot = jnp.zeros((1, C), ARENA_DT)
+        feat = z
+        feat_onehot = jnp.zeros((1, W), ARENA_DT)
         goleft = jnp.zeros((1, MB), ARENA_DT)
     else:
         feat, mask_vec, xr = decision
         feat = jnp.asarray(feat, jnp.int32)
         tail = [jnp.int32(1), jnp.asarray(xr, jnp.int32)]
-        feat_onehot = (jnp.arange(C, dtype=jnp.int32)[None, :]
-                       == feat).astype(ARENA_DT)
+        feat_onehot = (jnp.arange(W, dtype=jnp.int32)[None, :]
+                       == (feat % W if blocked else feat)).astype(ARENA_DT)
         mv = jnp.asarray(mask_vec, jnp.float32).reshape(1, -1)
         goleft = jnp.pad(mv, ((0, 0), (0, MB - mv.shape[1]))
                          ).astype(ARENA_DT)
     with_hist = hist_stream is not None
     tail.append(jnp.asarray(hist_stream if with_hist else 0, jnp.int32))
+    if blocked:
+        tail.append(feat // _SUBL * _SUBL)
     sc = jnp.stack([jnp.asarray(start), jnp.asarray(cnt),
                     jnp.asarray(dstA), jnp.asarray(dstB)]
                    + tail).astype(jnp.int32)
@@ -575,7 +810,32 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
     out_shape = [jax.ShapeDtypeStruct((C, cap), ARENA_DT),
                  jax.ShapeDtypeStruct((2,), jnp.int32)]
     payload = 3 if quantized else 7
-    if with_hist:
+    K = tile // SUB
+    if blocked:
+        scratch = [
+            pltpu.VMEM((2, cb, tile), ARENA_DT),
+            pltpu.VMEM((2, 1, tile), jnp.float32),
+            pltpu.VMEM((2, _SUBL, tile), ARENA_DT),
+            pltpu.VMEM((C // cb, 2, cb, FLUSH_W), jnp.float32),
+            pltpu.VMEM(_staging_shape(cb, tile), ARENA_DT),
+            pltpu.VMEM((K, SUB, SUB), jnp.bfloat16),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2, 2, K)),
+        ]
+    else:
+        scratch = [
+            pltpu.VMEM((2, C, tile), ARENA_DT),
+            pltpu.VMEM((2, 1, tile), jnp.float32),
+            pltpu.VMEM((C, FLUSH_W), jnp.float32),
+            pltpu.VMEM((C, FLUSH_W), jnp.float32),
+            pltpu.VMEM(_staging_shape(C, tile), ARENA_DT),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2, 2, K)),
+        ]
+    if with_hist and not blocked:
         lo_n, hi_n, m = _radix_plan(max_bin)
         f_blk = max(m, 8)
         k = f_blk // m
@@ -586,7 +846,7 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
         out_shape.append(
             jax.ShapeDtypeStruct((n_blocks * k * Mc, N), jnp.float32))
     kernel = functools.partial(_partition_kernel, C=C, tile=tile,
-                               hist_plan=hist_plan)
+                               hist_plan=hist_plan, cb=cb)
     outs = pl.pallas_call(
         kernel,
         in_specs=[
@@ -598,24 +858,26 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
         ],
         out_specs=out_specs,
         out_shape=tuple(out_shape),
-        scratch_shapes=[
-            pltpu.VMEM((2, C, tile), ARENA_DT),
-            pltpu.VMEM((2, 1, tile), jnp.float32),
-            pltpu.VMEM((C, FLUSH_W), jnp.float32),
-            pltpu.VMEM((C, FLUSH_W), jnp.float32),
-            pltpu.VMEM(_staging_shape(C, tile), ARENA_DT),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2, 2, tile // SUB)),
-        ],
+        scratch_shapes=scratch,
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
-            vmem_limit_bytes=_partition_vmem_limit(C, out_shape[2:])),
+            vmem_limit_bytes=(None if blocked else
+                              _partition_vmem_limit(C, out_shape[2:]))),
         interpret=interpret,
     )(sc, feat_onehot, goleft, arena, pred)
     if not with_hist:
         return outs[0], outs[1]
+    if blocked:
+        # (XLA glue of the block plan takes the caller's scope: this runs
+        # under lgbm.root, the bagging root pass)
+        hs = jnp.asarray(hist_stream, jnp.int32)
+        hist = segment_histogram(
+            outs[0], jnp.where(hs == 1, jnp.asarray(dstB, jnp.int32),
+                               jnp.asarray(dstA, jnp.int32)),
+            outs[1][hs], num_features=num_features, max_bin=max_bin,
+            tile=tile, interpret=interpret, quantized=quantized)
+        return outs[0], outs[1], hist
     hist = split_radix_epilogue(outs[2], n_blocks * k, m, hi_n=hi_n,
                                 lo_n=lo_n,
                                 payload=payload)[:num_features, :max_bin, :]
@@ -624,7 +886,8 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
 
 def _compact_carry_kernel(sc_ref, starts_ref, cnts_ref, arena_any, out_any,
                           used_ref, in_buf, carry, flush_buf,
-                          read_sems, write_sems, *, C: int, tile: int):
+                          read_sems, write_sems, *, C: int, tile: int,
+                          blocked: bool = False):
     """Compact the live leaf segments' FULL channel rows into one dense
     contiguous block at dst0 — the carried-arena tree boundary: instead
     of extracting (rowid, value) pairs and sorting scores back to row
@@ -641,22 +904,32 @@ def _compact_carry_kernel(sc_ref, starts_ref, cnts_ref, arena_any, out_any,
     output packs segments in LEAF-INDEX order (callers derive per-row
     leaf values from cumsum(cnts)).
     used_ref (SMEM [1] i32): rows written (= sum of cnts).
+
+    `blocked`: C is one block of a wider arena's channels and the call a
+    grid over the blocks; each step compacts its own rows of every
+    segment, start to drain, so nothing but the row offset differs (the
+    destination is disjoint from every live segment: no step reads what
+    another wrote).
     """
     nseg, dst0 = sc_ref[0], sc_ref[1]
     K = tile // SUB
     lane_w = jax.lax.broadcasted_iota(jnp.int32, (C, CARRY_W), 1)
     lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
+    if blocked:
+        rows = pl.ds(pl.multiple_of(pl.program_id(0) * C, _SUBL), C)
+    else:
+        rows = slice(None)
 
     def read_dma(start, j, slot):
         src = pl.multiple_of(start + j * tile, 128)
         return pltpu.make_async_copy(
-            arena_any.at[:, pl.ds(src, tile)],
+            arena_any.at[rows, pl.ds(src, tile)],
             in_buf.at[slot], read_sems.at[slot])
 
     def flush_dma(slot, dst_col):
         return pltpu.make_async_copy(
             flush_buf.at[slot],
-            out_any.at[:, pl.ds(pl.multiple_of(dst_col, 128), FLUSH_W)],
+            out_any.at[rows, pl.ds(pl.multiple_of(dst_col, 128), FLUSH_W)],
             write_sems.at[slot])
 
     carry[:] = jnp.zeros((C, CARRY_W), jnp.float32)
@@ -742,6 +1015,17 @@ def _compact_carry_kernel(sc_ref, starts_ref, cnts_ref, arena_any, out_any,
     used_ref[0] = written + fill
 
 
+# compact_carry per channel: the read slots (8 KiB), the carry window
+# (2 KiB), the flush slots (1 KiB) and the append's padded, rolled and
+# shifted copies of the window Mosaic spills
+_COMPACT_PER_CHANNEL = 24 << 10
+
+
+def compact_channel_block(C: int) -> int:
+    """compact_carry's channel block at C arena channels."""
+    return _channel_block(C, _COMPACT_PER_CHANNEL)
+
+
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def compact_carry(arena, starts, cnts, num_live, dst0,
                   tile: int = TILE, interpret: bool = False):
@@ -749,9 +1033,14 @@ def compact_carry(arena, starts, cnts, num_live, dst0,
     block at dst0; returns (arena', rows_written).  dst0 must be
     FLUSH_W-aligned and its block disjoint from every live segment."""
     C, cap = arena.shape
+    cb = compact_channel_block(C)
+    # a grid over the channel blocks only where there is more than one:
+    # narrow data compiles the call it always has
+    grid = {"grid": (C // cb,)} if cb < C else {}
     sc = jnp.stack([jnp.asarray(num_live),
                     jnp.asarray(dst0)]).astype(jnp.int32)
-    kernel = functools.partial(_compact_carry_kernel, C=C, tile=tile)
+    kernel = functools.partial(_compact_carry_kernel, C=cb, tile=tile,
+                               blocked=bool(grid))
     out, used = pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -763,15 +1052,16 @@ def compact_carry(arena, starts, cnts, num_live, dst0,
         out_shape=(jax.ShapeDtypeStruct((C, cap), ARENA_DT),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
         scratch_shapes=[
-            pltpu.VMEM((2, C, tile), ARENA_DT),
-            pltpu.VMEM((C, CARRY_W), jnp.float32),
-            pltpu.VMEM((2, C, FLUSH_W), ARENA_DT),
+            pltpu.VMEM((2, cb, tile), ARENA_DT),
+            pltpu.VMEM((cb, CARRY_W), jnp.float32),
+            pltpu.VMEM((2, cb, FLUSH_W), ARENA_DT),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         input_output_aliases={3: 0},
         compiler_params=_side_effect_params(),
         interpret=interpret,
+        **grid,
     )(sc, jnp.asarray(starts, jnp.int32), jnp.asarray(cnts, jnp.int32),
       arena)
     return out, used[0]
@@ -779,7 +1069,8 @@ def compact_carry(arena, starts, cnts, num_live, dst0,
 
 def _compact_rows_kernel(sc_ref, starts_ref, cnts_ref, vals_ref, arena_any,
                          out_any, used_ref, in_buf, out_buf,
-                         read_sems, write_sems, *, fp: int, tile: int):
+                         read_sems, write_sems, *, fp: int, tile: int,
+                         row0: int = 0):
     """Compact the live leaf segments' (rowid, value) pairs into one
     dense stream — the cap-independent replacement for the old
     step-function label recovery (three O(cap) cumsums + an O(cap)
@@ -797,18 +1088,25 @@ def _compact_rows_kernel(sc_ref, starts_ref, cnts_ref, vals_ref, arena_any,
     output cursor; slots beyond the segment count carry dummy_rowid and
     are dropped by the consumer's scatter.  Double-buffered on both the
     read and write sides.
+
+    in_buf holds all C channels, or, for an arena too wide for that
+    (`_rowid_rows`), the sublane-tile-aligned rows from `row0` that span
+    the rowid planes.
     """
     nseg, dummy = sc_ref[0], sc_ref[1]
     dummy_f = dummy.astype(jnp.float32)
+    rows = in_buf.shape[1]
+    fp = fp - row0
 
     def read_dma(start, j, slot):
         # full channel block: a 3-row sublane slice at fp+6 may violate
         # the (16, 128) bf16 memref tiling; the extra bandwidth is ~2 ms
         # at 4M rows, well under what this kernel replaces
         src = pl.multiple_of(start + j * tile, 128)
-        return pltpu.make_async_copy(
-            arena_any.at[:, pl.ds(src, tile)],
-            in_buf.at[slot], read_sems.at[slot])
+        ref = (arena_any.at[pl.ds(row0, rows), pl.ds(src, tile)] if row0
+               else arena_any.at[:, pl.ds(src, tile)])
+        return pltpu.make_async_copy(ref, in_buf.at[slot],
+                                     read_sems.at[slot])
 
     def write_dma(dst_col, slot):
         dst = pl.multiple_of(dst_col, 128)
@@ -867,6 +1165,17 @@ def _compact_rows_kernel(sc_ref, starts_ref, cnts_ref, vals_ref, arena_any,
     used_ref[0] = ocur
 
 
+def _rowid_rows(C: int, fp: int) -> tuple:
+    """(first row, rows) compact_segments reads of each tile: all C
+    channels while the two read slots fit the default VMEM beside the
+    output slots (what narrow data has always compiled), else only the
+    16-row groups that span the rowid planes fp+6 .. fp+8."""
+    if 2 * C * TILE * 2 + (1 << 20) <= _VMEM_DEFAULT:
+        return 0, C
+    row0 = (fp + 6) // _SUBL * _SUBL
+    return row0, -(-(fp + N_AUX) // _SUBL) * _SUBL - row0
+
+
 @functools.partial(jax.jit, static_argnames=("num_features", "capn", "tile",
                                              "interpret"))
 def compact_segments(arena, starts, cnts, vals, num_live, dummy_rowid,
@@ -880,7 +1189,9 @@ def compact_segments(arena, starts, cnts, vals, num_live, dummy_rowid,
     L = starts.shape[0]
     sc = jnp.stack([jnp.asarray(num_live), jnp.asarray(dummy_rowid)]
                    ).astype(jnp.int32)
-    kernel = functools.partial(_compact_rows_kernel, fp=fp, tile=tile)
+    row0, rows = _rowid_rows(C, fp)
+    kernel = functools.partial(_compact_rows_kernel, fp=fp, tile=tile,
+                               row0=row0)
     out, used = pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -893,7 +1204,7 @@ def compact_segments(arena, starts, cnts, vals, num_live, dummy_rowid,
         out_shape=(jax.ShapeDtypeStruct((2, capn), jnp.float32),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
         scratch_shapes=[
-            pltpu.VMEM((2, C, tile), ARENA_DT),
+            pltpu.VMEM((2, rows, tile), ARENA_DT),
             pltpu.VMEM((2, 2, tile), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -989,10 +1300,53 @@ def _radix_accumulate(out_ref, block, mask, *, n_blocks: int, k: int,
             c0 += csz
 
 
-def _seg_hist_kernel(sc_ref, arena_any, out_ref, in_buf, read_sems,
-                     *, C: int, F: int,
+def _feature_block(n_blocks: int, f_blk: int, acc_block_bytes: int) -> int:
+    """Radix blocks (of f_blk features) a histogram kernel takes per grid
+    step.  All of them, in one step and no grid, while the accumulator
+    and the two read slots fit three quarters of Mosaic's default scoped
+    VMEM and the statically unrolled block loop (`_radix_accumulate`) has
+    at most 64 bodies: what narrow data has always compiled.  Else the
+    largest divisor of n_blocks that fits, at 16 bodies or fewer: Mosaic
+    gives every unrolled body its own stack, and 50 bodies of the
+    quantized kernel asked for 35 MB when compiled for a v5e (10 compile
+    in 3 s).  A step's rows start at a multiple of f_blk >= 8, which the
+    DMA takes (40-row steps compiled); one block a step always fits, so
+    every width has a plan.  `acc_block_bytes`: the accumulator's bytes
+    per radix block.  From static shapes only."""
+    room = _VMEM_DEFAULT // 4 * 3
+    slots = 2 * f_blk * TILE * 2
+    if n_blocks <= 64 and n_blocks * (acc_block_bytes + slots) <= room:
+        return n_blocks
+    for n in range(2, n_blocks + 1):
+        d = n_blocks // n
+        if (n_blocks % n == 0 and d <= 16
+                and d * (acc_block_bytes + slots) <= room):
+            return d
+    raise ValueError(
+        "no feature block serves %d radix blocks of %d features: one "
+        "block's accumulator, %d B, does not fit %d B"
+        % (n_blocks, f_blk, acc_block_bytes, room))
+
+
+def _hist_plan(num_features: int, max_bin: int, payload: int) -> tuple:
+    """(lo_n, hi_n, m, f_blk, k, n_blocks, nb) of a histogram kernel: the
+    radix plan of `max_bin`, the features per radix block and blocks per
+    data set, and `nb`, the radix blocks a grid step takes
+    (`_feature_block`; nb == n_blocks: one step, no grid)."""
+    lo_n, hi_n, m = _radix_plan(max_bin)
+    f_blk = max(m, 8)
+    k = f_blk // m
+    n_blocks = feature_channels(num_features) // f_blk
+    acc = k * payload * hi_n * m * lo_n * m * 4
+    return (lo_n, hi_n, m, f_blk, k, n_blocks,
+            _feature_block(n_blocks, f_blk, acc))
+
+
+def _seg_hist_kernel(sc_ref, arena_any, out_ref, in_buf, read_sems, *rest,
+                     C: int, F: int,
                      n_blocks: int, k: int, m: int, lo_n: int, hi_n: int,
-                     tile: int, payload: int = 7, read_rows: int = 0):
+                     tile: int, payload: int = 7, read_rows: int = 0,
+                     pay_row: int = 0):
     """sc_ref (SMEM [2] i32): start, cnt.  out_ref VMEM
     [n_blocks*k*payload*hi_n*m, N]: payload split components per feature —
     every lhs entry is a bf16-exact payload plane value times a one-hot,
@@ -1000,44 +1354,90 @@ def _seg_hist_kernel(sc_ref, arena_any, out_ref, in_buf, read_sems,
     reconstructed exactly in the epilogue.  read_rows < C (quantized
     mode) restricts the per-tile DMA to the leading arena rows that the
     3-component payload actually consumes — the row stripe is the
-    kernel's whole byte bill, so this IS the quantized bandwidth win."""
+    kernel's whole byte bill, so this IS the quantized bandwidth win.
+
+    pay_row > 0: the call is a grid over feature blocks (`_feature_block`)
+    and this step histograms the n_blocks radix blocks from row
+    program_id * read_rows, into its own block of the output; the payload
+    planes then come by a DMA of their own (the 8-row group at pay_row,
+    each step: 8 rows against read_rows), so every feature row is read
+    once however many steps there are.  out_ref is then the whole
+    histogram in HBM, pinned there (XLA otherwise places a result of a
+    few MB in VMEM, whole, beside the kernel's scratch), and the step's
+    accumulator a scratch copied out at the step's end."""
     s, cnt = sc_ref[0], sc_ref[1]
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
     rows = read_rows or C
+    if pay_row:
+        pay_buf, pay_sems, acc, out_sem = rest
+        out_hbm, out_ref = out_ref, acc
+        row0 = pl.multiple_of(pl.program_id(0) * rows, 8)
 
-    def read_dma(j, slot):
-        src = pl.multiple_of(s + j * tile, 128)
-        return pltpu.make_async_copy(
-            arena_any.at[pl.ds(0, rows), pl.ds(src, tile)],
-            in_buf.at[slot], read_sems.at[slot])
+        def read_dmas(j, slot):
+            src = pl.multiple_of(s + j * tile, 128)
+            return (pltpu.make_async_copy(
+                        arena_any.at[pl.ds(row0, rows), pl.ds(src, tile)],
+                        in_buf.at[slot], read_sems.at[slot]),
+                    pltpu.make_async_copy(
+                        arena_any.at[pl.ds(pay_row, _PAY_ROWS),
+                                     pl.ds(src, tile)],
+                        pay_buf.at[slot], pay_sems.at[slot]))
+    else:
+        def read_dmas(j, slot):
+            src = pl.multiple_of(s + j * tile, 128)
+            return (pltpu.make_async_copy(
+                arena_any.at[pl.ds(0, rows), pl.ds(src, tile)],
+                in_buf.at[slot], read_sems.at[slot]),)
 
     out_ref[:] = jnp.zeros_like(out_ref)
 
     @pl.when(n_tiles > 0)
     def _():
-        read_dma(0, 0).start()
-        read_dma(0, 0).wait()
+        for d in read_dmas(0, 0):
+            d.start()
+        for d in read_dmas(0, 0):
+            d.wait()
 
     def loop(j, _):
         slot = jax.lax.rem(j, jnp.int32(2))
 
         @pl.when(j + 1 < n_tiles)
         def _():
-            read_dma(j + 1, jax.lax.rem(j + jnp.int32(1), jnp.int32(2))).start()
+            for d in read_dmas(j + 1,
+                               jax.lax.rem(j + jnp.int32(1), jnp.int32(2))):
+                d.start()
 
         block = in_buf[slot]                              # [rows, T] bf16
         valid = (jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
                  < (cnt - j * tile)).astype(jnp.bfloat16)
+        planes = None
+        if pay_row:
+            pay = pay_buf[slot]
+            planes = [pay[i:i + 1, :] for i in range(payload - 1)]
         _radix_accumulate(out_ref, block, valid, n_blocks=n_blocks, k=k,
                           m=m, lo_n=lo_n, hi_n=hi_n, tile=tile,
-                          payload=payload)
+                          payload=payload, planes=planes)
 
         @pl.when(j + 1 < n_tiles)
         def _():
-            read_dma(j + 1, jax.lax.rem(j + jnp.int32(1), jnp.int32(2))).wait()
+            for d in read_dmas(j + 1,
+                               jax.lax.rem(j + jnp.int32(1), jnp.int32(2))):
+                d.wait()
         return 0
 
     jax.lax.fori_loop(0, n_tiles, loop, 0)
+    if pay_row:
+        _copy_out_block(acc, out_hbm, out_sem)
+
+
+def _copy_out_block(acc, out_hbm, sem):
+    """Write a grid step's accumulator to its block of the HBM output."""
+    n = acc.shape[0]
+    out = pltpu.make_async_copy(
+        acc, out_hbm.at[pl.ds(pl.multiple_of(pl.program_id(0) * n, 8), n)],
+        sem.at[0])
+    out.start()
+    out.wait()
 
 
 def split_radix_epilogue(out, G: int, m: int, hi_n: int, lo_n: int,
@@ -1070,34 +1470,59 @@ def segment_histogram(arena, start, cnt, num_features: int, max_bin: int,
     sums to recover with ops.quantize.dequantize_hist."""
     C, cap = arena.shape
     F = num_features
-    lo_n, hi_n, m = _radix_plan(max_bin)
-    f_blk = max(m, 8)
-    k = f_blk // m
-    n_blocks = feature_channels(F) // f_blk
+    payload = 3 if quantized else 7
+    lo_n, hi_n, m, f_blk, k, n_blocks, nb = _hist_plan(F, max_bin, payload)
     if n_blocks * f_blk + N_AUX > C:
         raise ValueError("arena channels too small for feature layout")
-    payload = 3 if quantized else 7
-    # quantized rows: features + the two code planes, DMA-aligned to the
-    # 8-sublane granule; everything past that row never leaves HBM
-    read_rows = min(C, _align8(n_blocks * f_blk + 2)) if quantized else C
     Mc, N = payload * hi_n * m, lo_n * m
     sc = jnp.stack([jnp.asarray(start), jnp.asarray(cnt)]).astype(jnp.int32)
-    kernel = functools.partial(
-        _seg_hist_kernel, C=C, F=F, n_blocks=n_blocks, k=k, m=m,
-        lo_n=lo_n, hi_n=hi_n, tile=tile, payload=payload,
-        read_rows=read_rows)
-    out = pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_blocks * k * Mc, N), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, read_rows, tile), ARENA_DT),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(sc, arena)
+    if nb == n_blocks:
+        # quantized rows: features + the two code planes, DMA-aligned to
+        # the 8-sublane granule; everything past that row never leaves HBM
+        read_rows = min(C, _align8(n_blocks * f_blk + 2)) if quantized else C
+        kernel = functools.partial(
+            _seg_hist_kernel, C=C, F=F, n_blocks=n_blocks, k=k, m=m,
+            lo_n=lo_n, hi_n=hi_n, tile=tile, payload=payload,
+            read_rows=read_rows)
+        out = pl.pallas_call(
+            kernel,
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((n_blocks * k * Mc, N),
+                                           jnp.float32),
+            scratch_shapes=[
+                pltpu.VMEM((2, read_rows, tile), ARENA_DT),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+            interpret=interpret,
+        )(sc, arena)
+    else:
+        # a grid over blocks of nb * f_blk features: each step reads its
+        # own feature rows and the payload group, fills its own block of
+        # the output
+        kernel = functools.partial(
+            _seg_hist_kernel, C=C, F=F, n_blocks=nb, k=k, m=m,
+            lo_n=lo_n, hi_n=hi_n, tile=tile, payload=payload,
+            read_rows=nb * f_blk, pay_row=n_blocks * f_blk)
+        out = pl.pallas_call(
+            kernel,
+            grid=(n_blocks // nb,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+            out_shape=jax.ShapeDtypeStruct((n_blocks * k * Mc, N),
+                                           jnp.float32),
+            scratch_shapes=[
+                pltpu.VMEM((2, nb * f_blk, tile), ARENA_DT),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, _PAY_ROWS, tile), ARENA_DT),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((nb * k * Mc, N), jnp.float32),
+                pltpu.SemaphoreType.DMA((1,)),
+            ],
+            interpret=interpret,
+        )(sc, arena)
     hist = split_radix_epilogue(out, n_blocks * k, m, hi_n=hi_n, lo_n=lo_n,
                                 payload=payload)
     return hist[:F, :max_bin, :]
@@ -1112,9 +1537,9 @@ _PAY_ROWS = 8
 
 def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
                        in_buf, code_buf, pay_buf, read_sems, code_sems,
-                       pay_sems, write_sems,
-                       *, n_blocks: int, k: int, m: int, lo_n: int,
-                       hi_n: int, tile: int):
+                       pay_sems, write_sems, *rest,
+                       n_blocks: int, k: int, m: int, lo_n: int,
+                       hi_n: int, tile: int, pay_row: int = 0):
     """Fused per-tree g/h-plane refresh + root histogram over ONE arena
     pass (quantized mode): per tile, DMA in the feature rows, the fresh
     code tile and the arena's 8-row payload group [Fp, Fp+8); put the
@@ -1146,15 +1571,41 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
     alternation, no global counters.  Tiles are column-disjoint, so the
     read of tile j+1 never races the write of tile j in the aliased
     buffer.
+
+    pay_row > 0: the call is a grid over feature blocks (`_feature_block`)
+    and this step histograms the n_blocks radix blocks from row
+    program_id * n_blocks * k * m into its own block of hist_ref.  Every
+    step reads the codes (the histogram's planes: 2 rows against the
+    block's hundreds); only step 0 reads, merges and writes the payload
+    group at pay_row, so the refresh is done once and no later step
+    reads a row another step writes (the feature rows are never written).
+    hist_ref is then the whole histogram, pinned in HBM, and the step's
+    accumulator a scratch copied out at the step's end (`_seg_hist_kernel`
+    says why).
     """
     s, cnt = sc_ref[0], sc_ref[1]
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
-    Fp = n_blocks * k * m
+    rows = n_blocks * k * m
+    if pay_row:
+        acc, out_sem = rest
+        hist_hbm, hist_ref = hist_ref, acc
+        Fp = pay_row
+        row0 = pl.multiple_of(pl.program_id(0) * rows, 8)
+        refresh = pl.program_id(0) == 0
+
+        def when_refresh(f):
+            pl.when(refresh)(f)
+    else:
+        Fp = rows
+        row0 = 0
+
+        def when_refresh(f):
+            f()
 
     def feat_dma(j, slot):
         src = pl.multiple_of(s + j * tile, 128)
         return pltpu.make_async_copy(
-            arena_any.at[pl.ds(0, Fp), pl.ds(src, tile)],
+            arena_any.at[pl.ds(row0, rows), pl.ds(src, tile)],
             in_buf.at[slot], read_sems.at[slot])
 
     def code_read_dma(j, slot):
@@ -1180,14 +1631,30 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
         return feat_dma(j, slot), code_read_dma(j, slot), \
             pay_read_dma(j, slot)
 
+    def start_reads(j, slot):
+        if not pay_row:
+            for d in reads(j, slot):
+                d.start()
+            return
+        feat_dma(j, slot).start()
+        code_read_dma(j, slot).start()
+        when_refresh(lambda: pay_read_dma(j, slot).start())
+
+    def wait_reads(j, slot):
+        if not pay_row:
+            for d in reads(j, slot):
+                d.wait()
+            return
+        feat_dma(j, slot).wait()
+        code_read_dma(j, slot).wait()
+        when_refresh(lambda: pay_read_dma(j, slot).wait())
+
     hist_ref[:] = jnp.zeros_like(hist_ref)
 
     @pl.when(n_tiles > 0)
     def _():
-        for d in reads(0, 0):
-            d.start()
-        for d in reads(0, 0):
-            d.wait()
+        start_reads(0, 0)
+        wait_reads(0, 0)
 
     row = jax.lax.broadcasted_iota(jnp.int32, (_PAY_ROWS, tile), 0)
 
@@ -1201,20 +1668,22 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
             # slot's payload buffer is refilled
             @pl.when(j >= 1)
             def _():
-                pay_write_dma(0, nslot).wait()
-            for d in reads(j + 1, nslot):
-                d.start()
+                when_refresh(lambda: pay_write_dma(0, nslot).wait())
+            start_reads(j + 1, nslot)
 
         # codes over the group's rows 0..1, in f32: the values are small
         # integers and byte planes, exact either way, and 32-bit selects
         # are the ones Mosaic lowers without a relayout
         cod = code_buf[slot].astype(jnp.float32)          # [2, T]
-        merged = jnp.where(
-            row == 0, cod[0:1, :],
-            jnp.where(row == 1, cod[1:2, :],
-                      pay_buf[slot].astype(jnp.float32)))
-        pay_buf[slot] = merged.astype(ARENA_DT)
-        pay_write_dma(j, slot).start()
+
+        @when_refresh
+        def _():
+            merged = jnp.where(
+                row == 0, cod[0:1, :],
+                jnp.where(row == 1, cod[1:2, :],
+                          pay_buf[slot].astype(jnp.float32)))
+            pay_buf[slot] = merged.astype(ARENA_DT)
+            pay_write_dma(j, slot).start()
 
         valid = (jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
                  < (cnt - j * tile)).astype(jnp.bfloat16)
@@ -1226,8 +1695,7 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
 
         @pl.when(j + 1 < n_tiles)
         def _():
-            for d in reads(j + 1, nslot):
-                d.wait()
+            wait_reads(j + 1, nslot)
         return 0
 
     jax.lax.fori_loop(0, n_tiles, loop, 0)
@@ -1236,11 +1704,15 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
     # in-loop wait is skipped on the last iteration)
     @pl.when(n_tiles >= 2)
     def _():
-        pay_write_dma(0, jax.lax.rem(n_tiles - 2, jnp.int32(2))).wait()
+        when_refresh(lambda: pay_write_dma(
+            0, jax.lax.rem(n_tiles - 2, jnp.int32(2))).wait())
 
     @pl.when(n_tiles >= 1)
     def _():
-        pay_write_dma(0, jax.lax.rem(n_tiles - 1, jnp.int32(2))).wait()
+        when_refresh(lambda: pay_write_dma(
+            0, jax.lax.rem(n_tiles - 1, jnp.int32(2))).wait())
+    if pay_row:
+        _copy_out_block(acc, hist_hbm, out_sem)
 
 
 @functools.partial(jax.jit,
@@ -1257,10 +1729,7 @@ def fused_refresh_histogram(arena, codes, start, cnt, num_features: int,
     """
     C, cap = arena.shape
     F = num_features
-    lo_n, hi_n, m = _radix_plan(max_bin)
-    f_blk = max(m, 8)
-    k = f_blk // m
-    n_blocks = feature_channels(F) // f_blk
+    lo_n, hi_n, m, f_blk, k, n_blocks, nb = _hist_plan(F, max_bin, 3)
     if n_blocks * f_blk + N_AUX > C:
         raise ValueError("arena channels too small for feature layout")
     Fp = n_blocks * f_blk
@@ -1272,35 +1741,71 @@ def fused_refresh_histogram(arena, codes, start, cnt, num_features: int,
     n_al = -(-n // tile) * tile
     codes = jnp.pad(codes.astype(ARENA_DT), ((0, 0), (0, n_al - n)))
     sc = jnp.stack([jnp.asarray(start), jnp.asarray(cnt)]).astype(jnp.int32)
+    if nb == n_blocks:
+        blocked, more = {}, []
+        hist_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
+    else:
+        # a grid over blocks of nb * f_blk features; step 0 refreshes
+        blocked = {"grid": (n_blocks // nb,)}
+        more = [pltpu.VMEM((nb * k * Mc, N), jnp.float32),
+                pltpu.SemaphoreType.DMA((1,))]
+        hist_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     kernel = functools.partial(
-        _fused_root_kernel, n_blocks=n_blocks, k=k, m=m, lo_n=lo_n,
-        hi_n=hi_n, tile=tile)
+        _fused_root_kernel, n_blocks=nb, k=k, m=m, lo_n=lo_n,
+        hi_n=hi_n, tile=tile, pay_row=Fp if blocked else 0)
     outs = pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
-                   pl.BlockSpec(memory_space=pltpu.VMEM)),
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY), hist_spec),
         out_shape=(jax.ShapeDtypeStruct((C, cap), ARENA_DT),
                    jax.ShapeDtypeStruct((n_blocks * k * Mc, N),
                                         jnp.float32)),
         scratch_shapes=[
-            pltpu.VMEM((2, Fp, tile), ARENA_DT),
+            pltpu.VMEM((2, nb * f_blk, tile), ARENA_DT),
             pltpu.VMEM((2, 2, tile), ARENA_DT),
             pltpu.VMEM((2, _PAY_ROWS, tile), ARENA_DT),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
-        ],
+        ] + more,
         input_output_aliases={2: 0},
         compiler_params=_side_effect_params(),
         interpret=interpret,
+        **blocked,
     )(sc, codes, arena)
     hist = split_radix_epilogue(outs[1], n_blocks * k, m, hi_n=hi_n,
                                 lo_n=lo_n, payload=3)
     return outs[0], hist[:F, :max_bin, :]
+
+
+def engine_plan(num_features: int, max_bin: int, quantized: bool) -> dict:
+    """What the arena kernels derive from a data set's width: channels,
+    channel blocks, feature blocks of the histogram grid and the VMEM each
+    kernel's scratch asks for.  Raises ValueError (with the numbers) where
+    no block plan serves the width; GBDT._setup_tree_engine calls it
+    before it builds an arena, logs it and puts it on the
+    `lgbm:engine_plan` span."""
+    C = arena_channels(num_features)
+    cb, ccb = partition_channel_block(C), compact_channel_block(C)
+    payload = 3 if quantized else 7
+    lo_n, hi_n, m, f_blk, k, n_blocks, nb = _hist_plan(num_features, max_bin,
+                                                       payload)
+    acc = k * payload * hi_n * m * lo_n * m * 4
+    return {
+        "channels": C,
+        "partition_block": cb, "partition_blocks": C // cb,
+        "compact_block": ccb, "compact_blocks": C // ccb,
+        "hist_features_per_step": nb * f_blk, "hist_steps": n_blocks // nb,
+        "vmem_partition": (
+            C * _VMEM_PER_CHANNEL if cb == C else
+            cb * _PART_PER_BLOCK_CHANNEL + C * _PART_RESIDENT + _PART_FIXED),
+        "vmem_compact": ccb * _COMPACT_PER_CHANNEL,
+        "vmem_histogram": nb * (acc + 2 * f_blk * TILE * 2),
+        "vmem_default": _VMEM_DEFAULT,
+    }
 
 
 # -- roofline cost models (obs/perf) ------------------------------------- #

@@ -68,7 +68,17 @@ def _prefix_lanes(x):
 
 
 def _split_scan_kernel(pvec_ref, svec_ref, fvec_ref, hist_ref, out_ref,
-                       best_ref, *, CH: int, F: int, B: int):
+                       best_ref, *, CH: int, F: int, B: int,
+                       blocks: int = 0):
+    """blocks > 0: the call is a grid of CH * blocks steps over blocks of
+    F features of one child each (`_scan_block`); a step scans its own
+    rows, and the cross-feature selection folds its best row into the
+    child's row of best_ref, which stays in VMEM across the grid."""
+    if blocks:
+        step = pl.program_id(0)
+        child = step // blocks
+        f0 = (step - child * blocks) * F
+        CH = 1
     R = CH * F
     l1 = pvec_ref[_L1]
     l2 = pvec_ref[_L2]
@@ -92,6 +102,8 @@ def _split_scan_kernel(pvec_ref, svec_ref, fvec_ref, hist_ref, out_ref,
     row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
 
     def per_child(col):
+        if blocks:
+            return jnp.full((R, 1), 0.0, jnp.float32) + svec_ref[child, col]
         v = jnp.full((R, 1), 0.0, jnp.float32) + svec_ref[0, col]
         for ch in range(1, CH):
             v = jnp.where(row >= ch * F, svec_ref[ch, col], v)
@@ -195,6 +207,8 @@ def _split_scan_kernel(pvec_ref, svec_ref, fvec_ref, hist_ref, out_ref,
     dl = jnp.where(use_desc & ~two_bin_nan, 1.0, 0.0)
 
     feat_id = (row - (row // F) * F).astype(jnp.float32)
+    if blocks:
+        feat_id = feat_id + f0.astype(jnp.float32)
     cols = [feat_gain, feat_id, best_thr, dl, stats[0], stats[1], stats[2],
             lo_p, stats[3], stats[4], stats[5], ro_p]
     block = jnp.concatenate(
@@ -230,7 +244,41 @@ def _split_scan_kernel(pvec_ref, svec_ref, fvec_ref, hist_ref, out_ref,
         picked = jnp.where((lane == _OLH) | (lane == _ORH),
                            picked - jnp.float32(K_EPSILON), picked)
         best_rows.append(picked)
-    best_ref[:] = jnp.concatenate(best_rows, axis=0)
+    if not blocks:
+        best_ref[:] = jnp.concatenate(best_rows, axis=0)
+        return
+    # fold into the child's row: a later block wins only with a strictly
+    # larger gain, so the lowest feature id keeps a tie, as in one block
+    picked, = best_rows
+    at = pl.ds(child, 1)
+
+    @pl.when(f0 == 0)
+    def _():
+        best_ref[at, :] = picked
+
+    @pl.when(f0 > 0)
+    def _():
+        kept = best_ref[at, :]
+        better = picked[:, _OG:_OG + 1] > kept[:, _OG:_OG + 1]
+        best_ref[at, :] = jnp.where(better, picked, kept)
+
+
+# the scan holds about two dozen [rows, lanes(B)] f32 arrays at once:
+# 22.61 MB at 2 000 rows of 63 bins, compiled for a v5e
+_SCAN_ARRAYS = 24
+_SCAN_VMEM = 12 << 20
+
+
+def _scan_block(CH: int, F: int, B: int) -> tuple:
+    """(features per block, blocks per child) of the scan: (F, 0) — one
+    step, no grid, what narrow data has always compiled — while all CH * F
+    rows fit the kernel's VMEM, else blocks of one child's features, a
+    multiple of 8 rows each (the last one padded with masked features)."""
+    row_bytes = _SCAN_ARRAYS * 4 * (-(-B // 128) * 128)
+    if CH * F * row_bytes <= _SCAN_VMEM:
+        return F, 0
+    n = -(-F * row_bytes // _SCAN_VMEM)
+    return -(-F // (8 * n)) * 8, n
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -239,6 +287,37 @@ def _run_scan(pvec, svec, fvec, hist3, *, interpret: bool):
     _, R, B = hist3.shape
     CH = svec.shape[0]
     F = R // CH
+    Fb, blocks = _scan_block(CH, F, B)
+    if blocks:
+        # pad each child's features to whole blocks: an all-zero fvec row
+        # has feature_mask 0, so its gain is the no-split sentinel
+        pad = blocks * Fb - F
+        if pad:
+            fvec = jnp.pad(fvec.reshape(CH, F, -1),
+                           ((0, 0), (0, pad), (0, 0))).reshape(-1, 8)
+            hist3 = jnp.pad(hist3.reshape(3, CH, F, B),
+                            ((0, 0), (0, 0), (0, pad), (0, 0))
+                            ).reshape(3, -1, B)
+        kernel = functools.partial(_split_scan_kernel, CH=CH, F=Fb, B=B,
+                                   blocks=blocks)
+        out, best = pl.pallas_call(
+            kernel,
+            grid=(CH * blocks,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec((Fb, 8), lambda i: (i, 0)),
+                      pl.BlockSpec((3, Fb, B), lambda i: (0, i, 0))],
+            out_specs=(pl.BlockSpec((Fb, ROW_W), lambda i: (i, 0)),
+                       pl.BlockSpec((CH, ROW_W), lambda i: (0, 0))),
+            out_shape=(jax.ShapeDtypeStruct((CH * blocks * Fb, ROW_W),
+                                            jnp.float32),
+                       jax.ShapeDtypeStruct((CH, ROW_W), jnp.float32)),
+            interpret=interpret,
+        )(pvec, svec, fvec, hist3)
+        if pad:
+            out = out.reshape(CH, blocks * Fb, ROW_W)[:, :F].reshape(
+                R, ROW_W)
+        return out, best
     kernel = functools.partial(_split_scan_kernel, CH=CH, F=F, B=B)
     return pl.pallas_call(
         kernel,

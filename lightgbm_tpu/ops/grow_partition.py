@@ -797,17 +797,38 @@ def grow_tree_partition_impl(
                     jnp.argmin(state.slot_tick).astype(jnp.int32))
                 tickL = state.slot_tick.at[slotL].set(state.tick)
                 slotR = jnp.argmin(tickL).astype(jnp.int32)
-                hist_cache = state.hist_cache.at[slotL].set(left_hist)
-                hist_cache = hist_cache.at[slotR].set(right_hist)
+                write_at = (slotL, slotR)       # distinct: K >= 4
+                kept = jnp.stack([state.hist_cache[slotL],
+                                  state.hist_cache[slotR]])
                 slot_leaf = state.slot_leaf.at[slotL].set(best_leaf)
                 slot_leaf = slot_leaf.at[slotR].set(new_leaf)
                 slot_tick = tickL.at[slotR].set(state.tick + 1)
                 tick = state.tick + 2
             else:
-                hist_cache = state.hist_cache.at[best_leaf].set(left_hist)
-                hist_cache = hist_cache.at[new_leaf].set(right_hist)
+                # under no_split both writes go to best_leaf: new_leaf is L,
+                # past the cache, when a forced entry runs the body on a
+                # full tree, and dynamic_update_slice clamps an index
+                write_at = (best_leaf,
+                            jnp.where(no_split, best_leaf, new_leaf))
+                kept = jnp.stack([parent_hist, parent_hist])
                 slot_leaf, slot_tick, tick = (state.slot_leaf, state.slot_tick,
                                               state.tick)
+            # The cache changes by these two slice writes and by nothing
+            # else, so the loop carries it in one buffer, updated in place
+            # (why it is not masked whole: see `sel` below).  The two VALUES
+            # are masked: under no_split the writes put back what the
+            # entries held.  Every read of state.hist_cache (parent_hist,
+            # kept) feeds child_hists, and the barrier keeps the compiler
+            # from fusing one of them into a write, behind the other write:
+            # reads first, then the writes.  The forced-split steps unrolled
+            # before the loop read the entry off the state their predecessor
+            # returned, so the order holds there too.
+            child_hists = jax.lax.optimization_barrier(jnp.where(
+                no_split, kept, jnp.stack([left_hist, right_hist])))
+            hist_cache = state.hist_cache
+            for child, at in enumerate(write_at):
+                hist_cache = jax.lax.dynamic_update_slice_in_dim(
+                    hist_cache, child_hists[child:child + 1], at, axis=0)
 
         with jax.named_scope("lgbm.grow.book"):
             startL = jnp.where(left_smaller, dstB, dstA).astype(dtype)
@@ -867,7 +888,7 @@ def grow_tree_partition_impl(
         # cross-feature select on the numerical path)
         with jax.named_scope("lgbm.grow.scan"):
             rows2 = pair_best_rows(
-                jnp.stack([left_hist, right_hist]),
+                child_hists,
                 jnp.stack([lg, rg]), jnp.stack([lh, rh]),
                 jnp.stack([lc_f, rc_f]), depth + 1, used2,
                 jnp.stack([minL, minR]), jnp.stack([maxL, maxR]))
@@ -875,15 +896,19 @@ def grow_tree_partition_impl(
             split_cache = state.split_cache.at[best_leaf].set(rows2[0]) \
                                            .at[new_leaf].set(rows2[1])
 
-        # merge: arena is already unchanged when no_split (cnt=0 pass);
-        # mask every small field back to its previous value
+        # merge: the arena is already unchanged when no_split (cnt=0 pass)
+        # and the histogram cache got its two entries back (above): neither
+        # is masked here, because a mask keeps the old value live beside the
+        # new one and costs a copy of the whole buffer per split.  Every
+        # other field is bytes to kilobytes (node, leaf and split tables,
+        # slot_leaf, slot_tick, cegb_used, the scalars) and is masked back
+        # to its previous value
         keep = no_split
 
         def sel(old_v, new_v):
             return jnp.where(keep, old_v, new_v)
 
         with jax.named_scope("lgbm.grow.cache"):
-            hist_cache = sel(state.hist_cache, hist_cache)
             slot_leaf = sel(state.slot_leaf, slot_leaf)
             slot_tick = sel(state.slot_tick, slot_tick)
         with jax.named_scope("lgbm.grow.book"):
@@ -954,8 +979,9 @@ def grow_tree_partition_impl(
                 jnp.where(applied, dyn_leaf, -1))
 
     # the loop itself under a scope: what the compiler does at the loop's
-    # level (copies and selects of carried state, above all of the
-    # histogram cache) takes the while's op_name, not the body's
+    # level with the carried state takes the while's op_name, not the
+    # body's.  A copy of the histogram cache here means the body no longer
+    # updates it in place (tests/test_hist_cache_inplace.py)
     with jax.named_scope("lgbm.grow.carry"):
         state = jax.lax.while_loop(cond, body, state)
 

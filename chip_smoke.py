@@ -6,8 +6,8 @@
 Drives the main path the way a user would — `lgb.train` on the partition
 engine with the fused, carried iteration and int8 histograms, then
 `Booster.predict` on the device — at the full width of the headline model
-(higgs-binary, 10.5M x 28, 255 leaves, max_bin 255; data from bench.py's
-seeded generator), and checks by the repo's own means that what came out is
+(higgs-binary, 10.5M x 28, 255 leaves, max_bin 255; data from the seeded
+generators below), and checks by the repo's own means that what came out is
 right.  Then, at small sizes, it makes Mosaic lower every Pallas kernel the
 repo has and compares the compiled partition engine with the XLA label
 engine.  The phases run in order, none is guarded: the first failure ends
@@ -33,11 +33,9 @@ import time
 
 import numpy as np
 
-import bench        # the seeded generators and the headline parameters
-
 NO_CHIP_EXIT = 3
 # loose quality floor for the full-size run: 13 trees on the synthetic set
-# reached 0.88 on the chip (PR 21); bench.py's own 5-iteration floor is 0.75
+# reached 0.88 on the chip (PR 21)
 AUC_FLOOR = 0.75
 LEAVES = 255
 # f32 partition engine vs label engine: typical raw-score distance after
@@ -48,6 +46,79 @@ ALL_KERNELS = {
     "_partition_kernel", "_compact_carry_kernel", "_compact_rows_kernel",
     "_seg_hist_kernel", "_fused_root_kernel", "_hist_kernel",
     "_hist_kernel_q", "_split_scan_kernel"}
+
+
+def _auc(y, p):
+    order = np.argsort(p)
+    ranks = np.empty(len(p))
+    ranks[order] = np.arange(1, len(p) + 1)
+    pos = y > 0.5
+    np_, nn = pos.sum(), (~pos).sum()
+    return (ranks[pos].sum() - np_ * (np_ + 1) / 2) / (np_ * nn)
+
+
+HIGGS_ROWS = 10_500_000   # docs/Experiments.rst:103-115
+HIGGS_FEATURES = 28
+
+
+def higgs_data(n, n_hold, seed=7):
+    """(X, y, X_holdout, y_holdout) of the Higgs shape: n x 28 Gaussian
+    columns, a noisy label with one interaction term; the holdout is
+    drawn from the same distribution and never trained on."""
+    F = HIGGS_FEATURES
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, F).astype(np.float32)
+    w = rng.randn(F)
+
+    def label_of(Xg):
+        logits = Xg @ w * 0.5 + 0.8 * np.sin(Xg[:, 0] * 2) * Xg[:, 1]
+        return (logits + rng.randn(len(Xg)) > 0).astype(np.float32)
+
+    y = label_of(X)
+    Xh = rng.randn(n_hold, F).astype(np.float32)
+    return X, y, Xh, label_of(Xh)
+
+
+def higgs_params(quantized):
+    """The headline configuration (docs/Experiments.rst:41-99); with
+    `quantized`, the int8-histogram path (docs/Quantized.md).  Warnings
+    stay on: an engine the run did not ask for announces itself there."""
+    params = {
+        "objective": "binary", "num_leaves": 255, "learning_rate": 0.1,
+        "max_bin": 255, "min_data_in_leaf": 20, "verbose": 0,
+    }
+    if quantized:
+        params["tpu_quantized_grad"] = True
+    return params
+
+
+MSLR_FEATURES = 137
+
+
+def mslr_data(n_query, docs_per_q=120, seed=11):
+    """(X, labels, qid, group) of the MSLR-WEB30K shape: ~120 docs per
+    query, 137 features, graded 0-4 relevance from a per-query ranking
+    of a sparse linear utility (docs/Experiments.rst:34,137-144)."""
+    F = MSLR_FEATURES
+    n = n_query * docs_per_q
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, F).astype(np.float32)
+    # sparse signal: learnable within the timed budget, so the NDCG floor
+    # actually separates healthy training from a wrong-trees regression
+    w = np.zeros(F)
+    w[:10] = rng.randn(10)
+    util = X @ w + 0.3 * rng.randn(n)
+    qid = np.repeat(np.arange(n_query), docs_per_q)
+    labels = np.zeros(n, np.float32)
+    order = np.argsort(-util.reshape(n_query, docs_per_q), axis=1)
+    grades = [(2, 4), (6, 3), (15, 2), (40, 1)]   # top-k cutoffs -> grade
+    for qi in range(n_query):
+        prev = 0
+        lab_row = labels[qi * docs_per_q:(qi + 1) * docs_per_q]
+        for cut, g in grades:
+            lab_row[order[qi, prev:cut]] = g
+            prev = cut
+    return X, labels, qid, np.full(n_query, docs_per_q)
 
 
 def _say(phase, **kv):
@@ -86,10 +157,10 @@ def main_path(lgb, jax, jnp, args, on_chip, full):
     # threshold (below it Booster.predict takes the host walk)
     n_hold = max(100_000, -(-predict_ops.MIN_DEVICE_WORK // n_trees) + 1)
     t0 = time.perf_counter()
-    X, y, Xh, yh = bench.higgs_data(args.rows, n_hold)
+    X, y, Xh, yh = higgs_data(args.rows, n_hold)
     t_data = time.perf_counter() - t0
 
-    params = bench.higgs_params(quantized=True)
+    params = higgs_params(quantized=True)
     if not on_chip:
         # off the chip `auto` means the label engine; the debug run forces
         # the engine under test (kernels in interpret mode)
@@ -201,7 +272,7 @@ def device_predict(booster, Xh, yh, full):
     host = g.predict(Xh[:10_000], device=False)
     err = float(np.max(np.abs(pred[:10_000] - host)))
     assert err < 1e-5, "device predict differs from the host walk by %g" % err
-    auc = float(bench._auc(yh, pred))
+    auc = float(_auc(yh, pred))
     if full:
         assert auc >= AUC_FLOOR, "holdout AUC %.4f < %.2f" % (auc, AUC_FLOOR)
     else:
@@ -232,7 +303,7 @@ def equivalence(lgb, jax, args):
     split flipped by f32 reassociation noise may compound, so later
     rounds are held to close typical scores, not pointwise equality."""
     n = min(args.rows, 200_000)
-    X, y, Xh, _ = bench.higgs_data(n, 20_000, seed=3)
+    X, y, Xh, _ = higgs_data(n, 20_000, seed=3)
     part = _train_small(lgb, jax, X, y, {"tpu_tree_engine": "partition"})
     label = _train_small(lgb, jax, X, y, {"tpu_tree_engine": "label"})
     assert part._gbdt._use_partition_engine
@@ -268,7 +339,7 @@ def kernel_coverage(lgb, jax, jnp, args):
     from lightgbm_tpu.ops import histogram_pallas as hp
     from lightgbm_tpu.utils.backend import pallas_interpret
     n = min(args.rows, 50_000)
-    X, y, _, _ = bench.higgs_data(n, 16, seed=5)
+    X, y, _, _ = higgs_data(n, 16, seed=5)
     force = {"tpu_tree_engine": "partition"}
     done = []
 
@@ -301,7 +372,7 @@ def kernel_coverage(lgb, jax, jnp, args):
                                       categorical_feature=[5]))
     # lambdarank at 137 features: C=160 arena channels, the widest any
     # record used
-    Xr, lab, _, group = bench.mslr_data(max(8, min(n, 24_000) // 120))
+    Xr, lab, _, group = mslr_data(max(8, min(n, 24_000) // 120))
     rank = _train_small(lgb, jax, Xr, lab,
                         dict(force, objective="lambdarank", metric="ndcg"),
                         group=group)
@@ -322,7 +393,7 @@ def kernel_coverage(lgb, jax, jnp, args):
     # the time.
     from lightgbm_tpu.ops import partition_pallas as pp
     wide_f = 2000 if jax.default_backend() == "tpu" else 520
-    Xw, yw, _, _ = bench.higgs_data(min(n, 32_768), 16, seed=7)
+    Xw, yw, _, _ = higgs_data(min(n, 32_768), 16, seed=7)
     rng_w = np.random.RandomState(11)
     Xw = np.concatenate([Xw, rng_w.randn(len(Xw), wide_f - Xw.shape[1])
                          .astype(np.float32)], axis=1)
@@ -397,8 +468,8 @@ def main(argv=None):
     import lightgbm_tpu as lgb
 
     if args.rows is None:
-        args.rows = bench.HIGGS_ROWS
-    full = args.rows == bench.HIGGS_ROWS and args.iters >= 10
+        args.rows = HIGGS_ROWS
+    full = args.rows == HIGGS_ROWS and args.iters >= 10
     try:
         import libtpu
         libtpu_version = getattr(libtpu, "__version__", "unknown")
@@ -410,7 +481,7 @@ def main(argv=None):
          **device, **versions)
     if not full:
         _say("device", REDUCED="rows=%d iters=%d (full: %d, >=10)"
-             % (args.rows, args.iters, bench.HIGGS_ROWS))
+             % (args.rows, args.iters, HIGGS_ROWS))
 
     seen = {}
     _record_pallas_calls(seen)

@@ -4,8 +4,9 @@
 call the Python binding still points at a deleted array, and touching
 it raises (or worse, silently reads garbage under some backends).  The
 fused gbdt paths donate the arena and the score plane every iteration,
-the partition kernels donate their scratch arena, and roofline_report
-threads donated arenas through stateful dict closures — all patterns
+the partition kernels donate their scratch arena, and a timing
+script may thread a donated arena through stateful dict closures
+(tests/fixtures/lint/donation_bench.py) — all patterns
 this checker must accept, while catching the three ways they rot:
 
 - ``donation-use-after``  HIGH  a donated binding is read after the

@@ -447,7 +447,7 @@ def assign_fingerprints(findings: List[Finding],
 
 # -- file collection and the suite entry point -----------------------------
 
-DEFAULT_ROOTS = ("lightgbm_tpu", "tools", "bench.py")
+DEFAULT_ROOTS = ("lightgbm_tpu", "tools", "chip_smoke.py")
 _SKIP_DIRS = {"__pycache__", ".git", "node_modules"}
 
 

@@ -2,8 +2,7 @@
 
 Text output groups by severity and marks baseline-known findings so a
 human triaging a failed gate sees the NEW debt first; JSON output is
-one self-describing document for CI annotation / trend dashboards
-(bench.py's ``lint_smoke`` line consumes the same summary).
+one self-describing document for CI annotation / trend dashboards.
 """
 from __future__ import annotations
 

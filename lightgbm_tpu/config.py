@@ -310,20 +310,6 @@ _SCHEMA = [
     #   on its sibling replicas — capacity degrades, availability doesn't)
     ("tpu_replica_breaker_reset_s", float, 5.0),  # per-replica breaker
     #   open -> half-open probe delay
-    # --- perf / roofline parameters (no reference analogue)
-    # Roofline performance observatory (obs/perf, tools/roofline_report,
-    # tools/perf_gate): analytic HBM-byte/FLOP floors per hot kernel vs
-    # the device's published peaks (obs/perf.DEVICE_PEAKS — one table,
-    # no option); see docs/Observability.md.
-    ("tpu_perf_roofline", bool, True),       # attach a roofline section (analytic
-    #   byte budget vs achieved GB/s) to each recorder round event and the
-    #   lgbm_roofline_* gauges; training output is bitwise-identical on/off
-    ("tpu_perf_chain", int, 8),              # dispatches chained per timing sync
-    #   in the measurement harness; sized on an installation that no longer
-    #   exists (~100 ms per blocking fetch) and not re-measured since
-    ("tpu_perf_gate_tolerance", float, 0.15),  # perf-ledger regression tolerance:
-    #   tools/perf_gate.py fails when a tracked metric drops more than this
-    #   fraction below its committed baseline
     # --- quantized histogram training parameters (no reference analogue)
     # Quantized gradient/hessian histogram accumulation (docs/Quantized.md):
     # g/h become int8 codes carried as TWO arena payload planes instead of
@@ -466,23 +452,13 @@ _SCHEMA = [
     #   JSON artifact (per-phase + per-metric windowed summaries and
     #   series tails) here; tools/run_diff.py diffs two artifacts with
     #   tolerance bands and a nonzero exit on regression
-    # --- scaling forensics (obs/scaling.py): per-round host/device step
-    #   decomposition, the runtime sync sentinel and the efficiency
-    #   waterfall (tools/scaling_report.py).  Strictly read-only —
+    # --- runtime sync sentinel (obs/scaling.py); strictly read-only:
     #   training is bitwise-identical with it on or off.  See
     #   docs/ScalingForensics.md
     ("tpu_sync_guard", str, "off"),          # runtime sync sentinel mode:
     #   "off" (default, zero overhead), "log" (count + stack-attribute
     #   every implicit device->host scalar fetch inside the round as a
     #   sync_event), or "fail" (raise at the first un-exempted sync)
-    ("tpu_scaling_decomp", bool, True),      # attach a step_decomp section
-    #   (host_sync / leader_wire / psum / dispatch legs) to each recorder
-    #   round event and the lgbm_scaling_* gauges
-    ("tpu_scaling_window", int, 8),          # rounds between the device
-    #   chain probes (one dependent scalar fetch each, obs/perf timing
-    #   discipline); larger amortizes the blocking fetch further
-    ("tpu_scaling_ici_gbps", float, 45.0),   # assumed per-link ICI
-    #   bandwidth for the analytic psum leg (bytes moved / this rate)
 ]
 
 # alias -> canonical name (src/io/config_auto.cpp:4-157)
@@ -646,10 +622,6 @@ ALIAS_TABLE: Dict[str, str] = {
     "runhist_path": "tpu_runhist_path",
     "sync_guard": "tpu_sync_guard",
     "transfer_guard": "tpu_sync_guard",
-    "scaling_decomp": "tpu_scaling_decomp",
-    "step_decomp": "tpu_scaling_decomp",
-    "scaling_window": "tpu_scaling_window",
-    "scaling_ici_gbps": "tpu_scaling_ici_gbps",
 }
 
 PARAMETER_TYPES: Dict[str, Any] = {name: typ for name, typ, _ in _SCHEMA}
@@ -962,12 +934,6 @@ class Config:
         if self.tpu_replica_breaker_reset_s < 0:
             log.fatal("tpu_replica_breaker_reset_s must be >= 0, got %g"
                       % self.tpu_replica_breaker_reset_s)
-        if self.tpu_perf_chain < 1:
-            log.fatal("tpu_perf_chain must be >= 1, got %d"
-                      % self.tpu_perf_chain)
-        if not 0 <= self.tpu_perf_gate_tolerance < 1:
-            log.fatal("tpu_perf_gate_tolerance must be in [0, 1), got %g"
-                      % self.tpu_perf_gate_tolerance)
         if self.tpu_quantized_bits != 8:
             log.fatal("tpu_quantized_bits: only 8-bit codes are "
                       "implemented, got %d" % self.tpu_quantized_bits)
@@ -1046,12 +1012,6 @@ class Config:
         if self.tpu_sync_guard not in ("off", "log", "fail"):
             log.fatal("tpu_sync_guard must be 'off', 'log' or 'fail', "
                       "got %r" % self.tpu_sync_guard)
-        if self.tpu_scaling_window < 1:
-            log.fatal("tpu_scaling_window must be >= 1, got %d"
-                      % self.tpu_scaling_window)
-        if self.tpu_scaling_ici_gbps <= 0:
-            log.fatal("tpu_scaling_ici_gbps must be > 0, got %g"
-                      % self.tpu_scaling_ici_gbps)
 
     def is_single_machine(self) -> bool:
         return self.num_machines <= 1

@@ -11,6 +11,7 @@ so models round-trip with the reference's parsers.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import time
 from functools import partial
@@ -462,20 +463,11 @@ class GBDT:
         # bulk fetches (recorder/federation, below) run outside the
         # guard, so a clean round reports zero sync events
         sentinel = self.sync_sentinel
-        if self.recorder is None and self.federation is None:
-            with obs_tracing.span("train/iteration", "train", iter=it):
-                if sentinel is None:
-                    return self._train_one_iter_impl(gradients, hessians)
-                with sentinel.guard(it):
-                    return self._train_one_iter_impl(gradients, hessians)
+        guard = (sentinel.guard(it) if sentinel is not None
+                 else contextlib.nullcontext())
         t0 = time.perf_counter()
-        with obs_tracing.span("train/iteration", "train", iter=it):
-            if sentinel is None:
-                finished = self._train_one_iter_impl(gradients, hessians)
-            else:
-                with sentinel.guard(it):
-                    finished = self._train_one_iter_impl(gradients,
-                                                         hessians)
+        with obs_tracing.span("train/iteration", "train", iter=it), guard:
+            finished = self._train_one_iter_impl(gradients, hessians)
         wall = time.perf_counter() - t0
         if self.recorder is not None:
             try:

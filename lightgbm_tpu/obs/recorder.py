@@ -83,14 +83,6 @@ class TrainingRecorder:
         self._deferred_iters: List[int] = []
         self._closed = False
         self._write_failed = False
-        # roofline: analytic per-iteration byte/FLOP floor (obs/perf),
-        # computed once from the first round's shapes, then turned into
-        # achieved GB/s per round from wall_s alone — read-only on
-        # training state, so bitwise identity is untouched
-        self.roofline_enabled = bool(
-            getattr(config, "tpu_perf_roofline", True))
-        self._budget: Optional[Dict] = None
-        self._roof = None
         # trend observatory: a per-run series store feeding the RUNHIST
         # artifact (obs/timeseries.py) — phase deltas, eval metrics and
         # a registry sweep per round.  Only built when a RUNHIST was
@@ -107,13 +99,6 @@ class TrainingRecorder:
             pats = str(getattr(config, "tpu_trend_metrics", "") or "")
             self._trend_include = [p.strip() for p in pats.split(",")
                                    if p.strip()] or None
-        # scaling forensics: per-round host/device step decomposition
-        # (obs/scaling.py) — same lazy-init / disable-on-failure contract
-        # as the roofline section; read-only apart from one exempted
-        # scalar probe per tpu_scaling_window rounds
-        self.scaling_enabled = bool(
-            getattr(config, "tpu_scaling_decomp", True))
-        self._decomposer = None
         adapters.ensure_device_metrics(self.registry)
         self._m_iters = self.registry.counter(
             "lgbm_train_iterations_total", help="Boosting rounds completed")
@@ -164,13 +149,6 @@ class TrainingRecorder:
         comm = adapters.comm_totals(self.registry)
         if comm is not None:
             event["comm"] = comm
-        roofline = self._roofline(gbdt, wall_s)
-        if roofline is not None:
-            event["roofline"] = roofline
-        decomp = self._step_decomp(gbdt, iteration, wall_s,
-                                   event["phases"])
-        if decomp is not None:
-            event["step_decomp"] = decomp
         self._m_iters.inc()
         self._m_seconds.inc(wall_s)
         if not finished:
@@ -291,70 +269,6 @@ class TrainingRecorder:
         if goss is not None:
             out["goss_top"], out["goss_other"] = int(goss[0]), int(goss[1])
         return out
-
-    def _roofline(self, gbdt, wall_s: float) -> Optional[Dict[str, float]]:
-        """Per-round roofline summary: the cached analytic byte/FLOP
-        floor for one iteration over the measured wall time, as achieved
-        GB/s / GFLOP/s and, on a device with published peaks
-        (perf.DEVICE_PEAKS), shares of those roofs.  Also feeds
-        the lgbm_roofline_* gauges and (when the tracer is armed) a
-        bytes/FLOPs-tagged span.  Best-effort: any failure disables the
-        section for the run rather than touching training."""
-        if not self.roofline_enabled:
-            return None
-        try:
-            from . import perf
-            if self._budget is None:
-                engine = ("partition"
-                          if getattr(gbdt, "_use_partition_engine", False)
-                          else "label")
-                ds = getattr(gbdt, "train_set", None)
-                features = int(getattr(ds, "num_features", 0) or 1)
-                self._budget = perf.iteration_budget(
-                    rows=int(getattr(gbdt, "num_data", 0) or 1),
-                    features=features,
-                    max_bin=int(getattr(gbdt, "max_bin", 0)
-                                or getattr(self.config, "max_bin", 255)),
-                    num_leaves=int(getattr(self.config, "num_leaves", 31)),
-                    engine=engine,
-                    quantized=bool(getattr(gbdt, "_quantized", False)))
-                self._roof = perf.device_roofline()
-            summary = perf.budget_summary(self._budget, wall_s, self._roof)
-            perf.publish_iteration_gauges(self.registry, summary)
-            tracer = tracing.get_tracer()
-            if tracer.enabled:
-                tracing.complete(
-                    "roofline/iteration", wall_s, cat="roofline",
-                    analytic_bytes=self._budget["total_bytes"],
-                    analytic_flops=self._budget["total_flops"],
-                    gbps=summary["achieved_gbps"],
-                    hbm_util=summary.get("hbm_util"))
-            return summary
-        except Exception as exc:  # noqa: BLE001 — telemetry never raises
-            self.roofline_enabled = False
-            log.warning("telemetry: roofline section disabled: %s", exc)
-            return None
-
-    def _step_decomp(self, gbdt, iteration: int, wall_s: float,
-                     phases: Dict) -> Optional[Dict]:
-        """Per-round scaling-forensics section (obs/scaling.py): the
-        wall split into host_sync / leader_wire / psum / dispatch legs
-        plus the windowed device probe and the sentinel's sync-event
-        delta.  Best-effort: any failure disables the section for the
-        run rather than touching training."""
-        if not self.scaling_enabled:
-            return None
-        try:
-            from . import scaling
-            if self._decomposer is None:
-                self._decomposer = scaling.StepDecomposer(self.config,
-                                                          self.registry)
-            return self._decomposer.on_round(gbdt, iteration, wall_s,
-                                             phases)
-        except Exception as exc:  # noqa: BLE001 — telemetry never raises
-            self.scaling_enabled = False
-            log.warning("telemetry: step_decomp section disabled: %s", exc)
-            return None
 
     def _span_deltas(self) -> Optional[Dict[str, Dict[str, float]]]:
         """Per-round span summary: the tracer's cumulative per-kind
@@ -569,8 +483,8 @@ def sync_event(config, **fields) -> None:
     (obs/scaling.SyncSentinel) fires from INSIDE a hooked jax array
     conversion — routing through one booster's TrainingRecorder from
     there would re-enter its buffering, so like the elastic/fleet events
-    it appends directly — same JSONL contract, best-effort;
-    tools/scaling_report.py and the tests grep these lines."""
+    it appends directly — same JSONL contract, best-effort; the tests
+    grep these lines."""
     path = getattr(config, "tpu_telemetry_path", "")
     if not path:
         return

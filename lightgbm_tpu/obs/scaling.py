@@ -1,72 +1,31 @@
-"""Scaling forensics: per-round host/device step decomposition, the
-runtime sync sentinel, and the efficiency-waterfall math.
+"""The runtime sync sentinel: every implicit device→host scalar fetch
+inside a boosting round, counted and attributed to its call site.
 
-Mesh scaling was blocked on attribution, not code: efficiency on the
-8-virtual-device CPU mesh at 4096 rows was 0.01-0.035 (a CPU run of
-tools/mesh_bench.py — no mesh run on a TPU is on record) and the
-suspects are named — per-round host sync, un-donated shard buffers,
-psum placement, leader-callback serialization — but nothing in obs/
-could say which one dominates.  This module makes the loss explain
-itself:
+``SyncSentinel`` is the dynamic complement to tpulint's static
+``jit-host-sync`` rule: armed (``tpu_sync_guard=log|fail``) it wraps
+the round in ``jax.transfer_guard_device_to_host("log")`` AND hooks
+the jax array scalar-conversion methods (``item`` / ``tolist`` /
+``__float__`` / ``__int__`` / ``__bool__`` / ``__index__``) so every
+implicit device→host scalar fetch inside the round becomes a counted,
+stack-attributed ``sync_event`` telemetry event.  The method hooks are
+what makes the sentinel testable on the CPU backend, where jax's
+transfer guard is inert for device→host fetches; on a real TPU
+backend the entered transfer-guard context logs the bulk transfers
+the scalar hooks cannot see.  Known-legitimate syncs (the one-shot
+fault-surfacing fetches of models/gbdt.py, the engine's metric fetch)
+run under the scoped ``exempt()`` context, not a global opt-out.
+``fail`` mode raises LightGBMError at the first un-exempted sync —
+after recording it.
 
-- ``StepDecomposer`` splits every boosting round's wall time into
-  attributable legs using ONLY numbers the obs stack already collects
-  (profiler phase deltas, comm counters, the hybrid axis' wire-wait
-  accumulator) plus one chain probe per window (a dependent scalar
-  ``float()`` fetch, the obs/perf timing discipline).  The recorder
-  attaches the result as a ``step_decomp``
-  section per iteration event, publishes ``lgbm_scaling_*`` gauges and
-  (when the tracer is armed) ``scaling/`` spans.
-
-  Legs, per round (all milliseconds):
-
-  ==============  ======================================================
-  wall_ms         measured round wall (train_one_iter)
-  host_sync_ms    host blocked on device→host fetches: the drain /
-                  tree-fetch / metric-fetch profiler phases
-  leader_wire_ms  io_callback leader-wire serialization (hybrid axis
-                  wire-wait delta, or the socket sync-wait counter)
-  psum_ms         analytic ICI cost of the round's mesh collective
-                  payload: bytes moved / tpu_scaling_ici_gbps
-  dispatch_ms     everything else — Python driver, trace/dispatch and
-                  device compute overlapped behind it (the
-                  "dispatch gap" the waterfall charges scaling loss to)
-  device_est_ms   windowed chain-probe estimate of the device tail
-                  still executing when the host finished dispatching
-                  (informational; overlaps dispatch_ms by construction)
-  ==============  ======================================================
-
-  wall = host_sync + leader_wire + psum + dispatch by construction
-  (dispatch is the clamped remainder), which is what lets the waterfall
-  legs sum to the measured wall exactly instead of "within noise".
-
-- ``SyncSentinel`` is the dynamic complement to tpulint's static
-  ``jit-host-sync`` rule: armed (``tpu_sync_guard=log|fail``) it wraps
-  the round in ``jax.transfer_guard_device_to_host("log")`` AND hooks
-  the jax array scalar-conversion methods (``item`` / ``tolist`` /
-  ``__float__`` / ``__int__`` / ``__bool__`` / ``__index__``) so every
-  implicit device→host scalar fetch inside the round becomes a counted,
-  stack-attributed ``sync_event`` telemetry event.  The method hooks are
-  what makes the sentinel testable on the CPU backend, where jax's
-  transfer guard is inert for device→host fetches; on a real TPU
-  backend the entered transfer-guard context logs the bulk transfers
-  the scalar hooks cannot see.  Known-legitimate syncs (the perf
-  probe's single ``float()``) run under the scoped ``exempt()``
-  context, not a global opt-out.  ``fail`` mode raises LightGBMError at
-  the first un-exempted sync — after recording it.
-
-- ``efficiency_waterfall`` fits per-world mean round legs into the
-  ideal → +host-sync → +dispatch-gap → +psum → +leader-wire → measured
-  decomposition ``tools/scaling_report.py`` renders and gates on.
-
-Everything here is read-only on training state: models train
-bitwise-identically with the full forensics stack on or off
-(tests/test_scaling.py pins this for gbdt serial and mesh-w2).
+The sentinel is read-only on training state: models train
+bitwise-identically with it armed or off (tests/test_scaling.py pins
+this for gbdt serial and mesh-w2).  Where a round's time goes is read
+from the profiler's trace (docs/Tracing.md, benchmarks/README.md), not
+from the host's clock.
 """
 from __future__ import annotations
 
 import threading
-import time
 import traceback
 from typing import Dict, List, Optional
 
@@ -79,14 +38,6 @@ _WATCHED_METHODS = ("item", "tolist", "__float__", "__int__", "__bool__",
 # process; past the cap events are still counted (a sync storm must not
 # turn the sentinel itself into the bottleneck)
 MAX_RECORDED_EVENTS = 100
-
-# profiler phases that ARE host-blocking device→host fetches — the
-# host_sync leg is their per-round delta sum (names from models/gbdt.py)
-SYNC_PHASES = ("drain_inflight", "tree_fetch", "metric_eval(fetch)")
-
-WATERFALL_LEGS = ("ideal", "host_sync", "dispatch_gap", "psum",
-                  "leader_wire", "residual")
-LOSS_LEGS = WATERFALL_LEGS[1:]
 
 
 # --------------------------------------------------------------------- #
@@ -255,8 +206,8 @@ def reset_sync_stats() -> None:
 
 
 class _Exempt:
-    """Scoped opt-out for a known-legitimate sync (the perf probe's one
-    dependent ``float()`` per window).  Nests a jax d2h "allow" guard so
+    """Scoped opt-out for a known-legitimate sync (a one-shot
+    fault-surfacing fetch).  Nests a jax d2h "allow" guard so
     a TPU backend's transfer log stays clean too — scoped, not global."""
     def __enter__(self):
         _tls.allow += 1
@@ -340,240 +291,3 @@ class SyncSentinel:
 
     def guard(self, round_idx: Optional[int] = None) -> _Guard:
         return _Guard(self, round_idx)
-
-
-# --------------------------------------------------------------------- #
-# Per-round step decomposition
-# --------------------------------------------------------------------- #
-class StepDecomposer:
-    """Turns one round's already-collected numbers into the host/device
-    legs.  Strictly read-only apart from ONE dependent scalar fetch per
-    tpu_scaling_window rounds (under exempt()), amortized into the
-    device_est leg exactly like obs/perf's chain discipline."""
-
-    def __init__(self, config, registry):
-        self.window = max(1, int(getattr(config, "tpu_scaling_window", 8)
-                                 or 8))
-        self.ici_gbps = float(getattr(config, "tpu_scaling_ici_gbps", 45.0)
-                              or 45.0)
-        self.registry = registry
-        self._rounds = 0
-        self._last_wire_s = None       # cumulative leader-wire seconds
-        self._last_mesh_bytes = None   # cumulative mesh collective bytes
-        self._last_sync_total = 0
-        self._device_est_ms = 0.0      # EWMA of the probe's drain time
-
-    # -- cumulative source reads (deltas taken per round) -------------- #
-    def _wire_total_s(self, gbdt) -> float:
-        """Cumulative leader-wire wait: the hybrid axis accumulator when
-        present, else the socket sync-wait counter family.  max() of the
-        two because the hybrid leader's wire exchange also ticks the
-        socket counter — charging it twice would invent loss."""
-        wire = 0.0
-        try:
-            grower = getattr(gbdt, "_grower", None)
-            axis = getattr(grower, "_axis", None) if grower else None
-            if axis is not None:
-                wire = float(getattr(axis, "_wire_wait_s", 0.0) or 0.0)
-        except Exception as exc:  # noqa: BLE001 — source is best-effort
-            log.debug("step decomp: axis wire read failed: %s", exc)
-        try:
-            fam = self.registry.family_sum(
-                "lgbm_comm_sync_wait_seconds_total")
-            if fam is not None:
-                wire = max(wire, float(fam))
-        except Exception as exc:  # noqa: BLE001 — source is best-effort
-            log.debug("step decomp: wire counter read failed: %s", exc)
-        return wire
-
-    def _mesh_bytes_total(self, gbdt) -> float:
-        """Cumulative bytes moved by the in-process mesh collective
-        (psum'd histogram payloads) — MeshCollective._m_sent, or the
-        hybrid backend's inner mesh stage."""
-        try:
-            grower = getattr(gbdt, "_grower", None)
-            coll = getattr(grower, "collective", None) if grower else None
-            if coll is None:
-                return 0.0
-            m = getattr(coll, "_m_sent", None)
-            if m is None:
-                m = getattr(getattr(coll, "_mesh_coll", None), "_m_sent",
-                            None)
-            return float(m.value) if m is not None else 0.0
-        except Exception:  # noqa: BLE001
-            return 0.0
-
-    def _probe_device_ms(self, gbdt) -> Optional[float]:
-        """One dependent scalar fetch: time-to-scalar AFTER the host
-        finished the round = the device tail still in flight.  Same
-        fetch _profile_sync uses, exempted from the sentinel by
-        construction."""
-        state = getattr(gbdt, "train_state", None)
-        score = getattr(state, "score", None) if state is not None else None
-        if score is None:
-            return None
-        import jax.numpy as jnp
-        t0 = time.perf_counter()
-        with exempt():
-            float(jnp.sum(score[:, :1]))
-        return (time.perf_counter() - t0) * 1e3
-
-    # -- the per-round section ----------------------------------------- #
-    def on_round(self, gbdt, iteration: int, wall_s: float,
-                 phases: Dict[str, Dict[str, float]]) -> Dict:
-        wall_ms = wall_s * 1e3
-        host_sync_ms = sum(phases[p]["ms"] for p in SYNC_PHASES
-                           if p in phases)
-
-        wire_total = self._wire_total_s(gbdt)
-        if self._last_wire_s is None:
-            self._last_wire_s = wire_total
-        leader_wire_ms = max(wire_total - self._last_wire_s, 0.0) * 1e3
-        self._last_wire_s = wire_total
-
-        mesh_bytes = self._mesh_bytes_total(gbdt)
-        if self._last_mesh_bytes is None:
-            self._last_mesh_bytes = mesh_bytes
-        psum_bytes = max(mesh_bytes - self._last_mesh_bytes, 0.0)
-        self._last_mesh_bytes = mesh_bytes
-        psum_ms = psum_bytes / (self.ici_gbps * 1e9) * 1e3
-
-        # dispatch is the remainder; clamping both it and the subtracted
-        # legs keeps the identity wall == sum(legs) when timers jitter
-        budget = wall_ms
-        host_sync_ms = min(host_sync_ms, budget)
-        budget -= host_sync_ms
-        leader_wire_ms = min(leader_wire_ms, budget)
-        budget -= leader_wire_ms
-        psum_ms = min(psum_ms, budget)
-        dispatch_ms = budget - psum_ms
-
-        self._rounds += 1
-        probe_ms = None
-        if self._rounds % self.window == 1 or self.window == 1:
-            probe_ms = self._probe_device_ms(gbdt)
-            if probe_ms is not None:
-                self._device_est_ms = (probe_ms if self._device_est_ms == 0.0
-                                       else 0.5 * self._device_est_ms
-                                       + 0.5 * probe_ms)
-
-        stats = sync_stats()
-        sync_delta = stats["total"] - self._last_sync_total
-        self._last_sync_total = stats["total"]
-
-        decomp = {
-            "wall_ms": round(wall_ms, 3),
-            "host_sync_ms": round(host_sync_ms, 3),
-            "leader_wire_ms": round(leader_wire_ms, 3),
-            "psum_ms": round(psum_ms, 4),
-            "psum_bytes": int(psum_bytes),
-            "dispatch_ms": round(dispatch_ms, 3),
-            "device_est_ms": round(self._device_est_ms, 3),
-            "host_share": round((host_sync_ms + leader_wire_ms)
-                                / max(wall_ms, 1e-9), 4),
-            "sync_events": int(sync_delta),
-        }
-        if probe_ms is not None:
-            decomp["probe_ms"] = round(probe_ms, 3)
-
-        self._publish(decomp, wall_s, probe_ms)
-        return decomp
-
-    def _publish(self, decomp: Dict, wall_s: float,
-                 probe_ms: Optional[float]) -> None:
-        for leg in ("host_sync", "leader_wire", "psum", "dispatch",
-                    "device_est"):
-            self.registry.gauge(
-                "lgbm_scaling_leg_ms",
-                help="Step-decomposition leg of the last boosting round "
-                     "(ms)", leg=leg).set(decomp[leg + "_ms"])
-        self.registry.gauge(
-            "lgbm_scaling_host_share",
-            help="Host-blocked share of the last round "
-                 "(host_sync + leader_wire over wall)").set(
-            decomp["host_share"])
-        from . import tracing
-        tracer = tracing.get_tracer()
-        if tracer.enabled:
-            tracing.complete(
-                "scaling/decomp", wall_s, cat="scaling",
-                host_sync_ms=decomp["host_sync_ms"],
-                leader_wire_ms=decomp["leader_wire_ms"],
-                psum_ms=decomp["psum_ms"],
-                dispatch_ms=decomp["dispatch_ms"],
-                host_share=decomp["host_share"])
-            if probe_ms is not None:
-                tracing.complete("scaling/probe", probe_ms / 1e3,
-                                 cat="scaling", window=self.window)
-
-
-# --------------------------------------------------------------------- #
-# Efficiency waterfall
-# --------------------------------------------------------------------- #
-def mean_decomposition(rounds: List[Dict]) -> Optional[Dict[str, float]]:
-    """Mean per-round legs over a run's step_decomp sections (skips
-    rounds that carry no decomposition)."""
-    rows = [r for r in rounds or [] if r and "wall_ms" in r]
-    if not rows:
-        return None
-    keys = ("wall_ms", "host_sync_ms", "leader_wire_ms", "psum_ms",
-            "dispatch_ms", "device_est_ms")
-    return {k: sum(float(r.get(k, 0.0)) for r in rows) / len(rows)
-            for k in keys}
-
-
-def efficiency_waterfall(per_world: Dict[int, Dict[str, float]]) -> Dict:
-    """Fit mean per-round legs at each world size into the loss
-    waterfall: ideal → +host_sync → +dispatch_gap → +psum →
-    +leader_wire → measured.
-
-    ``ideal`` is the world-1 round wall divided by w (perfect scaling);
-    each loss leg is that world's leg in EXCESS of the ideally-scaled
-    world-1 leg (a cost that shrank 1/w with the work contributes
-    nothing).  Because the per-round legs partition the wall exactly,
-    the named legs + residual sum to the measured wall identically;
-    residual only absorbs clamping noise, and |residual|/measured is
-    the health number the report gates on (≤ 10% by acceptance)."""
-    if not per_world:
-        return {}
-    worlds = sorted(per_world)
-    base = per_world.get(1) or per_world[worlds[0]]
-    base_w = 1 if 1 in per_world else worlds[0]
-    out: Dict = {}
-    for w, legs in ((w, per_world[w]) for w in worlds):
-        scale = float(w) / float(base_w)
-        measured = float(legs["wall_ms"])
-        ideal = float(base["wall_ms"]) / scale
-        excess = {
-            "host_sync": max(float(legs["host_sync_ms"])
-                             - float(base["host_sync_ms"]) / scale, 0.0),
-            "dispatch_gap": max(float(legs["dispatch_ms"])
-                                - float(base["dispatch_ms"]) / scale, 0.0),
-            "psum": max(float(legs["psum_ms"])
-                        - float(base["psum_ms"]) / scale, 0.0),
-            "leader_wire": max(float(legs["leader_wire_ms"])
-                               - float(base["leader_wire_ms"]) / scale,
-                               0.0),
-        }
-        residual = measured - ideal - sum(excess.values())
-        ordered = {"ideal": round(ideal, 3)}
-        ordered.update({k: round(v, 3) for k, v in excess.items()})
-        ordered["residual"] = round(residual, 3)
-        dominant = max(excess, key=lambda k: excess[k])
-        if abs(residual) > excess[dominant]:
-            dominant = "residual"
-        if max(excess[max(excess, key=lambda k: excess[k])],
-               abs(residual)) < 0.01 * max(measured, 1e-9):
-            dominant = "none"      # scaling is clean at this world size
-        out[w] = {
-            "measured_ms": round(measured, 3),
-            "legs": ordered,
-            "dominant_loss": dominant,
-            "residual_share": round(abs(residual) / max(measured, 1e-9), 4),
-            "efficiency": round(float(base["wall_ms"])
-                                / max(scale * measured, 1e-9), 4),
-            "host_share": round((float(legs["host_sync_ms"])
-                                 + float(legs["leader_wire_ms"]))
-                                / max(measured, 1e-9), 4),
-        }
-    return out

@@ -224,7 +224,7 @@ def grow_tree_partition_impl(
         # full_bag quantized roots skip the XLA plane write entirely: the
         # fused root kernel below DMAs the fresh codes into the arena while
         # it streams the feature rows for the root histogram — one pass pays
-        # for both (the per-iteration byte saving iteration_budget reports)
+        # for both
         fuse_root = quantized and full_bag
         if carried:
             # bins/rowids AND the score/label planes already sit at the
@@ -1068,25 +1068,3 @@ grow_tree_partition = partial(jax.jit, static_argnames=(
     "hist_slots", "forced_splits", "pristine", "carried_bump0",
     "quantized", "interpret"),
     donate_argnums=(0,))(grow_tree_partition_impl)
-
-
-# -- roofline cost model (obs/perf) -------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-
-@cost_model("tree/iteration")
-def _cost_tree_iteration(rows: int, features: int, max_bin: int,
-                         num_leaves: int,
-                         engine: str = "partition",
-                         quantized: bool = False) -> KernelCost:
-    """One full boosting iteration (grow one tree): the aggregate of
-    the phase floors in obs/perf.iteration_budget — root histogram,
-    per-split partition + smaller-child histogram + split scans, g/h
-    refresh and carry compaction.  Balanced-tree lower bound: the sum
-    of parent segments across the L-1 splits is modeled as n*log2(L)
-    rows."""
-    from ..obs import perf
-    b = perf.iteration_budget(rows, features, max_bin, num_leaves,
-                              engine=engine, quantized=quantized)
-    return KernelCost("tree/iteration", b["total_bytes"], b["total_flops"],
-                      "sum of phase floors, n*log2(L) partition bound")

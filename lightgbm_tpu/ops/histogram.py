@@ -172,21 +172,3 @@ def subtract(parent_hist: jnp.ndarray, child_hist: jnp.ndarray) -> jnp.ndarray:
     """Sibling histogram by subtraction (FeatureHistogram::Subtract,
     feature_histogram.hpp:67-73) — the communication/work saver."""
     return parent_hist - child_hist
-
-
-# -- roofline cost model (obs/perf) -------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-
-@cost_model("hist/xla")
-def _cost_hist_xla(rows: int, features: int, max_bin: int,
-                   dtype_bytes: int = 4) -> KernelCost:
-    """XLA histogram (scatter/onehot/compact): compulsory traffic is one
-    pass over bins (u8), g/h/leaf_ids, plus the [F, B, 3] f32 output;
-    the FLOP floor is 3 accumulates per (row, feature) — the onehot
-    impl executes B times that on the MXU, which is exactly the
-    bandwidth-for-lanes trade the Pallas kernel exists to undo."""
-    n, F, B = int(rows), int(features), int(max_bin)
-    nbytes = n * F + n * (2 * dtype_bytes + 4) + F * B * 3 * 4
-    return KernelCost("hist/xla", nbytes, 3 * n * F,
-                      "one pass over bins+gh; 3 adds/(row,feat) floor")

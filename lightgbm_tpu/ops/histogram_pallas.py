@@ -235,42 +235,3 @@ def radix_epilogue(out, G: int, m: int, hi_n: int, lo_n: int):
     out = out.reshape(G, m, 3, hi_n, m, lo_n)
     diag = jnp.moveaxis(jnp.diagonal(out, axis1=1, axis2=4), -1, 1)
     return diag.reshape(G * m, 3, hi_n * lo_n).transpose(0, 2, 1)
-
-
-# -- roofline cost model (obs/perf) -------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-
-@cost_model("hist/pallas")
-def _cost_hist_pallas(rows: int, features: int, max_bin: int,
-                      dtype_bytes: int = 4) -> KernelCost:
-    """Radix-pair MXU histogram: HBM floor is one pass over bins (u8)
-    and g/h/leaf_ids plus the pre-epilogue [G, M, N] f32 accumulator;
-    FLOPs are what the MXU actually executes — 2*M*N MACs per row tile
-    per feature group, off-diagonal (f, f') blocks included."""
-    n, F, B = int(rows), int(features), int(max_bin)
-    lo_n, hi_n, m = _radix_plan(B)
-    G = -(-F // m)
-    M, N = 3 * hi_n * m, m * lo_n
-    nbytes = n * F + n * (2 * dtype_bytes + 4) + G * M * N * 4
-    return KernelCost("hist/pallas", nbytes, 2 * n * G * M * N,
-                      "MXU %dx%d tile per %d-feature group" % (M, N, m))
-
-
-@cost_model("hist/quantized")
-def _cost_hist_quantized(rows: int, features: int, max_bin: int,
-                         dtype_bytes: int = 4) -> KernelCost:
-    """Quantized radix histogram: per-row HBM floor is F bin bytes plus
-    THREE payload bytes (int8 g code, int8 h code, uint8 leaf id) where
-    the f32 kernel reads 2*dtype_bytes+4 — and where the f32 PARTITION
-    engine streams the full bf16 arena row (partition/hist).  FLOPs are
-    identical: this chip's MXU runs every dtype at the same rate, so the
-    quantized win is purely bytes."""
-    n, F, B = int(rows), int(features), int(max_bin)
-    lo_n, hi_n, m = _radix_plan(B)
-    G = -(-F // m)
-    M, N = 3 * hi_n * m, m * lo_n
-    nbytes = n * (F + 3) + G * M * N * 4
-    return KernelCost("hist/quantized", nbytes, 2 * n * G * M * N,
-                      "int8 codes: %d B/row vs %d B/row f32"
-                      % (F + 3, F + 2 * dtype_bytes + 4))

@@ -1806,76 +1806,3 @@ def engine_plan(num_features: int, max_bin: int, quantized: bool) -> dict:
         "vmem_histogram": nb * (acc + 2 * f_blk * TILE * 2),
         "vmem_default": _VMEM_DEFAULT,
     }
-
-
-# -- roofline cost models (obs/perf) ------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-_ARENA_B = 2  # bf16 arena element
-
-
-@cost_model("partition/segment")
-def _cost_partition(rows: int, features: int) -> KernelCost:
-    """Stream a parent segment once and write both children (same total
-    rows): 2x the segment's arena footprint plus the pred plane slice.
-    FLOPs count the 2*SUB MACs per row of the permutation matmuls.  The
-    bytes are the kernel's floor, not its cost: on the v5e the tile body
-    (decision, P build, the sort matmuls, the appends) takes several
-    times the tile's DMA time, so the kernel runs at a small share of
-    this roofline (PERF.md, section 5, has the measured share)."""
-    n = int(rows)
-    row_b = _ARENA_B * arena_channels(int(features))
-    return KernelCost("partition/segment", 2 * n * row_b + n * 4,
-                      2 * n * SUB,
-                      "parent read + children write, %dB/row" % row_b)
-
-
-@cost_model("partition/hist")
-def _cost_seg_hist(rows: int, features: int, max_bin: int) -> KernelCost:
-    """Segment histogram: one pass over the segment's arena rows (bin
-    planes AND residue planes ride the same row stripe) plus the
-    [F, B, 3] f32 output; 3 accumulates per (row, feature) floor."""
-    n, F, B = int(rows), int(features), int(max_bin)
-    row_b = _ARENA_B * arena_channels(F)
-    return KernelCost("partition/hist", n * row_b + F * B * 3 * 4,
-                      3 * n * F, "one arena pass, %dB/row" % row_b)
-
-
-@cost_model("partition/hist_quantized")
-def _cost_seg_hist_q(rows: int, features: int, max_bin: int) -> KernelCost:
-    """Quantized segment histogram: the per-tile DMA stops after the
-    feature rows + TWO code planes (8-sublane aligned), so the stale
-    residue/rowid rows never leave HBM — the row stripe is the whole
-    byte bill, so this IS the quantized win over partition/hist."""
-    n, F, B = int(rows), int(features), int(max_bin)
-    read_rows = min(arena_channels(F), _align8(feature_channels(F) + 2))
-    row_b = _ARENA_B * read_rows
-    return KernelCost("partition/hist_quantized",
-                      n * row_b + F * B * 3 * 4, 3 * n * F,
-                      "partial arena pass, %dB/row (f32: %dB)"
-                      % (row_b, _ARENA_B * arena_channels(F)))
-
-
-@cost_model("partition/fused_root")
-def _cost_fused_root(rows: int, features: int, max_bin: int) -> KernelCost:
-    """Fused refresh+histogram: read the feature rows once plus the
-    fresh code planes, write the code planes — replaces the separate
-    schedule's plane update (read codes + write planes) AND the full
-    arena row stripe of the f32 root segment_histogram."""
-    n, F, B = int(rows), int(features), int(max_bin)
-    # feature rows + code read + the payload group read and written back
-    row_b = _ARENA_B * (feature_channels(F) + 2 + 2 * _PAY_ROWS)
-    return KernelCost("partition/fused_root",
-                      n * row_b + F * B * 3 * 4, 3 * n * F,
-                      "one fused pass, %dB/row vs %dB separate"
-                      % (row_b, _ARENA_B * (arena_channels(F) + 2 + 6)))
-
-
-@cost_model("partition/compact")
-def _cost_compact(rows: int, features: int) -> KernelCost:
-    """Carry compaction: read every live row once, write it once at its
-    packed destination — pure data movement, zero useful FLOPs."""
-    n = int(rows)
-    row_b = _ARENA_B * arena_channels(int(features))
-    return KernelCost("partition/compact", 2 * n * row_b, 0,
-                      "pure copy, %dB/row" % row_b)

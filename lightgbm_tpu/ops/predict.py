@@ -370,24 +370,3 @@ def _cat_member(cat, iv):
     return jnp.take_along_axis(cat[None, :, :],
                                iv.astype(jnp.int32)[:, :, None],
                                axis=2)[:, :, 0]
-
-
-# -- roofline cost model (obs/perf) -------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-
-@cost_model("predict/ensemble")
-def _cost_predict(rows: int, features: int, trees: int, leaves: int,
-                  nodes: int, classes: int = 1) -> KernelCost:
-    """Signature-matmul prediction: stream X once (hi+lo planes when
-    split-f32 is active — modeled as the f32 plane only, the floor),
-    read the ensemble constants (sig dominates at [T, L, N] bf16), and
-    write [rows, k] scores.  FLOPs are the two einsums the MXU
-    executes: the [T,L,N]x[rows,T,N] signature match plus the [T,L]
-    leaf-value contraction, on top of T*N threshold compares."""
-    r, F = int(rows), int(features)
-    T, L, N, k = int(trees), int(leaves), int(nodes), max(int(classes), 1)
-    nbytes = r * F * 4 + T * L * N * 2 + T * L * 4 + r * k * 4
-    flops = 2 * r * T * L * N + 2 * r * T * L + 3 * r * T * N
-    return KernelCost("predict/ensemble", nbytes, flops,
-                      "sig einsum dominates: 2*rows*T*L*N MACs")

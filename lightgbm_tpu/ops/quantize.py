@@ -4,11 +4,10 @@ LightGBM's quantized-training mode ("Quantized Training of Gradient
 Boosting Decision Trees", NeurIPS 2022) observes that histogram
 construction is bandwidth-bound and that low-bit gradient codes keep
 split quality when gradients are STOCHASTICALLY rounded (the rounding
-noise stays zero-mean, so bin sums are unbiased estimates).  On this
-chip the observation is sharper than on CPU/GPU: NOTES.md measures the
-same ~24 TFLOP/s in every dtype, so int8 buys BYTES, not FLOPs — and
-HBM bytes (~161 GB/s) are the binding resource for every histogram
-kernel (see docs/Quantized.md and obs/perf.iteration_budget).
+noise stays zero-mean, so bin sums are unbiased estimates).  Here int8
+buys BYTES, not FLOPs: one packed payload plane instead of two float
+planes per row (docs/Quantized.md; what the chip measured for the
+histogram kernels is in PERF.md).
 
 Codes here are int8 in [-127, 127] with ONE scale per (tree, g|h):
 

@@ -673,18 +673,3 @@ def best_split_for_leaf(hist: jnp.ndarray,
                                 max_constraints=max_constraints,
                                 feature_mask=feature_mask)
     return select_best_feature(pf)
-
-
-# -- roofline cost model (obs/perf) -------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-
-@cost_model("split/xla")
-def _cost_split_xla(features: int, max_bin: int) -> KernelCost:
-    """Best-split scan over one leaf's [F, B, 3] histogram: read the
-    histogram once, write one packed split row per feature; ~32 FLOPs
-    per bin cover the L/R prefix sums, both missing directions and the
-    regularized gain formula."""
-    F, B = int(features), int(max_bin)
-    return KernelCost("split/xla", F * B * 3 * 4 + F * 64, 32 * F * B,
-                      "hist read + per-feature split row out")

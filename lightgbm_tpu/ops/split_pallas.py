@@ -513,17 +513,3 @@ def scan_single(hist, sum_g, sum_h, cnt, params: SplitParams,
         max_constraints=None if mx is None else mx[:1],
         interpret=interpret)
     return index_per_feature(pf, 0)
-
-
-# -- roofline cost model (obs/perf) -------------------------------------- #
-from ..obs.perf import KernelCost, cost_model  # noqa: E402
-
-
-@cost_model("split/pallas")
-def _cost_split_pallas(features: int, max_bin: int) -> KernelCost:
-    """Fused Pallas split scan: same compulsory traffic as the XLA scan
-    (one histogram read, one packed result row) — the kernel's win is
-    dispatch count and VMEM reuse, not bytes, so the model is shared."""
-    F, B = int(features), int(max_bin)
-    return KernelCost("split/pallas", F * B * 3 * 4 + F * 64, 32 * F * B,
-                      "fused scan; same byte floor as split/xla")

@@ -52,6 +52,14 @@ def rng():
     return np.random.RandomState(42)
 
 
+@pytest.fixture(scope="session")
+def example_files(tmp_path_factory):
+    """{name: path} of the generated stand-ins for the reference's
+    examples/ files (tests/_fixtures.py), written once per session."""
+    from _fixtures import write_examples
+    return write_examples(tmp_path_factory.mktemp("examples"))
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_per_module():
     """Accumulated jit executables eventually make a late XLA-CPU

@@ -1,11 +1,14 @@
-"""DART / GOSS / RF boosting modes (reference test_engine.py:51,735,752)."""
+"""DART / GOSS / RF boosting modes (reference test_engine.py:51,735,752).
+
+The data are the generated regression pair of tests/_fixtures.py (seed 30:
+a continuous label of variance 5.98; predicting the training mean gives a
+holdout l2 of 5.7662, the label's own noise 0.25).  Thresholds were set
+from what the label engine reaches on the CPU backend (PR 30's run), about
+a tenth above the measurement named beside each."""
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-
-REGRESSION_TRAIN = "/root/reference/examples/regression/regression.train"
-REGRESSION_TEST = "/root/reference/examples/regression/regression.test"
 
 
 def _load(path):
@@ -14,9 +17,9 @@ def _load(path):
 
 
 @pytest.fixture(scope="module")
-def data():
-    X, y = _load(REGRESSION_TRAIN)
-    Xt, yt = _load(REGRESSION_TEST)
+def data(example_files):
+    X, y = _load(example_files["regression.train"])
+    Xt, yt = _load(example_files["regression.test"])
     return X, y, Xt, yt
 
 
@@ -30,7 +33,7 @@ def test_dart(data):
                      "metric": "l2", "verbose": -1, "drop_rate": 0.1},
                     train, num_boost_round=40, valid_sets=[valid],
                     evals_result=evals, verbose_eval=False)
-    assert evals["valid_0"]["l2"][-1] < 1.0
+    assert evals["valid_0"]["l2"][-1] < 3.0   # measured 2.6868 at 40 rounds
     assert np.isfinite(bst.predict(Xt)).all()
 
 
@@ -43,7 +46,7 @@ def test_goss(data):
                      "metric": "l2", "verbose": -1, "learning_rate": 0.1},
                     train, num_boost_round=40, valid_sets=[valid],
                     evals_result=evals, verbose_eval=False)
-    assert evals["valid_0"]["l2"][-1] < 1.0
+    assert evals["valid_0"]["l2"][-1] < 1.5   # measured 1.3264 at 40 rounds
     # GOSS warm-up ends at iteration 10 (1/lr); training still converges after
     assert evals["valid_0"]["l2"][-1] < evals["valid_0"]["l2"][5]
 
@@ -83,7 +86,15 @@ def test_bagging(data):
                "bagging_freq": 2, "bagging_fraction": 0.5},
               train, num_boost_round=30, valid_sets=[valid],
               evals_result=evals, verbose_eval=False)
-    assert evals["valid_0"]["l2"][-1] < 1.0
+    assert evals["valid_0"]["l2"][-1] < 2.1   # measured 1.8605 at 30 rounds
+    # half the rows per tree is another model than all of them (2.0126)
+    full = {}
+    train = lgb.Dataset(X, y)
+    lgb.train({"objective": "regression", "metric": "l2", "verbose": -1},
+              train, num_boost_round=30,
+              valid_sets=[train.create_valid(Xt, yt)],
+              evals_result=full, verbose_eval=False)
+    assert abs(full["valid_0"]["l2"][-1] - evals["valid_0"]["l2"][-1]) > 0.01
 
 
 def test_feature_fraction(data):
@@ -91,7 +102,13 @@ def test_feature_fraction(data):
     train = lgb.Dataset(X, y)
     bst = lgb.train({"objective": "regression", "verbose": -1,
                      "feature_fraction": 0.5}, train, num_boost_round=10)
-    assert np.isfinite(bst.predict(Xt)).all()
+    pred = bst.predict(Xt)
+    assert np.isfinite(pred).all()
+    assert np.mean((pred - yt) ** 2) < 4.1    # measured 3.6738 at 10 rounds
+    # trees that saw half the columns are other trees (all columns: 3.3891)
+    full = lgb.train({"objective": "regression", "verbose": -1},
+                     lgb.Dataset(X, y), num_boost_round=10).predict(Xt)
+    assert np.abs(pred - full).max() > 0.01
 
 
 def test_shap_sums_to_prediction(data):

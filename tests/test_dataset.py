@@ -8,8 +8,6 @@ from lightgbm_tpu.io.dataset import BinnedDataset
 from lightgbm_tpu.io.metadata import Metadata
 from lightgbm_tpu.io.parser import detect_format, load_text_file
 
-REF_BINARY = "/root/reference/examples/binary_classification/binary.train"
-
 
 def _make(rng, n=500, f=5, **params):
     X = rng.randn(n, f)
@@ -76,15 +74,15 @@ def test_detect_format():
     assert detect_format(["1 2:0.5 7:0.3"]) == "libsvm"
 
 
-def test_load_reference_example():
-    mat, libsvm_labels, names = load_text_file(REF_BINARY)
+def test_load_reference_example(example_files):
+    mat, libsvm_labels, names = load_text_file(example_files["binary.train"])
     assert libsvm_labels is None
     assert mat.shape == (7000, 29)  # label + 28 features
     assert set(np.unique(mat[:, 0])) == {0.0, 1.0}
 
 
-def test_reference_example_binning():
-    mat, _, _ = load_text_file(REF_BINARY)
+def test_reference_example_binning(example_files):
+    mat, _, _ = load_text_file(example_files["binary.train"])
     y, X = mat[:, 0], mat[:, 1:]
     meta = Metadata(len(y))
     meta.set_label(y)

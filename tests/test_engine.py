@@ -1,16 +1,16 @@
 """End-to-end engine tests, modeled on the reference's
-tests/python_package_test/test_engine.py quality thresholds."""
+tests/python_package_test/test_engine.py.
+
+The data are the generated stand-ins for the reference's examples/ files
+(tests/_fixtures.py, seed 30).  Every quality threshold below was set from
+what the label engine reaches on them on the CPU backend (x64 on, as
+conftest sets it; PR 30's run), and says so beside the number."""
 import os
 
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-
-BINARY_TRAIN = "/root/reference/examples/binary_classification/binary.train"
-BINARY_TEST = "/root/reference/examples/binary_classification/binary.test"
-REGRESSION_TRAIN = "/root/reference/examples/regression/regression.train"
-REGRESSION_TEST = "/root/reference/examples/regression/regression.test"
 
 
 def _load(path):
@@ -19,16 +19,16 @@ def _load(path):
 
 
 @pytest.fixture(scope="module")
-def binary_data():
-    X, y = _load(BINARY_TRAIN)
-    Xt, yt = _load(BINARY_TEST)
+def binary_data(example_files):
+    X, y = _load(example_files["binary.train"])
+    Xt, yt = _load(example_files["binary.test"])
     return X, y, Xt, yt
 
 
 @pytest.fixture(scope="module")
-def regression_data():
-    X, y = _load(REGRESSION_TRAIN)
-    Xt, yt = _load(REGRESSION_TEST)
+def regression_data(example_files):
+    X, y = _load(example_files["regression.train"])
+    Xt, yt = _load(example_files["regression.test"])
     return X, y, Xt, yt
 
 
@@ -42,12 +42,15 @@ def test_binary(binary_data):
                     train, num_boost_round=50, valid_sets=[valid],
                     evals_result=evals, verbose_eval=False)
     logloss = evals["valid_0"]["binary_logloss"][-1]
-    assert logloss < 0.53  # reference test asserts < 0.15 train; valid band
+    # measured 0.5201 at 50 rounds (0.6761 after the first); 3 % of room
+    assert logloss < 0.535
     pred = bst.predict(Xt)
-    # holdout accuracy floor: models from different (equally valid) f32
-    # accumulation orders land 0.74-0.76 on this task — the logloss floor
-    # above is the tight quality guard, this is a sanity band
-    assert ((pred > 0.5) == (yt > 0)).mean() > 0.73
+    # holdout accuracy measured 0.740.  Models from different (equally
+    # valid) f32 accumulation orders land within 0.01 of each other on a
+    # task like this (0.74-0.76 on the reference's file), so the floor is
+    # the measurement less twice that — the logloss bound above is the
+    # tight quality guard, this is a sanity band
+    assert ((pred > 0.5) == (yt > 0)).mean() > 0.72
 
 
 def test_regression(regression_data):
@@ -58,7 +61,9 @@ def test_regression(regression_data):
     lgb.train({"objective": "regression", "metric": "l2", "verbose": -1},
               train, num_boost_round=50, valid_sets=[valid],
               evals_result=evals, verbose_eval=False)
-    assert evals["valid_0"]["l2"][-1] < 1.0
+    # measured 1.4393 at 50 rounds; predicting the training mean gives
+    # 5.7662 and the label's own noise floor is 0.25; 10 % of room
+    assert evals["valid_0"]["l2"][-1] < 1.6
 
 
 def test_missing_value_handle(rng):
@@ -82,7 +87,11 @@ def test_early_stopping(binary_data):
                      "verbose": -1, "learning_rate": 1.5, "num_leaves": 127},
                     train, num_boost_round=200, valid_sets=[valid],
                     early_stopping_rounds=5, verbose_eval=False)
+    # at learning_rate 1.5 the holdout loss rises from the first round
+    # on (measured: best round 1, stopped after 6): training stops
+    # `early_stopping_rounds` after its best round, far short of 200
     assert bst.best_iteration < 200
+    assert bst.current_iteration == bst.best_iteration + 5
 
 
 def test_continue_train(regression_data):
@@ -116,6 +125,8 @@ def test_custom_objective(binary_data):
     lgb.train({"verbose": -1, "metric": "none"}, train, num_boost_round=50,
               valid_sets=[valid], fobj=loglikelihood, feval=binary_error,
               evals_result=evals, verbose_eval=False)
+    # measured 0.276 at 50 rounds (0.548 after the first): the accuracy
+    # band of test_binary, from the other side
     assert evals["valid_0"]["error"][-1] < 0.3
 
 
@@ -201,10 +212,11 @@ def test_repo_model_loads_in_reference(name, test_path):
     np.testing.assert_allclose(pred, ref, atol=INTEROP_ATOL * scale)
 
 
-def test_repo_model_quality_on_reference_data(binary_data):
+def test_repo_model_quality_on_reference_data():
     """The frozen repo-trained binary model is not a toy: it separates
-    the reference's held-out test set."""
-    X, y, Xt, yt = binary_data
+    the reference's held-out test set (the committed copy the interop
+    suites read)."""
+    Xt, yt, _ = _interop_case("ref50", os.path.join(INTEROP, "binary.test"))
     pred = lgb.Booster(
         model_file=os.path.join(INTEROP, "repo_ref50.txt")).predict(Xt)
     from sklearn.metrics import roc_auc_score
@@ -221,6 +233,10 @@ def test_pandas_input(binary_data):
     assert bst.feature_name() == list("abcde")
     pred = bst.predict(pd.DataFrame(Xt[:, :5], columns=list("abcde")))
     assert len(pred) == len(yt)
+    # a frame is its values: the same model from the array, bit for bit
+    bst_np = lgb.train({"objective": "binary", "verbose": -1},
+                       lgb.Dataset(X[:, :5], y), num_boost_round=5)
+    np.testing.assert_array_equal(pred, bst_np.predict(Xt[:, :5]))
 
 
 def test_feature_importance(binary_data):
@@ -234,10 +250,23 @@ def test_feature_importance(binary_data):
     assert (imp_gain >= 0).all()
 
 
-def test_weights(binary_data):
+def test_weights(binary_data, example_files):
+    from sklearn.metrics import roc_auc_score
     X, y, Xt, yt = binary_data
-    w = np.loadtxt(BINARY_TRAIN + ".weight")
-    train = lgb.Dataset(X, y, weight=w)
-    bst = lgb.train({"objective": "binary", "verbose": -1}, train,
-                    num_boost_round=10)
-    assert np.isfinite(bst.predict(Xt)).all()
+    w = np.loadtxt(example_files["binary.train.weight"])
+    params = {"objective": "binary", "verbose": -1}
+    pred = lgb.train(params, lgb.Dataset(X, y, weight=w),
+                     num_boost_round=10).predict(Xt)
+    plain = lgb.train(params, lgb.Dataset(X, y),
+                      num_boost_round=10).predict(Xt)
+    assert np.isfinite(pred).all()
+    # measured holdout AUC 0.7601 weighted, 0.7736 unweighted, at 10 rounds
+    assert roc_auc_score(yt, pred) > 0.74
+    # the weights reach the gradients (largest difference measured 0.23)
+    assert np.abs(pred - plain).max() > 0.01
+    # and unit weights are no weights (measured 1.3e-7 after 3 rounds)
+    unit = lgb.train(params, lgb.Dataset(X, y, weight=np.ones(len(y))),
+                     num_boost_round=3).predict(Xt)
+    none = lgb.train(params, lgb.Dataset(X, y),
+                     num_boost_round=3).predict(Xt)
+    np.testing.assert_allclose(unit, none, atol=1e-5)

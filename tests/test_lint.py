@@ -261,7 +261,7 @@ def test_seeded_rank_conditional_collective_is_caught(tmp_path):
 
 
 def test_seeded_read_after_donate_is_caught(tmp_path):
-    bench = open(_real("tools/phase_bench.py")).read()
+    bench = open(os.path.join(FIX, "donation_bench.py")).read()
     probe = "            arrays, out_ids, arena, _ = gp.grow_tree_partition("
     tail = "                interpret=interp)\n"
     assert probe in bench and tail in bench
@@ -271,7 +271,7 @@ def test_seeded_read_after_donate_is_caught(tmp_path):
         "gp.grow_tree_partition(").replace(
         tail, tail + "            checksum = arena.sum()\n")
     for name, text in [
-            ("phase_bench.py", seeded),
+            ("donation_bench.py", seeded),
             ("grow_partition.py",
              open(_real("lightgbm_tpu/ops/grow_partition.py")).read())]:
         (tmp_path / name).write_text(text)
@@ -283,7 +283,7 @@ def test_seeded_read_after_donate_is_caught(tmp_path):
                                      only=["donation"])
             if f.check == "donation-use-after"]
     assert hits and all(f.severity == "HIGH" for f in hits)
-    assert any("arena" in f.message and f.path == "phase_bench.py"
+    assert any("arena" in f.message and f.path == "donation_bench.py"
                for f in hits)
 
 

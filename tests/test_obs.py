@@ -11,6 +11,8 @@ import sys
 import threading
 import urllib.request
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -348,3 +350,24 @@ def test_profiler_reset_and_minmax():
     assert 0 <= snap["min_ms"] <= snap["max_ms"]
     p.reset()
     assert p.snapshot() == {}
+
+
+# ------------------------------------------------- device / peak-HBM gauges
+
+def test_peak_hbm_gauge_published():
+    from lightgbm_tpu.obs import adapters, device
+    reg = MetricsRegistry()
+    adapters.ensure_device_metrics(reg)
+    text = reg.render_prometheus()
+    assert "lgbm_xla_peak_hbm_bytes" in text
+    assert "lgbm_xla_cost_analyses_total" in text
+    f = jax.jit(lambda a: jnp.sum(a * 2.0))
+    stats = device.analyze_compiled(f, (jnp.ones((64, 64)),), "64x64")
+    hbm = device.hbm_stats()
+    if stats is not None:                 # analysis availability varies
+        assert hbm["analyses"] >= 1
+        assert hbm["peak_hbm_bytes"] >= stats.get("peak_hbm_bytes", 0) or \
+            hbm["peak_hbm_bytes"] >= 0
+    # the gauge renders the live high-water mark
+    val = reg.get("lgbm_xla_peak_hbm_bytes").value
+    assert val == hbm["peak_hbm_bytes"]

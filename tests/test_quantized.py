@@ -3,9 +3,10 @@
 Covers the code/scale math, the f32 integer-exactness envelope the
 overflow guards are built on, the three quantized Pallas kernels in
 interpret mode against numpy integer references, quantized-vs-f32
-training parity on the Higgs feature shape, bitwise kill-and-resume
-determinism of the stochastic rounding, and the analytic byte floors
-the roofline/perf tooling gates on (docs/Quantized.md).
+training parity on the Higgs feature shape, and bitwise kill-and-resume
+determinism of the stochastic rounding (docs/Quantized.md).  The byte
+floor of the fused root pass is the benchmark's
+(tests/benchmark/test_bench_costs.py, `fused_root_roofline`).
 """
 import os
 
@@ -298,48 +299,3 @@ class TestKillAndResume:
                                  tpu_checkpoint_interval=2),
                             ds, num_boost_round=8, resume_from=root)
         assert resumed.model_to_string() == full.model_to_string()
-
-
-# ------------------------------------------------ analytic byte floors
-
-
-class TestByteFloors:
-    def test_iteration_budget_quantized_below_f32(self):
-        from lightgbm_tpu.obs import perf
-        f32 = perf.iteration_budget(4_194_304, 28, 255, 255,
-                                    engine="partition")
-        q = perf.iteration_budget(4_194_304, 28, 255, 255,
-                                  engine="partition", quantized=True)
-        assert q["quantized"] is True
-        assert q["total_bytes"] < f32["total_bytes"]
-
-    def test_quantized_hist_floor_le_55_percent(self):
-        # the ISSUE-8 acceptance gate, straight from the cost models
-        from lightgbm_tpu.obs import perf
-        perf.cost_models()
-        kq = perf.cost("hist/quantized", rows=4_194_304, features=28,
-                       max_bin=255)
-        kf = perf.cost("partition/hist", rows=4_194_304, features=28,
-                       max_bin=255)
-        assert kq.hbm_bytes <= 0.55 * kf.hbm_bytes
-
-    def test_fused_root_is_one_stripe_pass(self):
-        # the fused root reads the same 40-row stripe the quantized
-        # segment histogram reads (feature rows + the payload group),
-        # plus the fresh codes in and the 8-row payload group back out —
-        # Mosaic takes no 2-row DMA slice, so the group is rewritten
-        # whole (partition_pallas._PAY_ROWS).  Still one pass over the
-        # rows where the f32 schedule makes two (refresh six residue
-        # planes, then stream the full arena row).
-        from lightgbm_tpu.obs import perf
-        from lightgbm_tpu.ops import partition_pallas as pp
-        perf.cost_models()
-        n = 4_194_304
-        fused = perf.cost("partition/fused_root", rows=n, features=28,
-                          max_bin=255)
-        hist_q = perf.cost("partition/hist_quantized", rows=n, features=28,
-                           max_bin=255)
-        hist_f = perf.cost("partition/hist", rows=n, features=28,
-                           max_bin=255)
-        assert fused.hbm_bytes == hist_q.hbm_bytes + n * 2 * (2 + pp._PAY_ROWS)
-        assert fused.hbm_bytes < hist_f.hbm_bytes + n * 2 * 6

@@ -1,15 +1,12 @@
-"""Scaling forensics (obs/scaling.py + friends): decomposition math,
-the runtime sync sentinel, the donation audit, the waterfall report's
-exit-code contract, and the read-only guarantee — forensics on/off
-trains bitwise-identical models.
+"""The runtime sync sentinel and the donation audit (obs/scaling.py,
+obs/device.py), and the read-only guarantee: sentinel on/off trains
+bitwise-identical models.
 
 The sentinel tests exercise the REAL hook path (patched ArrayImpl
 conversion methods), so they also pin the restore discipline: after
 every guard exits, the class methods must be the originals again.
 """
 import json
-import os
-import sys
 from functools import partial
 
 import numpy as np
@@ -23,93 +20,6 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.obs import device as obs_device
 from lightgbm_tpu.obs import scaling
 from lightgbm_tpu.utils.log import LightGBMError
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
-
-
-def _registry():
-    from lightgbm_tpu.obs import default_registry
-    return default_registry()
-
-
-# --------------------------------------------------------------------- #
-# Step decomposition math
-# --------------------------------------------------------------------- #
-class TestDecomposition:
-    def _decomposer(self, **params):
-        cfg = Config(dict({"tpu_scaling_window": 10_000}, **params))
-        return scaling.StepDecomposer(cfg, _registry())
-
-    def test_legs_partition_wall_exactly(self):
-        d = self._decomposer()
-        phases = {"drain_inflight": {"ms": 5.0, "calls": 1},
-                  "histogram": {"ms": 9.0, "calls": 1}}
-        out = d.on_round(object(), 0, 0.020, phases)
-        assert out["wall_ms"] == pytest.approx(20.0)
-        assert out["host_sync_ms"] == pytest.approx(5.0)
-        total = (out["host_sync_ms"] + out["leader_wire_ms"]
-                 + out["psum_ms"] + out["dispatch_ms"])
-        assert total == pytest.approx(out["wall_ms"], abs=1e-2)
-
-    def test_sync_legs_clamped_to_wall(self):
-        d = self._decomposer()
-        phases = {"drain_inflight": {"ms": 50.0, "calls": 1},
-                  "tree_fetch": {"ms": 50.0, "calls": 1}}
-        out = d.on_round(object(), 0, 0.010, phases)   # 10ms wall
-        assert out["host_sync_ms"] == pytest.approx(10.0)
-        assert out["dispatch_ms"] == pytest.approx(0.0)
-        assert out["host_share"] == pytest.approx(1.0)
-
-    def test_mean_decomposition(self):
-        rounds = [{"wall_ms": 10.0, "host_sync_ms": 2.0,
-                   "leader_wire_ms": 0.0, "psum_ms": 1.0,
-                   "dispatch_ms": 7.0, "device_est_ms": 4.0},
-                  {"wall_ms": 20.0, "host_sync_ms": 4.0,
-                   "leader_wire_ms": 0.0, "psum_ms": 1.0,
-                   "dispatch_ms": 15.0, "device_est_ms": 6.0},
-                  {}]                       # skipped: no wall_ms
-        m = scaling.mean_decomposition(rounds)
-        assert m["wall_ms"] == pytest.approx(15.0)
-        assert m["host_sync_ms"] == pytest.approx(3.0)
-        assert m["device_est_ms"] == pytest.approx(5.0)
-        assert scaling.mean_decomposition([]) is None
-        assert scaling.mean_decomposition([{}]) is None
-
-
-class TestWaterfall:
-    BASE = {"wall_ms": 100.0, "host_sync_ms": 10.0, "leader_wire_ms": 0.0,
-            "psum_ms": 0.0, "dispatch_ms": 90.0}
-    W2 = {"wall_ms": 80.0, "host_sync_ms": 20.0, "leader_wire_ms": 5.0,
-          "psum_ms": 5.0, "dispatch_ms": 50.0}
-
-    def test_losses_and_identity(self):
-        wf = scaling.efficiency_waterfall({1: self.BASE, 2: self.W2})
-        e = wf[2]
-        legs = e["legs"]
-        assert legs["ideal"] == pytest.approx(50.0)
-        assert legs["host_sync"] == pytest.approx(15.0)   # 20 - 10/2
-        assert legs["leader_wire"] == pytest.approx(5.0)
-        assert legs["psum"] == pytest.approx(5.0)
-        assert legs["dispatch_gap"] == pytest.approx(5.0)  # 50 - 90/2
-        # the waterfall reconstructs the measured wall identically
-        assert sum(legs.values()) == pytest.approx(e["measured_ms"],
-                                                   abs=1e-6)
-        assert e["residual_share"] == pytest.approx(0.0, abs=1e-6)
-        assert e["dominant_loss"] == "host_sync"
-        assert e["efficiency"] == pytest.approx(100.0 / (2 * 80.0))
-        assert e["host_share"] == pytest.approx(25.0 / 80.0)
-
-    def test_world1_is_clean(self):
-        wf = scaling.efficiency_waterfall({1: self.BASE, 2: self.W2})
-        e = wf[1]
-        assert e["efficiency"] == pytest.approx(1.0)
-        assert e["dominant_loss"] == "none"
-        assert e["residual_share"] == pytest.approx(0.0, abs=1e-6)
-
-    def test_empty(self):
-        assert scaling.efficiency_waterfall({}) == {}
 
 
 # --------------------------------------------------------------------- #
@@ -219,76 +129,17 @@ class TestDonationAudit:
 
 
 # --------------------------------------------------------------------- #
-# Waterfall report gate (exit-code contract 0/1/2)
+# Read-only guarantee: sentinel on/off, bit for bit
 # --------------------------------------------------------------------- #
-class TestScalingReportGate:
-    @staticmethod
-    def _report():
-        base = {"wall_ms": 100.0, "host_sync_ms": 10.0,
-                "leader_wire_ms": 0.0, "psum_ms": 0.0, "dispatch_ms": 90.0}
-        w2 = {"wall_ms": 80.0, "host_sync_ms": 20.0, "leader_wire_ms": 5.0,
-              "psum_ms": 5.0, "dispatch_ms": 50.0}
-        wf = scaling.efficiency_waterfall({1: base, 2: w2})
-        return {"n_devices": 8, "rows": 512, "timed_iters": 2,
-                "backend": "cpu", "worlds": [1, 2], "runs": {},
-                "waterfall": {"f32": {str(w): v for w, v in wf.items()}}}
-
-    @pytest.fixture()
-    def report_main(self, monkeypatch):
-        import tools.scaling_report as sr
-        monkeypatch.setattr(sr, "build_report",
-                            lambda *a, **k: self._report())
-        return sr
-
-    def test_exit_0_within_baseline(self, report_main, tmp_path, capsys):
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps({
-            "residual_share_max": 0.10,
-            "dtypes": {"f32": {"worlds": {
-                "2": {"efficiency_min": 0.625, "host_share_max": 0.9}}}},
-        }))
-        assert report_main.main(["--baseline", str(base)]) == 0
-        assert "dominant=host_sync" in capsys.readouterr().out
-
-    def test_exit_1_on_breach(self, report_main, tmp_path, capsys):
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps({
-            "residual_share_max": 0.10,
-            "dtypes": {"f32": {"worlds": {
-                "2": {"efficiency_min": 0.625, "host_share_max": 0.1}}}},
-        }))
-        assert report_main.main(["--baseline", str(base)]) == 1
-        assert "BREACH" in capsys.readouterr().out
-
-    def test_exit_2_unreadable_baseline(self, report_main, tmp_path,
-                                        capsys):
-        missing = tmp_path / "nope.json"
-        assert report_main.main(["--baseline", str(missing)]) == 2
-        capsys.readouterr()
-
-    def test_json_output_carries_breaches(self, report_main, tmp_path,
-                                          capsys):
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps({"residual_share_max": 0.10,
-                                    "dtypes": {}}))
-        assert report_main.main(["--baseline", str(base), "--json"]) == 0
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert out["breaches"] == []
-        assert out["waterfall"]["f32"]["2"]["dominant_loss"] == "host_sync"
-
-
-# --------------------------------------------------------------------- #
-# Read-only guarantee: forensics on/off, bit for bit
-# --------------------------------------------------------------------- #
-def _train_model(tmp_path, forensics: bool, mesh: bool) -> str:
+def _train_model(tmp_path, sentinel: bool, mesh: bool) -> str:
     params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
               "learning_rate": 0.1, "verbose": -1, "seed": 11,
               "deterministic": True}
     if mesh:
         params.update(tree_learner="data", num_machines=2,
                       tpu_comm_backend="mesh", tpu_tree_engine="partition")
-    if forensics:
-        params.update(tpu_sync_guard="log", tpu_scaling_window=1,
+    if sentinel:
+        params.update(tpu_sync_guard="log",
                       tpu_telemetry_path=str(tmp_path / "tel.jsonl"))
     rng = np.random.RandomState(3)
     X = rng.rand(256, 6).astype(np.float32)
@@ -298,7 +149,7 @@ def _train_model(tmp_path, forensics: bool, mesh: bool) -> str:
     return booster.model_to_string()
 
 
-def test_forensics_bitwise_identity_serial(tmp_path):
+def test_sentinel_bitwise_identity_serial(tmp_path):
     off = _train_model(tmp_path / "off", False, mesh=False)
     (tmp_path / "on").mkdir()
     on = _train_model(tmp_path / "on", True, mesh=False)
@@ -306,34 +157,28 @@ def test_forensics_bitwise_identity_serial(tmp_path):
 
 
 @pytest.mark.slow
-def test_forensics_bitwise_identity_mesh_w2(tmp_path):
+def test_sentinel_bitwise_identity_mesh_w2(tmp_path):
     off = _train_model(tmp_path / "off", False, mesh=True)
     (tmp_path / "on").mkdir()
     on = _train_model(tmp_path / "on", True, mesh=True)
     assert on == off
 
 
-def test_forensics_emit_decomp_and_stay_clean(tmp_path):
-    """The 'on' run actually produced step_decomp sections with legs
-    summing to the wall, and the clean round path tripped zero sync
-    events — the bench smoke's invariants, pinned in-suite."""
+def test_clean_training_rounds_trip_no_sync_event(tmp_path):
+    """With the sentinel armed, the round path of a plain training run
+    makes no implicit device->host fetch, and no sync_event line reaches
+    the telemetry stream."""
+    scaling.reset_sync_stats()
     params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
-              "seed": 11, "tpu_sync_guard": "log", "tpu_scaling_window": 1,
+              "seed": 11, "tpu_sync_guard": "log",
               "tpu_telemetry_path": str(tmp_path / "tel.jsonl")}
     rng = np.random.RandomState(3)
     X = rng.rand(256, 6).astype(np.float32)
     y = (X[:, 0] > 0.5).astype(np.float32)
     ds = lgb.Dataset(X, label=y, params=dict(params))
     lgb.train(params, ds, num_boost_round=3)
-    decs = []
+    assert scaling.sync_stats()["total"] == 0
     with open(tmp_path / "tel.jsonl") as fh:
-        for line in fh:
-            ev = json.loads(line)
-            if ev.get("event") == "iteration" and "step_decomp" in ev:
-                decs.append(ev["step_decomp"])
-    assert len(decs) == 3
-    for d in decs:
-        legs = (d["host_sync_ms"] + d["leader_wire_ms"] + d["psum_ms"]
-                + d["dispatch_ms"])
-        assert legs == pytest.approx(d["wall_ms"], abs=1e-2)
-        assert d["sync_events"] == 0
+        events = [json.loads(line)["event"] for line in fh]
+    assert events.count("iteration") == 3
+    assert "sync_event" not in events

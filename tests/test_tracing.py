@@ -8,6 +8,7 @@ import json
 import multiprocessing as mp
 import os
 import socket
+import subprocess
 import sys
 import threading
 
@@ -18,8 +19,8 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.obs import tracing
 
-FIXDIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "fixtures")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXDIR = os.path.join(REPO, "tests", "fixtures")
 
 
 def _import_tool(name):
@@ -376,6 +377,26 @@ class TestTraceTools:
         bad = str(tmp_path / "bad.json")
         open(bad, "w").write("not json")
         assert trace_check.main([bad]) == 2
+
+    # the same three verdicts through the CLI's real exit codes
+    @pytest.mark.parametrize("trace,baseline,rc,stream,word", [
+        ("rank0.trace.json", "baseline.json", 0, "stdout", "OK"),
+        ("rank0.trace.json", "baseline_breach.json", 1, "stderr", "BREACH"),
+        ("bad.json", None, 2, "stderr", ""),
+    ], ids=["passes_committed_baseline", "breach", "unreadable"])
+    def test_trace_check_subprocess(self, tmp_path, trace, baseline, rc,
+                                    stream, word):
+        fix = os.path.join(FIXDIR, "trace")
+        (tmp_path / "bad.json").write_text("nope")
+        argv = [os.path.join(fix, trace) if baseline
+                else str(tmp_path / trace)]
+        if baseline:
+            argv += ["--baseline", os.path.join(fix, baseline)]
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO, "tools", "trace_check.py")]
+            + argv, capture_output=True, text=True, cwd=REPO, timeout=300)
+        assert proc.returncode == rc, proc.stderr
+        assert word in getattr(proc, stream)
 
     def test_telemetry_report_fixture(self):
         telemetry_report = _import_tool("telemetry_report")

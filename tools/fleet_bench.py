@@ -153,23 +153,6 @@ def run_bench(tenants=16, resident_cap=4, duration_s=4.0, trees=8):
     }
 
 
-def smoke():
-    """One-line summary for bench.py's fleet_smoke — never raises."""
-    try:
-        r = run_bench(tenants=8, resident_cap=2, duration_s=2.0)
-        d = r["detail"]
-        return ("fleet %d tenants / cap %d: %.0f req/s, hot p99 %.1f ms, "
-                "cold p99 %.1f ms, cold-load p99 %.0f ms, errors %d, "
-                "peak %d/%d B, ok=%s"
-                % (d["tenants"], d["resident_cap"], r["value"],
-                   d["hot"]["p99_ms"], d["cold"]["p99_ms"],
-                   d["cold_load_ms"]["p99"], d["errors"],
-                   d["fleet"]["peak_resident_bytes"], d["budget_bytes"],
-                   d["quality_ok"]))
-    except Exception as e:  # noqa: BLE001 — smoke only, never fatal
-        return "FAILED: %s" % e
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Multi-tenant fleet residency bench")

@@ -68,7 +68,7 @@ def run(argv=None):
                     "drift and resource hygiene (no jax required)")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to scan (default: %s)" %
-                         ", ".join(("lightgbm_tpu", "tools", "bench.py")))
+                         ", ".join(("lightgbm_tpu", "tools", "chip_smoke.py")))
     ap.add_argument("--root", default=REPO,
                     help="project root for relative paths and "
                          "docs/Parameters.md (default: repo root)")
@@ -155,7 +155,7 @@ def run(argv=None):
 
 
 def smoke(root=None):
-    """One-line summary for bench.py's lint_smoke — never raises."""
+    """One-line summary with per-family counts."""
     analysis = load_analysis()
     findings = analysis.run_suite(os.path.abspath(root or REPO))
     counts = analysis.severity_counts(findings)

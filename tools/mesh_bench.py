@@ -8,8 +8,7 @@ over the local devices with psum'd histograms, so throughput should
 scale near-linearly with world size while the quantized mode stays
 active (globally-agreed code scales — no serial-only ValueError).
 
-Run standalone (prints one JSON line) or via bench.py's
-``mesh_scaling`` detail hook:
+Prints one JSON line:
 
     python tools/mesh_bench.py                      # device defaults
     python tools/mesh_bench.py --rows 2000000 --iters 50
@@ -35,31 +34,11 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 
-def _read_decomps(path):
-    """step_decomp sections from a telemetry JSONL, in round order."""
-    decs = []
-    try:
-        with open(path) as fh:
-            for line in fh:
-                try:
-                    ev = json.loads(line)
-                except ValueError:
-                    continue
-                if ev.get("event") == "iteration" and "step_decomp" in ev:
-                    decs.append(ev["step_decomp"])
-    except OSError:
-        pass
-    return decs
-
-
 def run(worlds, n_rows, n_features, iters, num_leaves):
-    import tempfile
-
     import jax
     import numpy as np
 
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.obs import scaling as obs_scaling
     from lightgbm_tpu.utils import log as lgb_log
 
     lgb_log.set_level(0)        # warnings on: an engine change says so
@@ -84,22 +63,11 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                       "learning_rate": 0.1, "max_bin": 255,
                       "min_data_in_leaf": 20, "verbose": -1,
                       "tpu_tree_engine": "partition",
-                      "tpu_quantized_grad": quant,
-                      # runtime sync sentinel armed in log mode: a clean
-                      # round path reports sync_events == 0 per round
-                      "tpu_sync_guard": "log"}
+                      "tpu_quantized_grad": quant}
             if world > 1:
                 params.update(tree_learner="data", num_machines=world,
                               tpu_comm_backend="mesh")
-            # per-run telemetry stream: the recorder's step_decomp
-            # sections (obs/scaling.py) supply the attribution columns
-            tel_fd, tel_path = tempfile.mkstemp(prefix="mesh_bench_",
-                                                suffix=".jsonl")
-            os.close(tel_fd)
-            params["tpu_telemetry_path"] = tel_path
             ds = lgb.Dataset(X, label=y, params=dict(params))
-            # direct Booster (not lgb.train): train's finally would
-            # close the telemetry stream before the timed update loop
             booster = lgb.Booster(params=params, train_set=ds)
             booster.update()                                    # compile
             g = booster._gbdt
@@ -109,13 +77,7 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                 booster.update()
             float(jax.numpy.sum(g.train_state.score))
             dt = time.perf_counter() - t0
-            g.finish_telemetry()
             g._sync_model()
-            decs = _read_decomps(tel_path)[1:]  # drop the compile round
-            try:
-                os.remove(tel_path)
-            except OSError:
-                pass
             grower = g._grower
             mem = [d.memory_stats() or {} for d in jax.local_devices()]
             engine_on = (grower._partition is not None if grower is not None
@@ -126,6 +88,7 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                 # 5 decimals: CPU smoke throughputs are ~1e-4 Mrows
                 "mrows_iter_s": round(n_rows * iters / dt / 1e6, 5),
                 "elapsed_s": round(dt, 3),
+                "round_wall_ms": round(dt / iters * 1e3, 3),
                 "quantized_active": bool(getattr(g, "_quantized", False)),
                 "engine": "partition" if engine_on else "label",
                 # a truncated tree is a smaller model than the one asked
@@ -142,26 +105,6 @@ def run(worlds, n_rows, n_features, iters, num_leaves):
                 "device_peak_bytes_in_use": [m.get("peak_bytes_in_use")
                                              for m in mem],
             }
-            mean = obs_scaling.mean_decomposition(decs)
-            if mean is not None:
-                # attribution columns (mean per timed round): host-sync
-                # wall, device-compute estimate, psum wire model, and
-                # leader-wire callback wait (zero on pure-mesh worlds)
-                out["runs"][key].update(
-                    round_wall_ms=round(mean["wall_ms"], 3),
-                    host_ms=round(mean["host_sync_ms"], 3),
-                    device_ms=round(mean["device_est_ms"], 3),
-                    psum_ms=round(mean["psum_ms"], 4),
-                    callback_ms=round(mean["leader_wire_ms"], 3),
-                    host_share=round(
-                        mean["host_sync_ms"] / mean["wall_ms"], 4)
-                    if mean["wall_ms"] else 0.0,
-                    # raw mean legs: scaling_report feeds these into
-                    # obs.scaling.efficiency_waterfall unrounded-ish
-                    legs_ms={k: round(v, 4) for k, v in mean.items()},
-                    sync_events=sum(int(d.get("sync_events", 0))
-                                    for d in decs),
-                )
             # the next run's arena must not sit next to this one's
             del booster, g, grower, ds
     # scaling efficiency against the world=1 run of the same dtype

@@ -22,8 +22,7 @@ Two modes:
 A third mode sweeps replica counts (--replicas, serving/replicas.py):
 one fresh server per count under the SAME open-loop offered load,
 emitting p50/p99 + achieved throughput per replica count — the
-capacity curve the set_replica_count lever buys (and the ledger line
-tools/perf_gate.py gates as serve_replicas_p99_ms / _rows_s).
+capacity curve the set_replica_count lever buys.
 
 Usage: python tools/serve_bench.py [requests_per_level] [model_trees]
        python tools/serve_bench.py --open-loop [--qps 50,200,800]
@@ -295,8 +294,7 @@ def _replica_sweep_main(args):
             "duration_s": args.duration_s,
             "model_trees": args.trees,
             "levels": levels,
-            # 1-row requests: achieved qps IS the rows/s throughput the
-            # ledger floors (tools/perf_baseline.json serve_replicas_*)
+            # 1-row requests: achieved qps IS the rows/s throughput
             "rows_s": head["achieved_qps"],
             "quality_ok": all(r["errors"] == 0 for r in levels.values()),
         },

@@ -5,7 +5,7 @@ Summarizes one trace (single-rank file or a trace_merge.py output) into
 the numbers a perf PR argues with — per-phase p50/p95 latency and call
 counts, XLA compile/retrace counts, the share of wall time spent blocked
 on comm peers — and compares them against a committed baseline JSON,
-exiting nonzero on any breach.  CI runs this after the bench so "this
+exiting nonzero on any breach, so "this
 PR made tree_grow 2x slower" or "this PR added 30 retraces" fails the
 build instead of landing as an anecdote.
 
@@ -42,8 +42,7 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
 
 
 def summarize(trace: Dict) -> Dict:
-    """Trace-event object -> summary dict (the check's input and the
-    bench's trace-derived phase shares)."""
+    """Trace-event object -> summary dict (the check's input)."""
     events = trace.get("traceEvents", [])
     meta = trace.get("metadata") or {}
     durs: Dict[str, List[float]] = {}

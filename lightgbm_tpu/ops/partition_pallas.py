@@ -484,11 +484,10 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
 
         if hist_plan is not None:
             hs_f = hs.astype(jnp.float32)
-            hmask = (hs_f * predB + (1.0 - hs_f) * predA).astype(jnp.bfloat16)
+            hmask = hs_f * predB + (1.0 - hs_f) * predA
             nb_h, k_h, m_h, lo_h, hi_h, pay_h = hist_plan
             _radix_accumulate(hist_ref, rows, hmask, n_blocks=nb_h, k=k_h,
-                              m=m_h, lo_n=lo_h, hi_n=hi_h, tile=tile,
-                              payload=pay_h)
+                              m=m_h, lo_n=lo_h, hi_n=hi_h, payload=pay_h)
 
         pred2 = jnp.concatenate(
             [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
@@ -836,7 +835,7 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
             pltpu.SemaphoreType.DMA((2, 2, K)),
         ]
     if with_hist and not blocked:
-        lo_n, hi_n, m = _radix_plan(max_bin)
+        lo_n, hi_n, m = _hist_radix(max_bin)
         f_blk = max(m, 8)
         k = f_blk // m
         n_blocks = feature_channels(num_features) // f_blk
@@ -1229,75 +1228,134 @@ def _comp_chunks(hi_n: int, m: int, payload: int = 7):
     return chunks
 
 
+def _hist_radix(max_bin: int) -> tuple:
+    """(lo_n, hi_n, m) of the histogram kernels: `_radix_plan`'s digits,
+    hi_n rounded up to whole 8-sublane slabs of the left operand (8 // m
+    hi levels a slab; a level no bin reaches stays zero and the callers'
+    `[:max_bin]` slices it off)."""
+    lo_n, hi_n, m = _radix_plan(max_bin)
+    per_slab = 8 // m
+    return lo_n, -(-hi_n // per_slab) * per_slab, m
+
+
+def _radix_planes(block, mask, planes, *, n_blocks: int, payload: int):
+    """The masked payload planes of a tile, each on all 8 sublanes: payload
+    x [8, tile] f32 (the count's plane is the mask).  Masking by 0/1 keeps
+    every entry a bf16-exact plane value (residue planes or int8 codes);
+    one sublane broadcast per plane and tile."""
+    mask = mask.astype(jnp.float32)
+    if planes is None:
+        Fp = n_blocks * 8
+        planes = [block[Fp + i:Fp + i + 1, :] for i in range(payload - 1)]
+    slab = (8, mask.shape[1])
+    gh = [jnp.broadcast_to(p.astype(jnp.float32) * mask, slab)
+          for p in planes]
+    return gh + [jnp.broadcast_to(mask, slab)]
+
+
+def _radix_digits(rows, lo_n: int):
+    """(hi, lo) f32 digits of a radix block's 8 feature rows [8, tile]
+    bf16: bin = hi * lo_n + lo."""
+    bins = rows.astype(jnp.float32)
+    hi = jnp.floor(bins * (1.0 / lo_n))
+    return hi, bins - hi * lo_n
+
+
+def _radix_rhs(lo, *, k: int, lo_n: int):
+    """The product's right operand, [k, m*lo_n, tile] bf16 one-hot rows
+    (f', lo) of each of the block's k groups; lo_n = 16 or 32 on the
+    sublanes, so the reshape moves nothing."""
+    loh = jnp.where(
+        lo.astype(jnp.int32)[:, None, :]
+        == jax.lax.broadcasted_iota(jnp.int32, (1, lo_n, 1), 1),
+        jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
+    return loh.reshape(k, lo.shape[0] // k * lo_n, lo.shape[1])
+
+
+def _radix_hits(hi, *, k: int, m: int, hi_n: int):
+    """The compare masks of the left operands of a block's k groups, one
+    dense [8, tile] mask per 8-row slab: slab j of a group holds its m
+    features' hi levels j*k .. j*k + k - 1, row r the level j*k + r // m
+    of feature r % m."""
+    if k == 1:
+        rels = [hi]
+    else:
+        # m = 4: a group's four hi rows on both halves of the sublanes
+        # (rows r and r + 4 change places: a rotation by half of 8, the
+        # same either way), the upper half one level ahead
+        assert k == 2
+        upper = jax.lax.broadcasted_iota(jnp.int32, hi.shape, 0) >= m
+        swapped = pltpu.roll(hi, m, axis=0)
+        rels = [jnp.where(upper, swapped - 1.0, hi),
+                jnp.where(upper, hi - 1.0, swapped)]
+    return [[rel == jnp.float32(lvl) for lvl in range(0, hi_n, k)]
+            for rel in rels]
+
+
+def _radix_lhs(hits, gh, c0: int, csz: int):
+    """The product's left operand for components c0 .. c0 + csz - 1,
+    [csz*hi_n*m, tile] bf16, rows (c, hi, f): per slab one 32-bit select
+    of the component's payload rows by the slab's mask; the slabs
+    concatenate at 8-row boundaries and are cast once."""
+    return jnp.concatenate(
+        [jnp.where(h, gh[c], jnp.float32(0.0))
+         for c in range(c0, c0 + csz) for h in hits],
+        axis=0).astype(jnp.bfloat16)
+
+
 def _radix_accumulate(out_ref, block, mask, *, n_blocks: int, k: int,
-                      m: int, lo_n: int, hi_n: int, tile: int,
-                      payload: int = 7, planes=None):
+                      m: int, lo_n: int, hi_n: int, payload: int = 7,
+                      planes=None):
     """Accumulate the radix-factorized split-payload histogram of `block`
-    [C, tile] bf16 rows selected by `mask` [1, tile] bf16 (0/1) into
+    [C, tile] bf16 rows selected by `mask` [1, tile] f32 (0/1) into
     out_ref [n_blocks*k*payload*hi_n*m, lo_n*m] f32 — the shared inner
     loop of the segment-histogram kernel and the fused
     partition/refresh+histogram passes.  payload=7 is the f32-exact mode
     (6 residue planes + count); payload=3 is the quantized mode (int8
     g/h codes + count — the accumulator then holds exact integer code
     sums, see ops/quantize).  The payload planes are the block's rows
-    after the feature rows, or `planes` (payload-1 rows of [1, tile]
-    bf16) when the caller holds them apart from the feature rows."""
-    N = lo_n * m
+    after the feature rows, or `planes` (payload-1 rows of [1, tile])
+    when the caller holds them apart from the feature rows.
+
+    A bin is two digits, bin = hi * lo_n + lo.  Per product group of m
+    features and per 2 048-row tile one MXU product sums, over the rows,
+    left[(c, hi, f), t] = payload_c[t] * (hi_f[t] == hi) against the
+    one-hot right[(f', lo), t] = (lo_f'[t] == lo); the blocks f == f' of
+    the result are the histograms (`split_radix_epilogue`).
+
+    Row order, of the left operand and of the accumulator alike: group,
+    then (c, hi, f) — component, hi level, feature within the group.  The
+    8 feature rows of a radix block (f_blk = k * m = 8) fill the sublanes
+    of an f32 vreg, and they stay there: an 8-row slab of the operand is
+    8 // m consecutive hi levels of the group's m features, made by ONE
+    compare of a dense [8, tile] array (`_radix_hits`, shared by the
+    components) and one 32-bit select per component (`_radix_lhs`).
+    Nothing is built with fewer than 8 rows on the sublanes (a
+    [.., 4, tile] piece costs what an 8-row one does) and nothing is
+    multiplied in bf16 (the v5e has no bf16 VALU: unpack, multiply,
+    pack).  `part`'s rows being the accumulator's, each product is added
+    by one read-modify-write of a contiguous row range (per chunk in f32
+    mode: a chunk of components is a contiguous range in this order)."""
     Mc = payload * hi_n * m
     f_blk = k * m
+    assert f_blk == 8 and hi_n % k == 0
     chunks = _comp_chunks(hi_n, m, payload)
-    Fp = n_blocks * f_blk
-    # masking by 0/1 keeps every entry a bf16-exact plane value (residue
-    # planes or int8 codes)
-    if planes is None:
-        comps = [block[Fp + i:Fp + i + 1, :] * mask
-                 for i in range(payload - 1)]
-    else:
-        comps = [p * mask for p in planes]
-    comps.append(mask)
-    gh = jnp.concatenate(comps, axis=0)               # [payload, T] bf16
+    gh = _radix_planes(block, mask, planes, n_blocks=n_blocks,
+                       payload=payload)
 
     for b in range(n_blocks):
-        bins = block[b * f_blk:(b + 1) * f_blk, :].astype(jnp.float32)
-        hi = jnp.floor(bins * (1.0 / lo_n))
-        lo = bins - hi * lo_n
-        hih = jnp.where(
-            hi.astype(jnp.int32)[:, None, :]
-            == jax.lax.broadcasted_iota(jnp.int32, (1, hi_n, 1), 1),
-            jnp.float32(1.0),
-            jnp.float32(0.0)).astype(jnp.bfloat16)    # [f_blk,hi_n,T]
-        loh = jnp.where(
-            lo.astype(jnp.int32)[:, None, :]
-            == jax.lax.broadcasted_iota(jnp.int32, (1, lo_n, 1), 1),
-            jnp.float32(1.0),
-            jnp.float32(0.0)).astype(jnp.bfloat16)    # [f_blk,lo_n,T]
-        rhs = loh.reshape(k, N, tile)
-        c0 = 0
-        for csz in chunks:
-            # lhs[g, (f, c, hi), t] = gh[c, t] * hihot[g*m + f, hi, t]
-            # NB: slice-then-reshape, never `[None, c0:c0+csz, None]`
-            # indexing — a partial slice mixed with newaxes lowers via
-            # lax.gather, which Mosaic rejects in this shape
-            ghc = gh[c0:c0 + csz, :].reshape(1, csz, 1, tile)
-            lhs = (ghc * hih.reshape(f_blk, 1, hi_n, tile)
-                   ).reshape(k, m * csz * hi_n, tile)
-            part = jax.lax.dot_general(
-                lhs, rhs,
-                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)   # [k, m*csz*hi_n, N]
-            r0 = b * k * Mc
-            # part rows are (f, c_local, hi); the accumulator layout is
-            # (f, c, hi) with the FULL payload-component c axis — each
-            # feature's chunk block lands at its own strided offset
-            for kk in range(k):
-                for f in range(m):
-                    src = (f * csz) * hi_n
-                    dst = r0 + kk * Mc + (f * payload + c0) * hi_n
-                    sz = csz * hi_n
-                    out_ref[dst:dst + sz, :] = (
-                        out_ref[dst:dst + sz, :]
-                        + part[kk, src:src + sz, :])
-            c0 += csz
+        hi, lo = _radix_digits(block[b * f_blk:(b + 1) * f_blk, :], lo_n)
+        rhs = _radix_rhs(lo, k=k, lo_n=lo_n)
+        for kk, hits in enumerate(_radix_hits(hi, k=k, m=m, hi_n=hi_n)):
+            dst, c0 = (b * k + kk) * Mc, 0
+            for csz in chunks:
+                part = jax.lax.dot_general(
+                    _radix_lhs(hits, gh, c0, csz), rhs[kk],
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                sz = csz * hi_n * m                   # part: [sz, lo_n*m]
+                out_ref[dst:dst + sz, :] = out_ref[dst:dst + sz, :] + part
+                dst, c0 = dst + sz, c0 + csz
 
 
 def _feature_block(n_blocks: int, f_blk: int, acc_block_bytes: int) -> int:
@@ -1309,7 +1367,11 @@ def _feature_block(n_blocks: int, f_blk: int, acc_block_bytes: int) -> int:
     largest divisor of n_blocks that fits, at 16 bodies or fewer: Mosaic
     gives every unrolled body its own stack, and 50 bodies of the
     quantized kernel asked for 35 MB when compiled for a v5e (10 compile
-    in 3 s).  A step's rows start at a multiple of f_blk >= 8, which the
+    in 3 s).  That was the body before PR 31; with the left operand in
+    8-row slabs 50 bodies of both quantized kernels compile for a v5e
+    within the default limit (5 s; 25 in 2 s, 10 in 1.5 s), so the bound
+    of 16 is now a choice and not the compiler's (PERF.md, open
+    questions).  A step's rows start at a multiple of f_blk >= 8, which the
     DMA takes (40-row steps compiled); one block a step always fits, so
     every width has a plan.  `acc_block_bytes`: the accumulator's bytes
     per radix block.  From static shapes only."""
@@ -1333,7 +1395,7 @@ def _hist_plan(num_features: int, max_bin: int, payload: int) -> tuple:
     radix plan of `max_bin`, the features per radix block and blocks per
     data set, and `nb`, the radix blocks a grid step takes
     (`_feature_block`; nb == n_blocks: one step, no grid)."""
-    lo_n, hi_n, m = _radix_plan(max_bin)
+    lo_n, hi_n, m = _hist_radix(max_bin)
     f_blk = max(m, 8)
     k = f_blk // m
     n_blocks = feature_channels(num_features) // f_blk
@@ -1348,8 +1410,11 @@ def _seg_hist_kernel(sc_ref, arena_any, out_ref, in_buf, read_sems, *rest,
                      tile: int, payload: int = 7, read_rows: int = 0,
                      pay_row: int = 0):
     """sc_ref (SMEM [2] i32): start, cnt.  out_ref VMEM
-    [n_blocks*k*payload*hi_n*m, N]: payload split components per feature —
-    every lhs entry is a bf16-exact payload plane value times a one-hot,
+    [n_blocks*k*payload*hi_n*m, N]: per product group (n_blocks*k of them,
+    m features each) payload*hi_n*m rows in (component, hi level, feature)
+    order against N = m*lo_n columns (feature', lo digit); the blocks
+    feature == feature' are the histograms (`split_radix_epilogue`).
+    Every lhs entry is a bf16-exact payload plane value or zero,
     so the dots run as single bf16 MXU passes and the f32 values are
     reconstructed exactly in the epilogue.  read_rows < C (quantized
     mode) restricts the per-tile DMA to the leading arena rows that the
@@ -1409,14 +1474,14 @@ def _seg_hist_kernel(sc_ref, arena_any, out_ref, in_buf, read_sems, *rest,
 
         block = in_buf[slot]                              # [rows, T] bf16
         valid = (jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-                 < (cnt - j * tile)).astype(jnp.bfloat16)
+                 < (cnt - j * tile)).astype(jnp.float32)
         planes = None
         if pay_row:
             pay = pay_buf[slot]
             planes = [pay[i:i + 1, :] for i in range(payload - 1)]
         _radix_accumulate(out_ref, block, valid, n_blocks=n_blocks, k=k,
-                          m=m, lo_n=lo_n, hi_n=hi_n, tile=tile,
-                          payload=payload, planes=planes)
+                          m=m, lo_n=lo_n, hi_n=hi_n, payload=payload,
+                          planes=planes)
 
         @pl.when(j + 1 < n_tiles)
         def _():
@@ -1444,9 +1509,12 @@ def split_radix_epilogue(out, G: int, m: int, hi_n: int, lo_n: int,
                          payload: int = 7):
     """[G*payload*hi_n*m, N] split-component accumulator -> [G*m, B, 3]:
     payload=7 sums each f32 value's three split-plane partials; payload=3
-    (quantized) passes the integer code sums through unchanged."""
-    out = out.reshape(G, m, payload, hi_n, m, lo_n)
-    diag = jnp.moveaxis(jnp.diagonal(out, axis1=1, axis2=4), -1, 1)
+    (quantized) passes the integer code sums through unchanged.  Rows are
+    (group, c, hi, f), columns (f', lo) (`_radix_accumulate`): feature f's
+    histogram is the block f == f', its bins (hi, lo) with lo minor-most
+    as in the accumulator's columns."""
+    out = out.reshape(G, payload, hi_n, m, m, lo_n)
+    diag = jnp.moveaxis(jnp.diagonal(out, axis1=3, axis2=4), -1, 1)
     comp = diag.reshape(G * m, payload, hi_n * lo_n)
     if payload == 3:
         return jnp.stack([comp[:, 0], comp[:, 1], comp[:, 2]], axis=-1)
@@ -1686,12 +1754,10 @@ def _fused_root_kernel(sc_ref, codes_any, arena_any, out_any, hist_ref,
             pay_write_dma(j, slot).start()
 
         valid = (jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-                 < (cnt - j * tile)).astype(jnp.bfloat16)
+                 < (cnt - j * tile)).astype(jnp.float32)
         _radix_accumulate(hist_ref, in_buf[slot], valid, n_blocks=n_blocks,
-                          k=k, m=m, lo_n=lo_n, hi_n=hi_n, tile=tile,
-                          payload=3,
-                          planes=(cod[0:1, :].astype(jnp.bfloat16),
-                                  cod[1:2, :].astype(jnp.bfloat16)))
+                          k=k, m=m, lo_n=lo_n, hi_n=hi_n, payload=3,
+                          planes=(cod[0:1, :], cod[1:2, :]))
 
         @pl.when(j + 1 < n_tiles)
         def _():

@@ -356,24 +356,38 @@ class BinnedDataset:
             log.debug("EFB skipped: no two of %d features are sparse "
                       "enough to share a column", F)
             return
-        nonzero_rows = []
-        for inner, raw in enumerate(self.real_feature_index):
-            m = self.bin_mappers[inner]
-            if _issparse(Xs):
-                j0, j1 = Xs.indptr[raw], Xs.indptr[raw + 1]
-                rows = Xs.indices[j0:j1]
-                b = m.values_to_bins(np.asarray(Xs.data[j0:j1], np.float64))
-                nonzero_rows.append(rows[b != m.default_bin])
-            else:
-                b = m.values_to_bins(np.asarray(Xs[:, raw], np.float64))
-                nonzero_rows.append(np.flatnonzero(b != m.default_bin))
-        self.bundle = efb.fast_feature_bundling(
-            nonzero_rows, S, [m.num_bin for m in self.bin_mappers],
-            [m.default_bin for m in self.bin_mappers],
-            config.max_conflict_rate, config.min_data_in_leaf, self.num_data)
-        if self.bundle is not None:
-            log.info("EFB bundled %d features into %d groups",
-                     F, self.bundle.num_groups)
+        with tracing.span("data/bundle", "data", features=F,
+                          dropped_trivial=self.num_total_features - F) as sp_:
+            nonzero_rows = []
+            for inner, raw in enumerate(self.real_feature_index):
+                m = self.bin_mappers[inner]
+                if _issparse(Xs):
+                    j0, j1 = Xs.indptr[raw], Xs.indptr[raw + 1]
+                    rows = Xs.indices[j0:j1]
+                    b = m.values_to_bins(
+                        np.asarray(Xs.data[j0:j1], np.float64))
+                    nonzero_rows.append(rows[b != m.default_bin])
+                else:
+                    b = m.values_to_bins(np.asarray(Xs[:, raw], np.float64))
+                    nonzero_rows.append(np.flatnonzero(b != m.default_bin))
+            self.bundle = efb.fast_feature_bundling(
+                nonzero_rows, S, [m.num_bin for m in self.bin_mappers],
+                [m.default_bin for m in self.bin_mappers],
+                config.max_conflict_rate, config.min_data_in_leaf,
+                self.num_data)
+            found = dict(groups=F, largest_group_bins=0, conflicts=0)
+            if self.bundle is not None:
+                found = dict(
+                    groups=self.bundle.num_groups,
+                    largest_group_bins=int(self.bundle.group_num_bins.max()),
+                    conflicts=self.bundle.conflicts)
+                log.info("EFB bundled %d features into %d groups",
+                         F, self.bundle.num_groups)
+            # what the search found rides the recorded span (an armed
+            # tracer); the profiler's annotation holds what was known at
+            # the span's start
+            if hasattr(sp_, "set"):
+                sp_.set(**found)
 
     def _set_offsets(self) -> None:
         nb = [m.num_bin for m in self.bin_mappers]
@@ -479,7 +493,12 @@ class BinnedDataset:
             dtype = (np.uint8 if int(info.group_num_bins.max()) <= 256
                      else np.uint16)
             bins = np.zeros((n, info.num_groups), dtype)
-            for g, feats in enumerate(info.groups):
+
+            # a group's column depends on no other group's, and the scatter
+            # runs outside the interpreter lock: groups spread over the
+            # cores, each with one [n] scratch in the column's own type
+            def _group_column(g: int) -> None:
+                feats = info.groups[g]
                 if len(feats) == 1:
                     inner = feats[0]
                     rows, b = col_entries(inner)
@@ -487,14 +506,16 @@ class BinnedDataset:
                                   dtype)
                     col[rows] = b.astype(dtype)
                     bins[:, g] = col
-                    continue
-                col = np.zeros(n, np.int64)      # 0 = all defaults
+                    return
+                col = np.zeros(n, dtype)         # 0 = all defaults
                 for inner in feats:              # later features win
                     rows, b = col_entries(inner)
                     nz = b != int(info.feature_default[inner])
-                    col[rows[nz]] = b[nz].astype(np.int64) \
-                        + int(info.feature_shift[inner])
-                bins[:, g] = col.astype(dtype)
+                    col[rows[nz]] = (b[nz].astype(np.int64) + int(
+                        info.feature_shift[inner])).astype(dtype)
+                bins[:, g] = col
+
+            _map_columns(_group_column, range(info.num_groups))
         else:
             F = self.num_features
             max_nb = max((m.num_bin for m in self.bin_mappers), default=2)

@@ -41,8 +41,11 @@ class BundleInfo:
     """Static bundling layout shared by dataset build and tree growth."""
 
     def __init__(self, groups: List[List[int]], num_bins: Sequence[int],
-                 default_bins: Sequence[int]):
+                 default_bins: Sequence[int], conflicts: int = 0):
         self.groups = groups
+        # rows of the bin sample on which two features of one group were
+        # both non-default (0 under max_conflict_rate = 0)
+        self.conflicts = int(conflicts)
         F = len(num_bins)
         G = len(groups)
         self.feature_default = np.asarray(default_bins, np.int32)
@@ -88,19 +91,22 @@ class BundleInfo:
 
     # -- (de)serialization for the binary dataset cache -------------------
     def to_state(self) -> str:
-        return json.dumps({"groups": self.groups})
+        return json.dumps({"groups": self.groups,
+                           "conflicts": self.conflicts})
 
     @classmethod
     def from_state(cls, state: str, num_bins, default_bins) -> "BundleInfo":
-        return cls(json.loads(state)["groups"], num_bins, default_bins)
+        d = json.loads(state)
+        return cls(d["groups"], num_bins, default_bins,
+                   conflicts=d.get("conflicts", 0))
 
 
 def find_groups(nonzero_rows: List[np.ndarray], num_bins: Sequence[int],
                 default_bins: Sequence[int], order: Sequence[int],
                 total_sample_cnt: int, max_error_cnt: int, filter_cnt: int,
-                num_data: int, rng: np.random.RandomState
-                ) -> List[List[int]]:
-    """Greedy conflict-bounded grouping (FindGroups, dataset.cpp:67-137).
+                num_data: int, rng: np.random.RandomState):
+    """Greedy conflict-bounded grouping (FindGroups, dataset.cpp:67-137):
+    (groups, conflicting sample rows over all groups).
 
     nonzero_rows[f]: sample-row indices where feature f is non-default.
     """
@@ -155,7 +161,7 @@ def find_groups(nonzero_rows: List[np.ndarray], num_bins: Sequence[int],
             conflict_marks.append(marks)
             group_nonzero.append(cur_cnt)
             group_bins.append(1 + extra_bins(fidx))
-    return groups
+    return groups, sum(group_conflict)
 
 
 def fast_feature_bundling(nonzero_rows: List[np.ndarray],
@@ -185,10 +191,10 @@ def fast_feature_bundling(nonzero_rows: List[np.ndarray],
     g2 = find_groups(nonzero_rows, num_bins, default_bins, by_cnt,
                      S, max_error_cnt, filter_cnt, num_data,
                      np.random.RandomState(num_data % (2 ** 31)))
-    groups = g2 if len(g2) < len(g1) else g1
+    groups, conflicts = g2 if len(g2[0]) < len(g1[0]) else g1
     if all(len(g) == 1 for g in groups):
         return None
-    return BundleInfo(groups, num_bins, default_bins)
+    return BundleInfo(groups, num_bins, default_bins, conflicts=conflicts)
 
 
 def bundling_from_sample_bins(bins: np.ndarray, num_bins: Sequence[int],

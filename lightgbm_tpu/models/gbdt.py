@@ -109,29 +109,21 @@ class _DatasetState:
         self.score = self.score.at[class_id].add(val)
 
 
-def _bundle_maps(ds: BinnedDataset):
-    """Host BundleInfo -> device BundleMaps for the grow loop (or None)."""
-    info = ds.bundle
-    if info is None:
+def _bundle_maps(ds: BinnedDataset, scan_space: Optional[str] = None,
+                 feature_too: bool = False):
+    """Host BundleInfo -> device BundleMaps for the grow loop (or None).
+    scan_space: None builds neither scan map (a validation set reads
+    none); the training set's is the one GBDT._scan_space names, chosen
+    with the engine (_setup_tree_engine).  feature_too: forced splits
+    unbundle whatever space the scan works in."""
+    if ds.bundle is None:
         return None
-    F = ds.num_features
-    G = info.num_groups
-    B = int(info.group_num_bins.max())
-    nbf = ds.feature_num_bins()
-    db = info.feature_default
-    b = np.arange(B, dtype=np.int64)[None, :]
-    g = info.feature_group.astype(np.int64)[:, None]
-    shift = np.where(info.needs_fix, info.feature_shift, 0)[:, None]
-    valid = b < nbf[:, None]
-    is_def = info.needs_fix[:, None] & (b == db[:, None])
-    idx = np.where(valid & ~is_def, g * B + b + shift, G * B)
-    return grow_ops.BundleMaps(
-        unbundle_idx=jnp.asarray(idx.astype(np.int32)),
-        feat_col=jnp.asarray(info.feature_group),
-        feat_lo=jnp.asarray(info.feature_lo),
-        feat_hi=jnp.asarray(info.feature_hi),
-        feat_shift=jnp.asarray(info.feature_shift),
-        needs_fix=jnp.asarray(info.needs_fix))
+    return grow_ops.bundle_maps(
+        ds.bundle, ds.feature_num_bins(),
+        np.array([m.missing_type for m in ds.bin_mappers], np.int32),
+        int(ds.bundle.group_num_bins.max()),
+        feature_scan=scan_space == "feature" or feature_too,
+        group_scan=scan_space == "group")
 
 
 class GBDT:
@@ -298,9 +290,10 @@ class GBDT:
 
     # ------------------------------------------------------------------ #
     def _setup_train(self, train_set: BinnedDataset) -> None:
-        # the fused-iteration jit closes over THIS train set's bundle maps,
-        # categorical flags, hist slots and forced splits as trace-time
-        # constants; a ResetTrainingData with a same-shaped dataset would
+        # the fused-iteration jit closes over THIS train set's categorical
+        # flags, hist slots and forced splits as trace-time constants (the
+        # bundle maps are arguments; their structure is part of the trace);
+        # a ResetTrainingData with a same-shaped dataset would
         # otherwise reuse the stale trace and silently train on the old
         # dataset's structure (c_api.cpp ResetTrainingData contract)
         self._fused_fn = None
@@ -375,6 +368,11 @@ class GBDT:
             self._grower.audit_donation = (self.recorder is not None
                                            or self._tracing)
         self._setup_tree_engine()
+        # the training set's scan map, for the space _setup_tree_engine
+        # chose with the engine; the grow loop follows the map it is given
+        self.train_state.bundle = _bundle_maps(
+            train_set, self._scan_space,
+            feature_too=bool(self._forced_splits))
         # bagging state
         self._bag_mask: Optional[jnp.ndarray] = None
         self._row_all_in = jnp.zeros(self.num_data, jnp.int32)
@@ -706,7 +704,7 @@ class GBDT:
 
         def fused(arena, bins_t, score, field_vals, row0, fmasks,
                   num_bins, default_bins, missing_types, sparams, monotone,
-                  penalty, shrink, qkey):
+                  penalty, bundle, shrink, qkey):
             # score is [k, n]; gradients come back class-major and every
             # class's tree grows in the SAME program, reusing the one
             # donated arena; each class gets its own feature mask (the
@@ -738,8 +736,7 @@ class GBDT:
                     arena, bins_t, g_in, h_in, row0, fmasks[kk],
                     num_bins, default_bins, missing_types, sparams,
                     monotone, penalty,
-                    None, None, self.is_categorical,
-                    self.train_state.bundle,
+                    None, None, self.is_categorical, bundle,
                     max_leaves=self.config.num_leaves,
                     max_depth=self.config.max_depth,
                     max_bin=self.max_bin, emit="score", full_bag=True,
@@ -785,7 +782,8 @@ class GBDT:
                 field_vals, self._row_all_in, fmasks,
                 self.train_state.num_bins, self.train_state.default_bins,
                 self.train_state.missing_types, self.split_params,
-                self.monotone, self.penalty, sh, qkey)
+                self.monotone, self.penalty, self.train_state.bundle, sh,
+                qkey)
         if rebuilt and getattr(self, "_tracing", False) \
                 and getattr(self.config, "tpu_trace_xla_analysis", True):
             # kernel attribution: one "compile" span per retrace carrying
@@ -913,7 +911,7 @@ class GBDT:
 
         def fused(arena, bins_t, root0, dst, field_vals, row0, fmask,
                   num_bins, default_bins, missing_types, sparams,
-                  monotone, penalty, shrink, qkey):
+                  monotone, penalty, bundle, shrink, qkey):
             olds = [getattr(h, a) for h, a in fields_io]
             for (h, a), v in zip(fields_io, field_vals):
                 setattr(h, a, v)
@@ -945,7 +943,7 @@ class GBDT:
                 arena, bins_t, g_in, h_in, row0, fmask,
                 num_bins, default_bins, missing_types, sparams,
                 monotone, penalty, None, None, self.is_categorical,
-                self.train_state.bundle,
+                bundle,
                 max_leaves=L, max_depth=self.config.max_depth,
                 max_bin=self.max_bin, emit="carry", full_bag=True,
                 max_cat_threshold=self.config.max_cat_threshold,
@@ -997,7 +995,7 @@ class GBDT:
             self._row_all_in, fmask,
             self.train_state.num_bins, self.train_state.default_bins,
             self.train_state.missing_types, self.split_params,
-            self.monotone, self.penalty, sh, qkey)
+            self.monotone, self.penalty, self.train_state.bundle, sh, qkey)
         if not getattr(self, "_fused_validated", False):
             with obs_scaling.exempt():   # one-shot fault-surfacing sync
                 int(ivec[-1])
@@ -1211,6 +1209,10 @@ class GBDT:
         full generality (CPU/f64/categorical/distributed learners)."""
         cfg = self.config
         eng = cfg.tpu_tree_engine
+        # the space the split scan's rows are in, decided in this function
+        # and nowhere else: the plan says it, _bundle_maps builds that
+        # space's map, and the grow loop follows the map it is given
+        self._scan_space = "feature"
         base_ok = (self.dtype == jnp.float32
                    and self.max_bin <= 256
                    and self.train_set.num_features > 0
@@ -1331,12 +1333,23 @@ class GBDT:
                 # quiet move to the slow engine
                 log.fatal("the partition engine has no block plan for %d "
                           "columns: %s" % (n_groups, e))
+            if (self.train_set.bundle is not None
+                    and self.is_categorical is None):
+                # the serial partition engine's numerical scan reads the
+                # bundled histogram itself; the label engine, a grower and
+                # the categorical scan unbundle to one row per feature
+                self._scan_space = "group"
             plan.update(arena_bytes=C * cap * 2,
                         bins_t_bytes=self.num_data * n_groups * 2,
                         hist_cache_bytes=hist_cache_bytes,
-                        device_budget_bytes=budget)
+                        device_budget_bytes=budget,
+                        # the arena's columns, the data set's, and which of
+                        # the two the split scan's rows are
+                        groups=n_groups,
+                        features=self.train_set.num_features,
+                        scan_space=self._scan_space)
             log.info("partition engine plan: %s", ", ".join(
-                "%s=%d" % kv for kv in plan.items()))
+                "%s=%s" % kv for kv in plan.items()))
         self._engine_plan = plan
         self._use_partition_engine = eng == "partition"
         if pooling_blocked and self._use_partition_engine:

@@ -40,13 +40,53 @@ class BundleMaps(NamedTuple):
     """Device-side EFB layout (io/efb.py BundleInfo): the bin matrix holds
     [n, G] bundled group columns; scans and splits address original
     features through these maps (FeatureGroup::SubFeatureIterator +
-    Dataset::FixHistogram, feature_group.h:146-152, dataset.cpp:928-949)."""
-    unbundle_idx: jnp.ndarray   # [F, B] int32 into flat [G*B] (+1 sentinel)
+    Dataset::FixHistogram, feature_group.h:146-152, dataset.cpp:928-949).
+    The two scan maps are built only for the engine that reads them."""
     feat_col: jnp.ndarray       # [F] int32 group column of each feature
     feat_lo: jnp.ndarray        # [F] int32 group-bin range of the feature's
     feat_hi: jnp.ndarray        #          mapped (non-default) bins
     feat_shift: jnp.ndarray     # [F] int32 group_bin = feature_bin + shift
     needs_fix: jnp.ndarray      # [F] bool default bin reconstructed at scan
+    # [F, B] int32 into flat [G*B] (+1 sentinel): scans in feature space
+    # (unbundle_hist: the label engine; categorical, voting and forced
+    # splits on the partition engine)
+    unbundle_idx: Optional[jnp.ndarray] = None
+    # [5, Gp, Bp] int32 per-lane statics of the scan in group space
+    # (split_pallas.group_lane_statics: the partition engine's numerical
+    # path, which never makes an [F, B, 3] histogram)
+    scan_lanes: Optional[jnp.ndarray] = None
+
+
+def bundle_maps(info, num_bins, missing_types, hist_bins: int,
+                feature_scan: bool, group_scan: bool) -> BundleMaps:
+    """Host BundleInfo (io/efb.py) -> BundleMaps, with the scan maps asked
+    for.  hist_bins: bins per histogram column (the largest group's)."""
+    import numpy as np
+    nbf = np.asarray(num_bins)
+    db = info.feature_default
+    idx = lanes = None
+    if feature_scan:
+        G, B = info.num_groups, int(hist_bins)
+        b = np.arange(B, dtype=np.int64)[None, :]
+        g = info.feature_group.astype(np.int64)[:, None]
+        shift = np.where(info.needs_fix, info.feature_shift, 0)[:, None]
+        valid = b < nbf[:, None]
+        is_def = info.needs_fix[:, None] & (b == db[:, None])
+        idx = jnp.asarray(np.where(valid & ~is_def, g * B + b + shift,
+                                   G * B).astype(np.int32))
+    if group_scan:
+        from .split_pallas import group_lane_statics
+        lanes = jnp.asarray(group_lane_statics(
+            info.groups, info.feature_lo, info.feature_hi,
+            info.feature_shift, info.needs_fix, nbf, db,
+            np.asarray(missing_types), int(hist_bins)))
+    return BundleMaps(
+        feat_col=jnp.asarray(info.feature_group),
+        feat_lo=jnp.asarray(info.feature_lo),
+        feat_hi=jnp.asarray(info.feature_hi),
+        feat_shift=jnp.asarray(info.feature_shift),
+        needs_fix=jnp.asarray(info.needs_fix),
+        unbundle_idx=idx, scan_lanes=lanes)
 
 
 def build_forced_candidate(hist, cnt, f_feat, f_thr, f_dl, unbundle,
@@ -80,6 +120,9 @@ def unbundle_hist(hist, sum_g, sum_h, cnt, bundle: Optional[BundleMaps],
     and partition engines — the two must stay math-identical."""
     if bundle is None:
         return hist
+    if bundle.unbundle_idx is None:
+        raise ValueError("these BundleMaps were built without the "
+                         "feature-space scan map (bundle_maps)")
     F = bundle.feat_col.shape[0]
     flat = jnp.concatenate(
         [hist.reshape(-1, 3), jnp.zeros((1, 3), hist.dtype)], axis=0)

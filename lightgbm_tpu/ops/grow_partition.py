@@ -139,8 +139,11 @@ def grow_tree_partition_impl(
 
     bins_t holds the (possibly EFB-bundled) GROUP columns [G, n]; the
     per-feature arrays (feature_mask/num_bins/...) address ORIGINAL
-    features and scans go through the bundle unbundling, exactly like the
-    label engine (Dataset::FixHistogram, dataset.cpp:928-949).
+    features.  With bundles the numerical scan reads the bundled
+    histogram itself (bundle.scan_lanes, split_pallas._group_scan_kernel);
+    the categorical scan, the voting learner and forced splits go through
+    the bundle unbundling, exactly like the label engine
+    (Dataset::FixHistogram, dataset.cpp:928-949).
 
     With axis_name (inside shard_map), rows are sharded per device: each
     device runs its own arena over local rows while histograms are
@@ -356,9 +359,24 @@ def grow_tree_partition_impl(
             (jnp.arange(F, dtype=jnp.int32) // f_local) == _dev)
     else:
         scan_feature_mask = feature_mask
-    fvec1 = fvec2 = None
+    # a bundled set's numerical scan works on the bundled histogram
+    # itself (split_pallas._group_scan_kernel): rows are the G group
+    # columns, never the F features, and no [F, B, 3] array is made per
+    # split.  Voting elects per feature and keeps the feature-space scan.
+    # The caller decides (models/gbdt.py::_setup_tree_engine) by the map
+    # it hands over.
+    group_scan = bundle is not None and bundle.scan_lanes is not None
+    if group_scan and (vp or not use_scan_kernel):
+        raise ValueError("the group-space scan map (bundle.scan_lanes) "
+                         "serves the numerical scan of the serial and "
+                         "data-parallel learners only")
+    fvec1 = fvec2 = lane_planes = None
     with jax.named_scope("lgbm.root"):
-        if use_scan_kernel:
+        if group_scan:
+            lane_planes = sp_pl.group_lane_planes(
+                bundle.scan_lanes, monotone=monotone, penalty=penalty,
+                feature_mask=scan_feature_mask)
+        elif use_scan_kernel:
             fvec1 = sp_pl.build_feature_statics(
                 num_bins, default_bins, missing_types, monotone=monotone,
                 penalty=penalty, feature_mask=scan_feature_mask, children=1)
@@ -370,6 +388,19 @@ def grow_tree_partition_impl(
         pen = jnp.where(used, 0.0, cegb_coupled).astype(jnp.float32)
         return fvec.at[:, sp_pl._CEGBF].set(
             jnp.concatenate([pen] * children) if children > 1 else pen)
+
+    def _group_rows(hist, sum_g, sum_h, cnt, used, mn, mx):
+        """[CH, RWC] best rows of CH leaves from their bundled histograms
+        [CH, G, B, 3]."""
+        planes = lane_planes
+        if cegb_coupled is not None and used is not None:
+            own = bundle.scan_lanes[sp_pl._LOWN]
+            pen = jnp.where(used, 0.0, cegb_coupled).astype(jnp.float32)
+            planes = planes.at[sp_pl._TCEGB].set(
+                jnp.where(own >= 0, pen[jnp.maximum(own, 0)], 0.0))
+        return sp_pl.best_split_rows_group(
+            hist, sum_g, sum_h, cnt, bundle.scan_lanes, planes, params,
+            min_constraints=mn, max_constraints=mx, interpret=interpret)
 
     def _gate(rows, depth_ok):
         """Mask rows that can never apply (depth limit): gain -> NEG,
@@ -526,16 +557,21 @@ def grow_tree_partition_impl(
                 None if maxc is None else jnp.reshape(
                     jnp.asarray(maxc, dtype), (1,)))
         elif use_scan_kernel:
-            h1 = unbundle(hist, sum_g, sum_h, cnt)[None]
+            h1 = (hist if group_scan
+                  else unbundle(hist, sum_g, sum_h, cnt))[None]
             mn1 = mx1 = None
             if monotone is not None and minc is not None:
                 mn1 = jnp.reshape(jnp.asarray(minc, dtype), (1,))
                 mx1 = jnp.reshape(jnp.asarray(maxc, dtype), (1,))
-            rows = sp_pl.best_split_rows_pallas(
-                h1, jnp.reshape(sum_g, (1,)), jnp.reshape(sum_h, (1,)),
-                jnp.reshape(cnt, (1,)), _patch_cegb(fvec1, used, 1), params,
-                min_constraints=mn1, max_constraints=mx1,
-                interpret=interpret)
+            one = (jnp.reshape(sum_g, (1,)), jnp.reshape(sum_h, (1,)),
+                   jnp.reshape(cnt, (1,)))
+            if group_scan:
+                rows = _group_rows(h1, *one, used, mn1, mx1)
+            else:
+                rows = sp_pl.best_split_rows_pallas(
+                    h1, *one, _patch_cegb(fvec1, used, 1), params,
+                    min_constraints=mn1, max_constraints=mx1,
+                    interpret=interpret)
         else:
             res = leaf_best_result(hist, sum_g, sum_h, cnt, used=used,
                                    minc=minc, maxc=maxc)
@@ -552,6 +588,10 @@ def grow_tree_partition_impl(
             rows = _vote_rows(hist2, sg2, sh2, cnt2_,
                               mn2 if monotone is not None else None,
                               mx2 if monotone is not None else None)
+        elif group_scan:
+            rows = _group_rows(hist2, sg2, sh2, cnt2_, used,
+                               mn2 if monotone is not None else None,
+                               mx2 if monotone is not None else None)
         elif use_scan_kernel:
             h2 = jax.vmap(lambda hh, gg, hs, cc: unbundle(hh, gg, hs, cc))(
                 hist2, sg2, sh2, cnt2_)

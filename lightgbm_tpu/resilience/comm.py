@@ -284,6 +284,11 @@ class Heartbeat:
         if self._thread is not None:
             self._thread.join(timeout=2 * self.interval_s + 1.0)
             self._thread = None
+        if self._miss_gauge is not None:
+            # a stopped monitor watches no peer: a streak left in the
+            # process-wide registry would keep the alert engine's
+            # heartbeat_miss rule firing on every later server of the process
+            self._miss_gauge.set(0)
 
     def dead_ranks(self) -> List[int]:
         return sorted(self._dead)

@@ -395,3 +395,61 @@ def test_compiled_for_v5e_never_copies_or_selects_the_cache(name, one_chip):
                 *shapes).compile().as_text()
     assert "tpu_custom_call" in hlo
     _assert_no_whole_cache_ops(hlo, name)
+
+
+# ---- a bundled set: the scan reads the bundled histogram (PR 32) --------
+def _bundled_args(n=300):
+    """Two one-hot blocks of six columns and one plain column, bundled
+    into three group columns: (arguments of the growth program, bundle
+    maps, number of features)."""
+    from lightgbm_tpu.io.efb import BundleInfo
+    from lightgbm_tpu.ops import grow as grow_ops
+    rng = np.random.RandomState(7)
+    nb = np.array([2] * 12 + [B], np.int32)
+    info = BundleInfo([list(range(6)), list(range(6, 12)), [12]],
+                      nb, np.zeros(13, np.int32))
+    bins = np.stack([rng.randint(0, 7, n), rng.randint(0, 7, n),
+                     rng.randint(0, B, n)], axis=1).astype(np.uint8)
+    maps = grow_ops.bundle_maps(info, nb, np.zeros(13, np.int32), B,
+                                feature_scan=False, group_scan=True)
+    grad = (np.round(rng.randn(n) * 16) / 16).astype(np.float32)
+    hess = (rng.randint(1, 4, n) / 2).astype(np.float32)
+    G = 3
+    args = (jnp.zeros((pp.arena_channels(G), 8 * pp.TILE), pp.ARENA_DT),
+            jnp.asarray(bins.T.astype(np.float32)), jnp.asarray(grad),
+            jnp.asarray(hess), jnp.zeros(n, jnp.int32), jnp.ones(13, bool),
+            jnp.asarray(nb), jnp.zeros(13, jnp.int32),
+            jnp.zeros(13, jnp.int32))
+    return args, maps, 13
+
+
+def _no_per_feature_histogram(text, features):
+    """No value of a per-feature histogram's shape [.., F, B, 3]."""
+    found = re.findall(r"f32\[(?:\d+,)?%d,%d,3\]" % (features, B), text)
+    assert not found, "per-feature histograms in the growth program: %s" \
+        % sorted(set(found))
+
+
+def test_a_bundled_set_is_scanned_without_a_per_feature_histogram(one_chip):
+    args, maps, features = _bundled_args()
+
+    def grow(*a, interpret):
+        return gp.grow_tree_partition_impl(
+            *a[:9], _PARAMS, None, None, None, None, None, a[9],
+            max_leaves=8, max_bin=B, full_bag=True, interpret=interpret)
+
+    tree, *_ = jax.jit(functools.partial(grow, interpret=True))(*args, maps)
+    assert int(tree.num_leaves) == 8
+    assert int(jnp.max(tree.split_feature)) > 2   # feature ids, not groups
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in jax.tree_util.tree_leaves((args, maps))]
+    treedef = jax.tree_util.tree_structure((args, maps))
+
+    def flat(*leaves):
+        a, m = jax.tree_util.tree_unflatten(treedef, leaves)
+        return grow(*a, m, interpret=False)
+
+    with jax.enable_x64(False):
+        hlo = jax.jit(flat).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in hlo and "_run_scan" in hlo
+    _no_per_feature_histogram(hlo, features)

@@ -340,6 +340,26 @@ class TestHeartbeat:
         dead.remove(2)  # a rank coming back is observed too
         assert hb.poll_once() == [3]
         assert gauge.value == 3
+        # rank 3 has missed two probes: stop() takes that streak out of the
+        # process-wide registry, where the alert engine of any later server
+        # in this process would read it (test_federation.py's serving case)
+        hb.stop()
+
+    def test_a_stopped_monitor_leaves_no_miss_streak(self):
+        """The streak gauge is process-wide and the alert engine's
+        heartbeat_miss rule reads it by name: a comm that missed probes
+        and was closed must not leave a later server of the process
+        firing (tests/test_federation.py's serving case did, under load)."""
+        reg = default_registry()
+        hb = Heartbeat(lambda: [1], interval_s=60.0, rank=0, world=3,
+                       registry=reg, suspect_after=5)
+        streak = reg.gauge("lgbm_comm_heartbeat_miss_streak", rank="0",
+                           world="3")
+        hb.poll_once()
+        hb.poll_once()
+        assert streak.value == 2
+        hb.stop()
+        assert streak.value == 0
 
     def test_detection_latency_bounded(self):
         """A silent rank is convicted within interval_s * suspect_after

@@ -9,8 +9,9 @@ rows live physically grouped by leaf in a feature-major f32 "arena"
 
 - `partition_segment` splits a parent segment into its two children with
   one sequential pass: per 256-lane sub-block it builds a compaction
-  permutation (prefix-scan of the go-left predicate -> position one-hot)
-  and applies it as an MXU matmul — a TPU has no fast scatter, so row
+  permutation (prefix-scan of the go-left predicate -> the rows' sorted
+  positions -> a one-hot the MXU takes as the mask of a weight push) and
+  applies it as an MXU matmul — a TPU has no fast scatter, so row
   movement is expressed as dense matrix products.  Stream A may be
   written back in place over the parent (writes provably lag reads); the
   other child goes to the bump-allocator cursor.  This mirrors the
@@ -161,7 +162,12 @@ def _align8(rows: int) -> int:
 
 
 _VMEM_DEFAULT = 16 << 20     # Mosaic's scoped VMEM limit when none is given
-_VMEM_PER_CHANNEL = 40 << 10  # partition_segment's VMEM per arena channel
+# partition_segment's VMEM per arena channel, one block: the read slots
+# (8 KiB), the two carries (2 KiB), the staging (16 KiB) and what Mosaic
+# spills of the sort products and the appends (6 KiB: compiled for a v5e,
+# C = 496 fits the default limit and C = 528 asks for 16.47 MB; the tile's
+# predicate part holds nothing per channel and, since PR 33, no one-hot)
+_VMEM_PER_CHANNEL = 34 << 10
 
 
 def _side_effect_params():
@@ -189,75 +195,127 @@ def _prefix_scan_lanes(x):
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
     sh = 1
     while sh < n:
-        x = x + jnp.where(lane >= sh, pltpu.roll(x, sh, axis=x.ndim - 1), 0.0)
+        x = x + jnp.where(lane >= sh, pltpu.roll(x, sh, axis=x.ndim - 1), 0)
         sh *= 2
     return x
 
 
 FLUSH_W = SUB          # flush chunk width; all HBM write offsets are
 #                        multiples of FLUSH_W (tiled-memref alignment).
-#                        128 RE-TESTED with the sort-P kernel (round 5):
-#                        21.8 vs 22.9 Mrows*iter/s — narrower carries
-#                        don't pay for the doubled flush DMAs here either
+#                        SUB = FLUSH_W = 128 RE-TESTED on the chip with
+#                        PR 33's tile body: 11.44 vs 9.77 ms per 10.5M-row
+#                        pass at C = 48, 3.90 vs 3.68 at C = 160 — the
+#                        halved operand does not pay for twice the appends'
+#                        and flushes' bookkeeping (PERF.md §6, PR 33)
 CARRY_W = FLUSH_W + SUB    # compact_carry's carry width (append window)
 # partition_segment's append rotates inside one FLUSH_W window and completes
 # at most one chunk
 assert FLUSH_W == SUB
 
 
-def _sort_P(pref2, pred2, K: int):
-    """Stable-partition permutation one-hots for ALL subblocks of a tile
-    in one build: P_all [K, SUB, SUB] bf16 — subblock k's stream-A rows
-    map to columns [0, ca_k) (compacted, in order) and its stream-B rows
-    to columns [ca_k, ca_k + cb_k), i.e. ONE [C, S] @ [S, S] MXU matmul
-    per subblock SORTS the block into an A-prefix and a B-suffix.  Half
-    the MACs of the previous dual-stream [S, 2*SUB] product: the two
-    halves of that output were disjoint by construction, so the split
-    point ca_k (known before any matmul from the prefix scans) lets both
-    streams share one SUB-wide product; the appends separate them again
-    with cheap lane masks + the usual VPU carry roll.
+def _sort_pos(pref2, pred2, K: int):
+    """Where each row of a tile's K subblocks lands in its sorted subblock:
+    pos [K, SUB] int32 — a stream-A row at its rank among the subblock's A
+    rows (columns [0, ca_k), compacted, in order), a stream-B row at ca_k +
+    its rank among the B rows, and -1 for a row in neither stream (the
+    invalid tail of a segment's last tile), which no column of the one-hot
+    matches: validity is folded into the position.
 
-    pref2/pred2: [2K, SUB] f32 — A-rows then B-rows (inclusive prefix
-    sums and 0/1 predicates).  Invalid rows (neither stream) map
-    nowhere (all-zero P row)."""
-    pA = pred2[:K]                                     # [K, S] f32 0/1
-    vAB = pred2[:K] + pred2[K:]                        # valid (0/1)
-    ca = pref2[:K, SUB - 1].reshape(K, 1)              # [K, 1] f32
-    pos = (pA * (pref2[:K] - 1.0)
-           + (1.0 - pA) * (pref2[K:] - 1.0 + ca))      # [K, S] f32
-    t3 = jax.lax.broadcasted_iota(jnp.int32, (K, SUB, SUB), 2)
-    # build the one-hot in f32 then cast: an i1 mask from 32-bit compares
-    # can't relayout onto 16-bit vector selects in Mosaic
-    return jnp.where(
-        (pos.astype(jnp.int32)[:, :, None] == t3)
-        & (vAB[:, :, None] > 0.5),
-        jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
+    pref2/pred2: [2K, SUB] int32 — A-rows then B-rows (inclusive prefix
+    sums and 0/1 predicates)."""
+    ca = pref2[:K, SUB - 1].reshape(K, 1)              # [K, 1]
+    return jnp.where(pred2[:K] == 1, pref2[:K] - 1,
+                     jnp.where(pred2[K:] == 1, pref2[K:] - 1 + ca, -1))
 
 
-def _decide(block, feat_onehot_ref, mask_ref, xr):
-    """In-kernel split decision of one tile: [1, T] f32, 1.0 -> stream A.
+def _sort_P(pos, k: int):
+    """Subblock k's permutation operand, TRANSPOSED: Pt [SUB, SUB] bf16
+    with Pt[t, s] = (pos[k, s] == t), so that ONE product
+    `chunk[C, s] . Pt[t, s]` (both operands contracted over their last
+    axis, as the histogram kernels' products are) SORTS the subblock into
+    an A-prefix and a B-suffix; the appends separate the two streams again
+    with lane masks and the usual carry roll.
 
-    The arena column of the split feature is read with a one-hot matvec
-    over channels (feat_onehot_ref [1, C]; bins < 256 are bf16-exact),
-    then routed through the go-left MASK VECTOR (mask_ref [1, MB]:
-    mask[v] == 1 -> bin value v goes left), XOR'd with xr.  The mask is
+    Built in this orientation, `pos` stays along the lanes as the prefix
+    scan leaves it (one sublane broadcast of row k) and the iota runs down
+    the sublanes: ONE compare an element, and Mosaic feeds the i1 result to
+    the MXU as the mask of a transposed weight push (`vmatpush.xpose.msk`)
+    without ever selecting or packing a bf16 value.  The orientation
+    P[s, t] it replaces moved `pos` and a validity array from lanes to
+    sublanes (3 500 XLU operations a tile, a quarter of the tile body at
+    C = 48), compared twice, ANDed, selected in f32 and packed."""
+    t = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
+    # f32 then bf16: an i1 mask from 32-bit compares can't relayout onto
+    # 16-bit vector selects in Mosaic
+    return jnp.where(pos[k:k + 1, :] == t, jnp.float32(1.0),
+                     jnp.float32(0.0)).astype(jnp.bfloat16)
+
+
+_SORT_DIMS = (((1,), (1,)), ((), ()))     # chunk[C, s] . Pt[t, s] -> [C, t]
+
+
+def _split_column(group, row, K: int):
+    """The split feature's bin values of one tile, [K, SUB] int32 in the
+    subblock layout the prefix scan wants (subblock k on sublane k):
+    `group` is the [16, tile] bf16 row group that holds the feature's
+    channel (a 16-aligned slice: the bf16 sublane tile), `row` the
+    channel's place in it.  No product and no [1, tile] strip: the 8-row
+    half with the channel is chosen by a scalar, and subblock k's lanes of
+    it are rotated down the sublanes until the channel sits on sublane
+    k % 8, where one select takes it.  Exact: the values are moved, never
+    summed."""
+    g = group.astype(jnp.float32)
+    half = jnp.where(row >= 8, g[8:], g[:8])               # [8, tile]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, SUB), 0)
+    slabs = []
+    for k0 in range(0, K, 8):
+        slab = jnp.zeros((8, SUB), jnp.float32)
+        for k in range(k0, min(k0 + 8, K)):
+            moved = pltpu.roll(half[:, k * SUB:(k + 1) * SUB],
+                               (k - row) & 7, axis=0)
+            slab = jnp.where(sub == k - k0, moved, slab)
+        slabs.append(slab)
+    return jnp.concatenate(slabs, axis=0)[:K].astype(jnp.int32)
+
+
+def _go_left(col, mask_ref):
+    """The go-left MASK VECTOR looked up at every bin value of `col`
+    ([K, SUB] int32, values < 256): 1 -> the row goes left.  mask_ref
+    ([2, 128] int32) holds mask[v] at [v // 128, v % 128]; a lane gather
+    per 128-lane piece and mask half (`tpu.dynamic_gather`), chosen by the
+    bin's top bit: no one-hot of the bins and no product.  The mask is
     built in XLA per split and encodes ALL decision semantics — numerical
     threshold + missing direction (NumericalDecision, tree.h:429-465),
     categorical bitsets (CategoricalDecision, tree.h:259-273) and EFB
     bundle-local bin ranges — so the kernel needs no per-kind logic."""
-    tile = block.shape[1]
-    col = jnp.round(jax.lax.dot(feat_onehot_ref[:], block,
-                                preferred_element_type=jnp.float32)
-                    ).astype(jnp.int32)                   # [1, T]
-    MB = mask_ref.shape[1]
-    col_onehot = jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, (MB, tile), 0)
-        == col.reshape(1, tile),
-        jnp.float32(1.0), jnp.float32(0.0)).astype(jnp.bfloat16)
-    go_left_f = jax.lax.dot(mask_ref[:], col_onehot,
-                            preferred_element_type=jnp.float32)
-    xr_f = jnp.float32(xr)
-    return go_left_f + xr_f - 2.0 * go_left_f * xr_f      # xor
+    halves = [jnp.broadcast_to(mask_ref[h:h + 1, :], (col.shape[0], 128))
+              for h in range(2)]
+    out = []
+    for c in range(0, col.shape[1], 128):
+        v = col[:, c:c + 128]
+        lane = v & 127
+        lo, hi = (jnp.take_along_axis(m, lane, axis=1) for m in halves)
+        out.append(jnp.where(v >= 128, hi, lo))
+    return jnp.concatenate(out, axis=1)
+
+
+def _decide(group, row, mask_ref, xr, K: int):
+    """In-kernel split decision of one tile: [K, SUB] int32, 1 -> stream
+    A.  The split feature's bin values (`_split_column`) looked up in the
+    go-left mask (`_go_left`), XOR'd with xr."""
+    return _go_left(_split_column(group, row, K), mask_ref) ^ xr
+
+
+def _decision_operands(feat, mask_vec):
+    """What the kernel's decision reads, from a split's (feature channel,
+    go-left mask over at most 256 bin values): the mask as mask_ref wants
+    it ([2, 128] int32, mask[v] at [v // 128, v % 128]) and the two
+    scalars after sc_ref's first seven — the first channel of the 16-row
+    group around the channel and the channel's place in it."""
+    feat = jnp.asarray(feat, jnp.int32)
+    mv = jnp.asarray(mask_vec, jnp.float32).reshape(-1) > 0.5
+    goleft = jnp.pad(mv, (0, 256 - mv.shape[0])).astype(jnp.int32)
+    return goleft.reshape(2, 128), [feat // _SUBL * _SUBL, feat % _SUBL]
 
 
 def _append_plan(fill, counts):
@@ -321,7 +379,8 @@ def _channel_block(C: int, per_channel: int, resident: int = 0,
 # Blocked, partition_segment holds per channel of a block the read slots
 # (8 KiB), the staging (16 KiB) and the sort products Mosaic spills (8 KiB);
 # of EVERY channel the two streams' carries; of none the tile's permutation
-# (1 MiB), the decision rows and the pred tiles.  Compiled for a v5e at
+# operands (`P_ref`, 1 MiB: every block replays them), the decision's 16-row
+# group (`dec_buf`, 128 KiB) and the pred tiles.  Compiled for a v5e at
 # C = 2016: blocks of 336 fit the default limit, 20.86 MB of scratch at
 # 672 do not.
 _PART_PER_BLOCK_CHANNEL = 32 << 10
@@ -338,13 +397,14 @@ def partition_channel_block(C: int) -> int:
                           _PART_FIXED)
 
 
-def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
+def _partition_kernel(sc_ref, mask_ref, arena_any, pred_any,
                       out_any, cnt_ref, *rest,
                       C: int, tile: int, hist_plan=None, cb: int = 0):
-    """sc_ref (SMEM [7] i32): start, cnt, dstA, dstB, mode, xr, hs —
-    start, dstA and dstB must be multiples of `tile` resp. FLUSH_W (the
-    bump allocator aligns).  Blocked (cb < C), an eighth entry holds the
-    first channel of the 16-row group around the split feature's channel.
+    """sc_ref (SMEM [9] i32): start, cnt, dstA, dstB, mode, xr, hs, then
+    the first channel of the 16-row group around the split feature's
+    channel and the channel's place in that group — start, dstA and dstB
+    must be multiples of `tile` resp. FLUSH_W (the bump allocator aligns).
+    mask_ref (VMEM [2, 128] i32): the go-left mask over the 256 bin values.
     arena_any/out_any: [C, cap] bf16 in HBM, aliased (same buffer).
     Routing: mode=0 reads pred_any ([1, cap] f32, 1.0 -> stream A);
     mode=1 computes the split decision in-kernel (`_decide`), XOR'd with
@@ -352,6 +412,17 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
     side).  The caller bakes ALL decision semantics (numerical threshold,
     missing direction, categorical bitsets, EFB ranges) into the mask.
     cnt_ref (SMEM out [2] i32): rows written to A and B.
+
+    What a tile computes before its first row moves does not scale with C
+    and is kept small (PR 33; at C = 48 it was over half of the tile's
+    instructions): the decision is a 16-row slice of the tile already in
+    VMEM, one row of it rotated into the [K, SUB] subblock layout
+    (`_split_column`) and looked up in the mask by a lane gather
+    (`_go_left`) — no [1, C] or [1, 256] product with M = 1 and no
+    256-row one-hot of the bins; validity, the prefix scan and the rows'
+    positions stay in that layout (two dense vregs an array); and the
+    permutation operand is built transposed, one compare an element
+    (`_sort_P`), and handed to the MXU as a push mask.
 
     Each SUB-lane sub-block is compacted with an MXU permutation matmul
     and appended onto its stream's carry, a [C, FLUSH_W] f32 window that
@@ -380,12 +451,13 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
 
     CHANNEL BLOCKS (cb < C: an arena too wide for one [C, tile] slab in
     VMEM).  Where a row goes depends on the predicate only, so the tile's
-    decision, prefix scan, [K, SUB, SUB] permutation and append plan are
-    made ONCE per tile, from the 16-row group that holds the split
-    feature's channel (a small DMA of its own; `pred` in mode 0), and
-    every block of cb channels replays them: its own read DMA, sort
-    products, appends onto its own carries (kept for all C/cb blocks, 2 KiB
-    a channel) and flushes to its own rows.  The pipeline's unit becomes
+    decision, prefix scan, K [SUB, SUB] permutation operands (stored as
+    bf16 in `P_ref`) and append plan are made ONCE per tile, from the
+    16-row group that holds the split feature's channel (the same code as
+    in one block, the group read by a small DMA of its own; `pred` in
+    mode 0), and every block of cb channels replays them: its own read
+    DMA, sort products, appends onto its own carries (kept for all C/cb
+    blocks, 2 KiB a channel) and flushes to its own rows.  The pipeline's unit becomes
     the step (tile j, block b), tile-major: step t waits the flushes of
     step t-2 (same staging parity), starts read t+1, computes, starts its
     flushes, waits read t+1.  Fill and written are the same for every
@@ -426,6 +498,10 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
     xr = sc_ref[5]    # XOR'd into the decision: 1 when the left child is
     #                   the smaller (stream-B) side
     hs = sc_ref[6]    # fused-histogram stream: 1 -> B, 0 -> A
+    # the 16-row group (the bf16 sublane tile) that holds the split
+    # feature's channel, and the channel's place in it
+    grp = pl.multiple_of(sc_ref[7], _SUBL)
+    dec_row = sc_ref[8]
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
     lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
 
@@ -465,45 +541,48 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
         return jnp.where(keep, window,
                          jnp.where(tail, jnp.float32(0.0), rolled))
 
-    def tile_permutation(j, rows_at, pred_at):
+    sub_idx = (jax.lax.broadcasted_iota(jnp.int32, (K, SUB), 0) * SUB
+               + jax.lax.broadcasted_iota(jnp.int32, (K, SUB), 1))
+
+    def tile_permutation(j, group_at, pred_at, rows_at=None):
         """The predicate's part of tile j, made once whatever the number
-        of channel blocks: the rows' streams, ONE batched prefix scan for
-        all subblocks of both streams (the per-subblock scans were
-        2*K*log2(SUB) serial roll steps) and ONE batched P build.
-        `rows_at()` gives the rows the decision reads.  Returns (those
-        rows, P_all [K, S, S], the 2K subblock counts)."""
-        valid = jax.lax.broadcasted_iota(
-            jnp.int32, (1, tile), 1) < (cnt - j * tile)
-        rows = rows_at()
-        mode_f = jnp.float32(mode)
-        on_f = (mode_f * _decide(rows, feat_onehot_ref, mask_ref, xr)
-                + (1.0 - mode_f) * pred_at())
-        on = on_f > 0.5
-        predA = jnp.where(valid & on, jnp.float32(1.0), jnp.float32(0.0))
-        predB = jnp.where(valid & ~on, jnp.float32(1.0), jnp.float32(0.0))
+        of channel blocks, all of it in the [K, SUB] subblock layout (a
+        tile's 2 048 rows in two dense vregs, not sixteen one-row ones):
+        the rows' streams, ONE batched prefix scan for all subblocks of
+        both streams and the rows' sorted positions.  `group_at()` gives
+        the 16-row group the decision reads, `rows_at()` the rows the
+        fused histogram reads.  Returns (pos [K, SUB], the 2K subblock
+        counts)."""
+        valid = sub_idx < (cnt - j * tile)
+        by_pred = (pred_at().reshape(K, SUB) > 0.5).astype(jnp.int32)
+        on = (mode * _decide(group_at(), dec_row, mask_ref, xr, K)
+              + (1 - mode) * by_pred)
+        predA = jnp.where(valid, on, 0)
+        predB = jnp.where(valid, 1 - on, 0)
 
         if hist_plan is not None:
-            hs_f = hs.astype(jnp.float32)
-            hmask = hs_f * predB + (1.0 - hs_f) * predA
+            hmask = (hs * predB + (1 - hs) * predA).astype(
+                jnp.float32).reshape(1, tile)
             nb_h, k_h, m_h, lo_h, hi_h, pay_h = hist_plan
-            _radix_accumulate(hist_ref, rows, hmask, n_blocks=nb_h, k=k_h,
-                              m=m_h, lo_n=lo_h, hi_n=hi_h, payload=pay_h)
+            _radix_accumulate(hist_ref, rows_at(), hmask, n_blocks=nb_h,
+                              k=k_h, m=m_h, lo_n=lo_h, hi_n=hi_h,
+                              payload=pay_h)
 
-        pred2 = jnp.concatenate(
-            [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
-        pref2 = _prefix_scan_lanes(pred2)                  # [2K, SUB]
-        cnt2 = pref2[:, SUB - 1].astype(jnp.int32)         # [2K]
-        return rows, _sort_P(pref2, pred2, K), cnt2        # P: [K, S, S]
+        pred2 = jnp.concatenate([predA, predB], axis=0)    # [2K, SUB]
+        pref2 = _prefix_scan_lanes(pred2)
+        return _sort_pos(pref2, pred2, K), pref2[:, SUB - 1]
 
     def sort_append(block, P_at, plan_of, carries_in, slot):
-        """K dependency-free SORT matmuls ([rows,S]@[S,S]: A-prefix +
-        B-suffix in a single product — half the MACs of the dual-stream
-        [S,2S] build) and the 2K straight-line appends of one block of
-        channels.  `plan_of()` gives the tile's (cA, cB) subblock counts,
-        `plan_of(cA, cB)` its two append plans.  Returns (the two new
-        carries, the plans)."""
-        comps = [jax.lax.dot(block[:, k * SUB:(k + 1) * SUB], P_at(k),
-                             preferred_element_type=jnp.float32)
+        """K dependency-free SORT matmuls (chunk[rows, s] . Pt[t, s]:
+        A-prefix + B-suffix in a single product — the split point ca_k
+        is known from the prefix scan before any product, so the two
+        streams share one SUB-wide output) and the 2K straight-line
+        appends of one block of channels.  `plan_of()` gives the tile's
+        (cA, cB) subblock counts, `plan_of(cA, cB)` its two append plans.
+        Returns (the two new carries, the plans)."""
+        comps = [jax.lax.dot_general(block[:, k * SUB:(k + 1) * SUB],
+                                     P_at(k), _SORT_DIMS,
+                                     preferred_element_type=jnp.float32)
                  for k in range(K)]                        # [rows, S] f32
         # split each sorted block into its A-prefix / B-suffix; the
         # B chunk is a subtraction, not a second select
@@ -582,8 +661,9 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
                 for d in read_dmas(j + 1, nslot):
                     d.start()
 
-            block, P_all, cnt2 = tile_permutation(
-                j, lambda: in_buf[slot], lambda: pred_buf[slot])
+            pos, cnt2 = tile_permutation(
+                j, lambda: in_buf[slot, pl.ds(grp, _SUBL), :],
+                lambda: pred_buf[slot], lambda: in_buf[slot])
 
             def plan_of(cA=None, cB=None):
                 if cA is None:
@@ -593,7 +673,7 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
                         _append_plan(fills[1], cB))
 
             (carryA[:], carryB[:]), plans = sort_append(
-                block, lambda k: P_all[k], plan_of,
+                in_buf[slot], lambda k: _sort_P(pos, k), plan_of,
                 lambda: [carryA[:], carryB[:]], slot)
             new_fills, new_written, new_pending = start_flushes(
                 plans, written, slot)
@@ -608,8 +688,6 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
             0, n_tiles, loop, (z2, z2, z2, z2))
         n_steps = n_tiles
     else:
-        grp = sc_ref[7]   # first channel of the split feature's 16-row group
-
         def rows_dma(j, row0, rows, buf, sems, slot):
             """Read `rows` channels from `row0` of tile j."""
             src = pl.multiple_of(s + j * tile, 128)
@@ -646,9 +724,10 @@ def _partition_kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, pred_any,
         def tile_loop(j, state):
             fills, written, pending, pending2 = state
             tslot = jax.lax.rem(j, jnp.int32(2))
-            _, P_all, cnt2 = tile_permutation(
+            pos, cnt2 = tile_permutation(
                 j, lambda: dec_buf[tslot], lambda: pred_buf[tslot])
-            P_ref[:] = P_all
+            for k in range(K):
+                P_ref[k] = _sort_P(pos, k)
             cA = [cnt2[k] for k in range(K)]
             cB = [cnt2[K + k] for k in range(K)]
             plans = (_append_plan(fills[0], cA), _append_plan(fills[1], cB))
@@ -777,32 +856,15 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
     C, cap = arena.shape
     cb = partition_channel_block(C)
     blocked = cb < C
-    z = jnp.int32(0)
-    MB = 256   # mask lane width (any bin value < 256 fits)
-    # blocked, the kernel reads the decision from the 16-row group around
-    # the feature's channel, and the one-hot addresses a row of the group
-    W = _SUBL if blocked else C
     if decision is None:
-        tail = [z, z]
-        feat = z
-        feat_onehot = jnp.zeros((1, W), ARENA_DT)
-        goleft = jnp.zeros((1, MB), ARENA_DT)
+        mode, (feat, mask_vec, xr) = 0, (0, jnp.zeros(256), 0)
     else:
-        feat, mask_vec, xr = decision
-        feat = jnp.asarray(feat, jnp.int32)
-        tail = [jnp.int32(1), jnp.asarray(xr, jnp.int32)]
-        feat_onehot = (jnp.arange(W, dtype=jnp.int32)[None, :]
-                       == (feat % W if blocked else feat)).astype(ARENA_DT)
-        mv = jnp.asarray(mask_vec, jnp.float32).reshape(1, -1)
-        goleft = jnp.pad(mv, ((0, 0), (0, MB - mv.shape[1]))
-                         ).astype(ARENA_DT)
+        mode, (feat, mask_vec, xr) = 1, decision
+    goleft, group_and_row = _decision_operands(feat, mask_vec)
     with_hist = hist_stream is not None
-    tail.append(jnp.asarray(hist_stream if with_hist else 0, jnp.int32))
-    if blocked:
-        tail.append(feat // _SUBL * _SUBL)
-    sc = jnp.stack([jnp.asarray(start), jnp.asarray(cnt),
-                    jnp.asarray(dstA), jnp.asarray(dstB)]
-                   + tail).astype(jnp.int32)
+    sc = jnp.stack([jnp.asarray(v, jnp.int32) for v in [
+        start, cnt, dstA, dstB, mode, xr, hist_stream if with_hist else 0]
+        + group_and_row])
     hist_plan = None
     out_specs = (pl.BlockSpec(memory_space=pl.ANY),
                  pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -851,20 +913,19 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=out_specs,
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
-        input_output_aliases={3: 0},
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             vmem_limit_bytes=(None if blocked else
                               _partition_vmem_limit(C, out_shape[2:]))),
         interpret=interpret,
-    )(sc, feat_onehot, goleft, arena, pred)
+    )(sc, goleft, arena, pred)
     if not with_hist:
         return outs[0], outs[1]
     if blocked:

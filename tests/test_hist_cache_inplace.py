@@ -397,6 +397,26 @@ def test_compiled_for_v5e_never_copies_or_selects_the_cache(name, one_chip):
     _assert_no_whole_cache_ops(hlo, name)
 
 
+@pytest.mark.parametrize("F", [28, 37, 137, 2000])  # C = 48, 64, 160, 2 016
+def test_partition_segment_compiles_for_v5e_at_the_cells_widths(F, one_chip):
+    """The tile body's lane gather, its dynamic 16-row slice of the tile in
+    VMEM and the transposed sort products pass interpret mode whatever
+    Mosaic makes of them: compile the kernel for the chip at the four
+    cells' widths, one block and six."""
+    C, cap = pp.arena_geometry(100_000, F)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        hlo = jax.jit(lambda arena, mask, feat, xr, cnt: pp.partition_segment(
+            arena, jnp.zeros((1, pp.TILE), jnp.float32), 0, cnt, 0,
+            60 * pp.TILE, decision=(feat, mask, xr))).lower(
+                sds((C, cap), pp.ARENA_DT), sds((256,), jnp.float32),
+                *[sds((), jnp.int32)] * 3).compile().as_text()
+    assert "tpu_custom_call" in hlo and "partition_segment" in hlo
+
+
 # ---- a bundled set: the scan reads the bundled histogram (PR 32) --------
 def _bundled_args(n=300):
     """Two one-hot blocks of six columns and one plain column, bundled

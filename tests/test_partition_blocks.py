@@ -117,6 +117,21 @@ def test_blocked_partition_reads_the_decision_from_any_block(blocks, feat,
         got[:, tpe._DST_B:tpe._DST_B + (~to_A).sum()], seg[:, ~to_A])
 
 
+@pytest.mark.parametrize("place", tpe._PLACES)
+def test_blocked_decision_reads_the_channel_wherever_it_sits(blocks, place):
+    """The decision's 16-row group comes by its own DMA: the split channel
+    on each of the group's rows, every bin value, both `xr`."""
+    blocks(partition=16)
+    tpe._run_decision(28, 27 if place == "last" else 16 + place, "bitset")
+
+
+@pytest.mark.parametrize("kind", tpe._MASKS)
+@pytest.mark.parametrize("F,cb,chan", [(28, 16, 5), (137, 80, 130)])
+def test_blocked_decision_by_every_kind_of_mask(blocks, F, cb, chan, kind):
+    blocks(partition=cb)
+    tpe._run_decision(F, chan, kind)
+
+
 @pytest.mark.parametrize("F,cb", [(28, 16), (137, 80)])
 def test_blocked_partition_equals_the_one_block_kernel(blocks, F, cb):
     """The same calls through the one-block kernel and through blocks:

@@ -9,6 +9,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # interpret-mode Pallas dominates the whole-tree tests — they are excluded
 # from the fast tier (pytest -m 'not slow'); run the full suite before
@@ -455,6 +457,168 @@ def test_partition_segment_fused_histogram(hist_stream, cnt, mode):
         np.add.at(want[f, :, 1], b, h)
         np.add.at(want[f, :, 2], b, 1.0)
     np.testing.assert_array_equal(np.asarray(out[2], np.float64), want)
+
+
+# --------------------------------------------------------------------- #
+# the split decision: every bin value, every kind of mask, every place of
+# the split channel in its 16-row group
+# --------------------------------------------------------------------- #
+_MASKS = ("threshold", "missing_left", "missing_right", "bitset",
+          "efb_range")
+
+
+def _mask_of(kind):
+    """A go-left mask over the 256 bin values, as ops/grow_partition.py
+    bakes one: a numerical threshold, a threshold with the missing bin
+    sent against it (the NaN bin, the last, going left; the zero bin going
+    right), a categorical bitset, a feature's range inside an EFB bundle
+    (the bins outside it are the feature's default bin)."""
+    v = np.arange(256)
+    if kind == "threshold":
+        return v <= 117
+    if kind == "missing_left":
+        return (v <= 60) | (v == 254)
+    if kind == "missing_right":
+        return (v <= 200) & (v != 0)
+    if kind == "bitset":
+        return np.random.RandomState(5).rand(256) < 0.5
+    lo, hi, shift, default = 37, 181, 36, 0
+    inside = (v >= lo) & (v < hi)
+    return np.where(inside, v - shift, default) <= 70
+
+
+def _every_bin(n, seed):
+    """n >= 256 bin values in which every value 0..255 occurs."""
+    rs = np.random.RandomState(seed)
+    return rs.permutation(np.concatenate(
+        [np.arange(256), rs.randint(0, 256, n - 256)])).astype(np.float32)
+
+
+@jax.jit
+def _decide_alone(group, mask2, sc):
+    """pp._decide by itself, on one tile's 16-row group."""
+    K = pp.TILE // pp.SUB
+
+    def kernel(sc_ref, group_ref, mask_ref, out_ref):
+        out_ref[:] = pp._decide(group_ref[:], sc_ref[0], mask_ref, sc_ref[1],
+                                K)
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((K, pp.SUB), jnp.int32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        interpret=True)(sc, group, mask2)
+
+
+@pytest.mark.parametrize("row", range(16))
+@pytest.mark.parametrize("xr", [0, 1])
+@pytest.mark.parametrize("kind", _MASKS)
+def test_decide_is_the_mask_at_every_bin_value(kind, xr, row):
+    """The decision of a tile against numpy: the split channel on each of
+    its group's 16 rows, every bin value 0..255 eight times, each kind of
+    mask, both `xr`; the answer in the [K, SUB] subblock layout."""
+    group = np.random.RandomState(row).randint(
+        0, 256, (16, pp.TILE)).astype(np.float32)
+    group[row] = _every_bin(pp.TILE, 16 * xr + row)
+    mask = _mask_of(kind)
+    got = _decide_alone(jnp.asarray(group, pp.ARENA_DT),
+                        pp._decision_operands(0, mask)[0],
+                        jnp.asarray([row, xr], jnp.int32))
+    want = mask[group[row].astype(int)] ^ bool(xr)
+    np.testing.assert_array_equal(
+        np.asarray(got), want.reshape(-1, pp.SUB).astype(np.int32))
+
+
+def _run_decision(F, chan, kind, cnt=2 * pp.TILE + 300):
+    """partition_segment by decision on channel `chan`, both `xr`, against
+    numpy: counts and both streams."""
+    C = pp.arena_channels(F)
+    arena = _base_arena(C)
+    arena[chan, _START:_START + cnt] = _every_bin(cnt, chan)
+    mask = _mask_of(kind)
+    seg = arena[:, _START:_START + cnt]
+    left = mask[seg[chan].astype(int)]
+    for xr in (0, 1):
+        out, counts = pp.partition_segment(
+            jnp.asarray(arena, pp.ARENA_DT),
+            jnp.zeros((1, pp.TILE), jnp.float32), _START, cnt, _START,
+            _DST_B, decision=(chan, jnp.asarray(mask, jnp.float32), xr),
+            interpret=True)
+        to_A = left ^ bool(xr)
+        nA = int(to_A.sum())
+        got = np.asarray(out.astype(jnp.float32))
+        assert list(np.asarray(counts)) == [nA, cnt - nA]
+        np.testing.assert_array_equal(got[:, _START:_START + nA],
+                                      seg[:, to_A])
+        np.testing.assert_array_equal(got[:, _DST_B:_DST_B + cnt - nA],
+                                      seg[:, ~to_A])
+
+
+# the second 16-row group's sixteen places, then the last feature: in the
+# last group before the payload planes (F = 137) or beside them (28, 37)
+_PLACES = tuple(range(16)) + ("last",)
+
+
+@pytest.mark.parametrize("place", _PLACES)
+@pytest.mark.parametrize("F", [28, 37, 137])     # C = 48, 64, 160
+def test_decision_reads_the_channel_wherever_it_sits(F, place):
+    _run_decision(F, F - 1 if place == "last" else 16 + place, "bitset")
+
+
+@pytest.mark.parametrize("kind", _MASKS)
+@pytest.mark.parametrize("F", [28, 37, 137])
+def test_decision_by_every_kind_of_mask(F, kind):
+    _run_decision(F, 5, kind)
+
+
+# --------------------------------------------------------------------- #
+# the permutation operand against a numpy stable partition
+# --------------------------------------------------------------------- #
+def _streams(case):
+    """(stream A, stream B) membership of one tile's rows."""
+    n = pp.TILE
+    i = np.arange(n)
+    rnd = np.random.RandomState(3).rand(n) < 0.4
+    if case == "invalid_tail":           # a segment's last tile
+        valid = i < 5 * pp.SUB // 2 + 3
+        return rnd & valid, ~rnd & valid
+    if case == "empty_A":
+        return np.zeros(n, bool), np.ones(n, bool)
+    if case == "empty_B":
+        return np.ones(n, bool), np.zeros(n, bool)
+    if case == "empty_both":
+        return np.zeros(n, bool), np.zeros(n, bool)
+    if case == "single_row_A":
+        return i == 777, np.zeros(n, bool)
+    if case == "single_row_B":
+        return np.zeros(n, bool), i == 2047
+    return rnd, ~rnd                     # "mixed"
+
+
+@pytest.mark.parametrize("case", ["mixed", "invalid_tail", "empty_A",
+                                  "empty_B", "empty_both", "single_row_A",
+                                  "single_row_B"])
+def test_sort_operand_is_a_stable_partition(case):
+    """`_sort_pos` and `_sort_P` by themselves: the product of a subblock
+    with its operand holds the subblock's A rows in order, then its B
+    rows, then zeros; rows of neither stream go nowhere."""
+    K, S = pp.TILE // pp.SUB, pp.SUB
+    a, b = _streams(case)
+    pred2 = jnp.asarray(np.concatenate([a.reshape(K, S), b.reshape(K, S)]),
+                        jnp.int32)
+    pref2 = jnp.cumsum(pred2, axis=1)
+    pos = pp._sort_pos(pref2, pred2, K)
+    rows = np.random.RandomState(1).randint(-250, 250, (24, pp.TILE))
+    for k in range(K):
+        Pt = np.asarray(pp._sort_P(pos, k).astype(jnp.float32))
+        assert set(np.unique(Pt)) <= {0.0, 1.0}
+        chunk = rows[:, k * S:(k + 1) * S]
+        ka, kb = a[k * S:(k + 1) * S], b[k * S:(k + 1) * S]
+        want = np.zeros_like(chunk)
+        want[:, :ka.sum()] = chunk[:, ka]
+        want[:, ka.sum():ka.sum() + kb.sum()] = chunk[:, kb]
+        np.testing.assert_array_equal(chunk @ Pt.T, want)
 
 
 def _numpy_partition_segment(arena, pred, start, cnt, dstA, dstB,

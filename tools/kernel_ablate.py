@@ -1,12 +1,33 @@
-"""Stage-ablation profile of the partition kernel — measures cumulative
-cost of each pipeline stage by compiling stripped variants (a checksum
-into cnt_ref keeps Mosaic from DCE-ing live stages).
+"""Stage profile of the partition kernel's tile body, and of the histogram
+kernels' (`hist`): step 0 of a kernel PR.
 
-Usage: python tools/kernel_ablate.py [rows_millions [features]]
+Usage: python tools/kernel_ablate.py [rows_millions [features [sub]]]
        python tools/kernel_ablate.py hist <rows> <features> <max_bin> <q|f32>
 
-On a TPU only: the partition stages die in Mosaic lowering off the chip and
-the `hist` mode refuses to start (a time from interpret mode is no reading).
+`10.5 28` is higgs (C = 48), `13.18 37` Allstate (C = 64), `2.27 137` MSLR
+(C = 160).  `sub` (128 or 256) re-traces every stage, the shipped kernel
+among them, with `SUB` = `FLUSH_W` = sub: the constant's re-test, for this
+tool alone (the package has one value and no switch).
+
+The partition stages are ADDITIVE: the compute stages run over a tile that
+is RESIDENT in VMEM (two tiles are read once, before the loop; tile j
+computes on slot j % 2, so nothing can be hoisted out of the loop), where
+no read hides them: a stage's increment over the stage before is what it
+adds to a compute-bound tile body.  (Until PR 33 every stage ran under the
+read pipeline and read max(read, compute): `decide` = 4.60 ms beside `dma`
+= 4.56 was taken for 0.03 ms of work.)  `dma` is the read pipeline alone,
+one tile in flight, and `full` the shipped kernel (`pp.partition_segment`:
+read, appends, flushes and write-back included), so what the appends and
+flushes cost is `full` less `chunks` less whatever of the read the tile
+body does not hide.  Every stage sums what it makes into the loop's carry,
+so all of it stays alive; `pbuild` sums all K permutation operands, as
+bf16 values, into a VMEM sink, one add per packed vreg (32 a subblock at
+SUB = 256: it reads that much too high, and it makes the kernel SELECT and
+PACK operands that `matmul` hands to the MXU as push masks, so `matmul`
+may read below `pbuild`).
+
+On a TPU only: it prints the device kind and exits 1 elsewhere (a time from
+interpret mode is no reading).
 """
 import functools
 import sys
@@ -21,26 +42,73 @@ from jax.experimental.pallas import tpu as pltpu
 sys.path.insert(0, ".")
 from lightgbm_tpu.ops import partition_pallas as pp  # noqa: E402
 
-SUB, TILE = pp.SUB, pp.TILE
+TILE = pp.TILE
 ARENA_DT = pp.ARENA_DT
 
-# cumulative stages of the tile body.  A stage's checksum reads one element
-# of what it computed, so the compiler may drop the rest: `pbuild` may keep
-# one of the K permutation one-hots; `matmul` reads one element of every
-# product, which keeps all K builds and matmuls; `chunks` adds the A/B split
-# and is everything but the appends; `full` is the shipped kernel itself
-# (pp.partition_segment), appends, flushes and write-back included
-STAGES = ("dma", "decide", "scan", "pbuild", "matmul", "chunks", "full")
+# `loop` is the empty stage loop (its time is subtracted from the others);
+# `column` adds the split feature's bin values (pp._split_column), `lookup`
+# the go-left mask's lookup and the two streams' predicates (pp._go_left),
+# `scan` the batched prefix scan and the rows' sorted positions
+# (pp._sort_pos), `pbuild` all K permutation operands (pp._sort_P),
+# `matmul` the K sort products in place of the sink, `chunks` the A/B split:
+# everything but the appends
+STAGES = ("loop", "column", "lookup", "scan", "pbuild", "matmul", "chunks")
 
 
-def _kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, out_any, cnt_ref,
-            in_buf, read_sems, *, C: int, tile: int, stage: str):
-    """The shipped kernel's read pipeline and parallel region, cut after
-    `stage`; nothing is written back."""
+def tile_body(stage, in_buf, slot, j, sc_ref, mask_ref, sink):
+    """Tile j's body up to `stage`, made from the kernel's own pieces;
+    returns an [8, 128] f32 digest of what the stage made."""
+    SUB, K = pp.SUB, TILE // pp.SUB
+    valid = (jax.lax.broadcasted_iota(jnp.int32, (K, SUB), 0) * SUB
+             + jax.lax.broadcasted_iota(jnp.int32, (K, SUB), 1)
+             ) < sc_ref[1] - j * TILE
+
+    def digest(x):
+        x = x.astype(jnp.float32)
+        x = x.reshape(-1, x.shape[-1])
+        rows = sum(x[r:r + 8] for r in range(0, x.shape[0], 8))
+        return sum(rows[:, c:c + 128] for c in range(0, x.shape[1], 128))
+
+    group = in_buf[slot, pl.ds(pl.multiple_of(sc_ref[7], 16), 16), :]
+    col = pp._split_column(group, sc_ref[8], K)
+    if stage == "column":
+        return digest(col)
+    on = pp._go_left(col, mask_ref) ^ sc_ref[5]
+    predA = jnp.where(valid, on, 0)
+    predB = jnp.where(valid, 1 - on, 0)
+    if stage == "lookup":
+        return digest(predA - predB)
+    pred2 = jnp.concatenate([predA, predB], axis=0)
+    pref2 = pp._prefix_scan_lanes(pred2)
+    pos = pp._sort_pos(pref2, pred2, K)
+    if stage == "scan":
+        return digest(pos)
+    if stage == "pbuild":
+        for k in range(K):
+            sink[:] = sink[:] + pltpu.bitcast(pp._sort_P(pos, k), jnp.int32)
+        return digest(pos)
+    block = in_buf[slot]
+    comps = [jax.lax.dot_general(block[:, k * SUB:(k + 1) * SUB],
+                                 pp._sort_P(pos, k), pp._SORT_DIMS,
+                                 preferred_element_type=jnp.float32)
+             for k in range(K)]
+    if stage == "matmul":
+        return digest(sum(comps))
+    lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
+    chunksA = [jnp.where(lane_s < pref2[k, SUB - 1], comps[k],
+                         jnp.float32(0.0)) for k in range(K)]
+    chunksB = [comps[k] - chunksA[k] for k in range(K)]
+    return digest(sum(chunksA) - sum(chunksB))
+
+
+def _kernel(sc_ref, mask_ref, arena_any, out_any, cnt_ref, in_buf, sink,
+            read_sems, *, tile: int, stage: str):
+    """`dma`: the shipped kernel's read pipeline alone.  Every other
+    stage: two tiles read once, then the stage loop over the resident
+    tiles; nothing is written back.  sc_ref and mask_ref are the shipped
+    kernel's."""
     s, cnt = sc_ref[0], sc_ref[1]
-    xr = sc_ref[5]
     n_tiles = jax.lax.div(cnt + jnp.int32(tile - 1), jnp.int32(tile))
-    K = tile // SUB
 
     def read_dma(j, slot):
         src = pl.multiple_of(s + j * tile, 128)
@@ -48,63 +116,50 @@ def _kernel(sc_ref, feat_onehot_ref, mask_ref, arena_any, out_any, cnt_ref,
             arena_any.at[:, pl.ds(src, tile)], in_buf.at[slot],
             read_sems.at[slot])
 
-    @pl.when(n_tiles > 0)
-    def _():
-        read_dma(0, 0).start()
-        read_dma(0, 0).wait()
-
-    def after_read(block, valid):
-        if stage == "dma":
-            return jnp.sum(block[0:1, 0:1].astype(jnp.float32))
-        on = pp._decide(block, feat_onehot_ref, mask_ref, xr) > 0.5
-        predA = jnp.where(valid & on, jnp.float32(1.0), jnp.float32(0.0))
-        predB = jnp.where(valid & ~on, jnp.float32(1.0), jnp.float32(0.0))
-        if stage == "decide":
-            return jnp.sum(predA)
-        pred2 = jnp.concatenate(
-            [predA.reshape(K, SUB), predB.reshape(K, SUB)], axis=0)
-        pref2 = pp._prefix_scan_lanes(pred2)
-        if stage == "scan":
-            return pref2[0, 0]
-        P_all = pp._sort_P(pref2, pred2, K)
-        if stage == "pbuild":
-            return jnp.sum(P_all[0, 0:1, 0:1].astype(jnp.float32))
-        comps = [jax.lax.dot(block[:, k * SUB:(k + 1) * SUB], P_all[k],
-                             preferred_element_type=jnp.float32)
-                 for k in range(K)]
-        if stage == "matmul":
-            return sum(c[0, 0] for c in comps)
-        cnt2 = pref2[:, SUB - 1].astype(jnp.int32)
-        lane_s = jax.lax.broadcasted_iota(jnp.int32, (1, SUB), 1)
-        chunksA = [jnp.where(lane_s < cnt2[k], comps[k], jnp.float32(0.0))
-                   for k in range(K)]
-        chunksB = [comps[k] - chunksA[k] for k in range(K)]
-        return jnp.sum(sum(chunksA) - sum(chunksB))
-
-    def loop(j, chk):
-        slot = jax.lax.rem(j, jnp.int32(2))
-        nslot = jax.lax.rem(j + jnp.int32(1), jnp.int32(2))
-
-        @pl.when(j + 1 < n_tiles)
+    sink[:] = jnp.zeros_like(sink)
+    zero = jnp.zeros((8, 128), jnp.float32)
+    if stage == "dma":
+        @pl.when(n_tiles > 0)
         def _():
-            read_dma(j + 1, nslot).start()
+            read_dma(0, 0).start()
+            read_dma(0, 0).wait()
 
-        valid = jax.lax.broadcasted_iota(
-            jnp.int32, (1, tile), 1) < (cnt - j * tile)
-        chk = chk + after_read(in_buf[slot], valid)
+        def loop(j, chk):
+            slot = jax.lax.rem(j, jnp.int32(2))
 
-        @pl.when(j + 1 < n_tiles)
-        def _():
-            read_dma(j + 1, nslot).wait()
-        return chk
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                read_dma(j + 1, 1 - slot).start()
+            chk = chk + in_buf[slot, 0:8, 0:128].astype(jnp.float32)
 
-    chk = jax.lax.fori_loop(0, n_tiles, loop, jnp.float32(0.0))
-    cnt_ref[0] = chk.astype(jnp.int32)
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                read_dma(j + 1, 1 - slot).wait()
+            return chk
+    else:
+        for t in range(2):
+            read_dma(t, t).start()
+            read_dma(t, t).wait()
+
+        def loop(j, chk):
+            slot = jax.lax.rem(j, jnp.int32(2))
+            if stage == "loop":
+                return chk + in_buf[slot, 0:8, 0:128].astype(jnp.float32)
+            return chk + tile_body(stage, in_buf, slot, j, sc_ref,
+                                   mask_ref, sink)
+
+    chk = jax.lax.fori_loop(0, n_tiles, loop, zero)
+    cnt_ref[0] = (jnp.sum(chk) + jnp.sum(sink[:].astype(jnp.float32))
+                  ).astype(jnp.int32)
     cnt_ref[1] = jnp.int32(0)
 
 
-@functools.partial(jax.jit, static_argnames=("stage", "n", "reps"))
+@functools.partial(jax.jit, static_argnames=("stage", "n", "reps"),
+                   donate_argnums=(0,))
 def run_stage(arena, decision, *, stage, n, reps):
+    """`reps` passes of one stage over the first n rows; the arena is
+    donated and comes back (aliased through every call: no copy of it is
+    timed)."""
     C, cap = arena.shape
     feat, mask_vec, xr = decision
     dstB = ((n + TILE - 1) // TILE) * TILE + TILE
@@ -115,18 +170,15 @@ def run_stage(arena, decision, *, stage, n, reps):
             lambda i, ar: pp.partition_segment(ar, pred, 0, n, 0, dstB,
                                                decision=decision)[0],
             arena)
-    feat_onehot = (jnp.arange(C, dtype=jnp.int32)[None, :]
-                   == feat).astype(ARENA_DT)
-    mv = jnp.asarray(mask_vec, jnp.float32).reshape(1, -1)
-    goleft = jnp.pad(mv, ((0, 0), (0, 256 - mv.shape[1]))).astype(ARENA_DT)
-    sc = jnp.asarray([0, n, 0, dstB, 1, 0, 0], jnp.int32)
-    kernel = functools.partial(_kernel, C=C, tile=TILE, stage=stage)
+    goleft, group_and_row = pp._decision_operands(feat, mask_vec)
+    sc = jnp.stack([jnp.asarray(v, jnp.int32)
+                    for v in [0, n, 0, dstB, 1, xr, 0] + group_and_row])
+    kernel = functools.partial(_kernel, tile=TILE, stage=stage)
 
     def body(i, ar):
         ar, cnts = pl.pallas_call(
             kernel,
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      pl.BlockSpec(memory_space=pltpu.VMEM),
                       pl.BlockSpec(memory_space=pltpu.VMEM),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=(pl.BlockSpec(memory_space=pl.ANY),
@@ -135,11 +187,12 @@ def run_stage(arena, decision, *, stage, n, reps):
                        jax.ShapeDtypeStruct((2,), jnp.int32)),
             scratch_shapes=[
                 pltpu.VMEM((2, C, TILE), ARENA_DT),
+                pltpu.VMEM((pp.SUB // 2, pp.SUB), jnp.int32),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
-            input_output_aliases={3: 0},
+            input_output_aliases={2: 0},
             compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        )(sc, feat_onehot, goleft, ar)
+        )(sc, goleft, ar)
         return ar
     return jax.lax.fori_loop(0, reps, body, arena)
 
@@ -299,12 +352,7 @@ def run_hist_stage(arena, *, stage, n, F, B, quant):
 
 def hist_main(argv):
     """hist <rows> <features> <max_bin> <q|f32> [reps]"""
-    if jax.default_backend() != "tpu":
-        raise SystemExit(
-            "kernel_ablate hist: backend is %r, not tpu; the stages are "
-            "device times and are not taken in interpret mode (the pieces "
-            "are tested there by tests/test_radix_operand.py)"
-            % jax.default_backend())
+    _require_tpu("hist")
     n, F, B = int(float(argv[0])), int(argv[1]), int(argv[2])
     quant = argv[3] == "q"
     reps = int(argv[4]) if len(argv) > 4 else 5
@@ -338,31 +386,58 @@ def hist_main(argv):
         prev = dt
 
 
+def _require_tpu(what):
+    if jax.default_backend() != "tpu":
+        raise SystemExit(
+            "kernel_ablate %s: backend is %r, not tpu; the stages are device "
+            "times and are not taken in interpret mode (the pieces are "
+            "tested there by tests/test_partition_engine.py and "
+            "tests/test_radix_operand.py)" % (what, jax.default_backend()))
+
+
 def main():
     if len(sys.argv) > 1 and sys.argv[1] == "hist":
         return hist_main(sys.argv[2:])
+    _require_tpu("partition")
     n = int(float(sys.argv[1]) * 1e6) if len(sys.argv) > 1 else 4_000_000
     F = int(sys.argv[2]) if len(sys.argv) > 2 else 28
+    if len(sys.argv) > 3:
+        pp.SUB = pp.FLUSH_W = int(sys.argv[3])
     B = 255
     rng = np.random.default_rng(0)
     C, cap = pp.arena_geometry(n, F)
-    print(f"n={n} C={C} SUB={SUB} TILE={TILE} FLUSH_W={pp.FLUSH_W}")
+    tiles = -(-n // TILE)
+    print(f"partition device={jax.devices()[0].device_kind!r} n={n} C={C} "
+          f"SUB={pp.SUB} TILE={TILE} FLUSH_W={pp.FLUSH_W} tiles={tiles}",
+          flush=True)
     arena = jnp.asarray(
         rng.integers(0, B, size=(C, cap)).astype(np.float32), ARENA_DT)
     float(jnp.sum(arena[:, :1]))
     mask = (jnp.arange(256) < B // 2).astype(jnp.float32)
-    decision = (jnp.int32(0), mask, jnp.int32(0))
+    decision = (jnp.int32(F // 2), mask, jnp.int32(0))
     reps = 10
-    prev = 0.0
-    for stage in STAGES:
-        out = run_stage(arena, decision, stage=stage, n=n, reps=reps)
-        float(jnp.sum(out[:, :1]))
+    times = {}
+    for stage in STAGES + ("dma", "full"):
+        arena = run_stage(arena, decision, stage=stage, n=n, reps=reps)
+        float(jnp.sum(arena[:, :1]))
         t0 = time.time()
-        out = run_stage(arena, decision, stage=stage, n=n, reps=reps)
-        float(jnp.sum(out[:, :1]))
-        dt = (time.time() - t0) / reps * 1000
-        print(f"{stage:8s}: {dt:7.2f} ms/pass (+{dt-prev:6.2f})")
-        prev = dt
+        arena = run_stage(arena, decision, stage=stage, n=n, reps=reps)
+        float(jnp.sum(arena[:, :1]))
+        times[stage] = dt = (time.time() - t0) / reps * 1000
+        if stage in ("loop", "dma", "full"):
+            print(f"{stage:8s}: {dt:7.2f} ms/pass  "
+                  f"{dt * 1e3 / tiles:6.3f} us/tile", flush=True)
+            continue
+        own = dt - times["loop"]
+        more = dt - times[STAGES[STAGES.index(stage) - 1]]
+        print(f"{stage:8s}: {own:7.2f} ms/pass (+{more:6.2f})  "
+              f"{own * 1e3 / tiles:6.3f} us/tile "
+              f"(+{more * 1e3 / tiles:6.3f})", flush=True)
+    rest = times["full"] - (times["chunks"] - times["loop"])
+    print(f"full - chunks: {rest:7.2f} ms/pass  "
+          f"{rest * 1e3 / tiles:6.3f} us/tile (appends, flushes and the part "
+          f"of the {times['dma'] * 1e3 / tiles:.3f} us read the tile body "
+          "does not hide)", flush=True)
 
 
 if __name__ == "__main__":

@@ -333,6 +333,51 @@ def equivalence(lgb, jax, args):
                 max_abs_raw_score_diff=mx)
 
 
+def validation(lgb, jax, args):
+    """A booster with a validation set stays on the fused + carried spine:
+    the iteration's own program scores the validation rows (XLA products,
+    ops/valid_score.py: no Mosaic kernel of its own to lower) and
+    eval_valid() evaluates AUC on the device, equal to metric.py's host
+    code over Booster.predict at every iteration."""
+    from lightgbm_tpu.metric import AUCMetric
+    n, nv, rounds = min(args.rows, 262_144), min(args.rows, 32_768), 6
+    X, y, Xv, yv = higgs_data(n, nv, seed=9)
+    params = dict(higgs_params(True), metric="auc",
+                  tpu_tree_engine="partition")
+    ds = lgb.Dataset(X, y)
+    booster = lgb.Booster(params, ds)
+    booster.add_valid(lgb.Dataset(Xv, yv, reference=ds), "hold")
+    series = []
+    for _ in range(rounds):
+        booster.update()
+        (_, name, value, _), = booster.eval_valid()
+        assert name == "auc" and np.isfinite(value), (name, value)
+        series.append(value)
+    g = booster._gbdt
+    pending = len(g._inflight)
+    assert g._fused_validated and g._carried_active, \
+        "a validation set took the booster off the fused + carried spine"
+    assert g._valid_scoring == "device", g._valid_scoring
+    assert pending == rounds, "eval_valid() drained the model (%d of %d " \
+        "trees still deferred)" % (pending, rounds)
+    host = AUCMetric(booster.config)
+    host.init(g.valid_states[0][1].ds.metadata, nv)
+    worst = 0.0
+    for i, value in enumerate(series):
+        raw = booster.predict(Xv, raw_score=True, num_iteration=i + 1)
+        worst = max(worst, abs(value - host.eval(np.asarray(raw, np.float64),
+                                                 None)[0]))
+    assert worst <= 1e-6, "device AUC series off the host metric by %g" % worst
+    _say("validation", rows=n, valid_rows=nv, spine="fused",
+         valid_scoring=g._valid_scoring, auc_last=series[-1],
+         max_abs_diff_vs_host=worst)
+    return dict(rows=n, valid_rows=nv, spine="fused", carried=True,
+                valid_scoring=g._valid_scoring, iterations=rounds,
+                valid_kernels="none: XLA products (ops/valid_score.py)",
+                observed={"auc_last": round(series[-1], 6),
+                          "max_abs_diff_vs_host": worst})
+
+
 def kernel_coverage(lgb, jax, jnp, args):
     """The configurations that reach the kernels the main path does not:
     real widths, small rows; each asserts the engine it should be on."""
@@ -491,6 +536,7 @@ def main(argv=None):
     predict_out = device_predict(booster, Xh, yh, full)
     del booster, Xh, yh
     equiv_out = equivalence(lgb, jax, args)
+    valid_out = validation(lgb, jax, args)
     configs = kernel_coverage(lgb, jax, jnp, args)
 
     kernels = sorted({name for name, _v in seen})
@@ -515,6 +561,7 @@ def main(argv=None):
     _report(device, dict(
         versions=versions, full_size=bool(full),
         main=main_out, device_predict=predict_out, equivalence=equiv_out,
+        validation=valid_out,
         kernel_configs=configs, kernels_compiled=kernels,
         kernels_interpret=False,
         observed={"total_s": round(total_s, 1)}))

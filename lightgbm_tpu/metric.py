@@ -25,9 +25,11 @@ class Metric:
         self.weights: Optional[np.ndarray] = None
         self.sum_weights = 0.0
         self.metadata = None
+        self._on_device = None      # (label > 0, weights) once uploaded
 
     def init(self, metadata, num_data: int) -> None:
         self.metadata = metadata
+        self._on_device = None
         self.label = np.asarray(metadata.label, np.float64)
         self.weights = (np.asarray(metadata.weights, np.float64)
                         if metadata.weights is not None else None)
@@ -36,6 +38,30 @@ class Metric:
 
     def eval(self, score: np.ndarray, objective=None) -> List[float]:
         raise NotImplementedError
+
+    def eval_device(self, score, objective=None):
+        """The metric's sums over the DEVICE score ([n], or the booster's
+        [1, n] as it is: no slicing program before the metric's), as a
+        small device array for `finish_device`; or None where this metric
+        (or this objective) has no device evaluation: GBDT._eval_state
+        then feeds `eval` a fetched score vector.  The class decides, no
+        option."""
+        return None
+
+    def finish_device(self, sums: np.ndarray) -> List[float]:
+        """What `eval` returns, from the fetched result of `eval_device`."""
+        raise NotImplementedError
+
+    def _device_rows(self):
+        """(label > 0 [n] bool, weights [n] float32 or None) on the
+        device, uploaded at the first device evaluation."""
+        if self._on_device is None:
+            import jax.numpy as jnp
+            self._on_device = (
+                jnp.asarray(self.label > 0),
+                None if self.weights is None
+                else jnp.asarray(self.weights, jnp.float32))
+        return self._on_device
 
     def _avg(self, losses: np.ndarray) -> float:
         if self.weights is not None:
@@ -165,6 +191,19 @@ class BinaryLoglossMetric(_PointwiseMetric):
         prob = np.clip(prob, eps, 1 - eps)
         return np.where(label > 0, -np.log(prob), -np.log(1.0 - prob))
 
+    def eval_device(self, score, objective=None):
+        # the loss from the raw score, log(1 + exp(-+ sigmoid * score)):
+        # the probability's own float32 rounding near 0 and 1 never enters
+        if getattr(objective, "name", None) != "binary":
+            return None
+        from .ops import metric_device
+        pos, w = self._device_rows()
+        return metric_device.logloss_sum(score, pos, w,
+                                         float(objective.sigmoid))
+
+    def finish_device(self, sums):
+        return [float(sums) / self.sum_weights]
+
 
 class BinaryErrorMetric(_PointwiseMetric):
     name = "binary_error"
@@ -197,15 +236,41 @@ class AUCMetric(Metric):
         lo_w = cumw[starts[grp_id]]
         hi_w = cumw[ends[grp_id]]
         avg_rank_w = (lo_w + hi_w) / 2.0
-        sum_pos_rank = float((avg_rank_w * ww * lab).sum())
         sum_pos = float((ww * lab).sum())
-        sum_all = float(ww.sum())
-        sum_neg = sum_all - sum_pos
+        return self._from_sums(float((avg_rank_w * ww * lab).sum()),
+                               sum_pos, float(ww.sum()) - sum_pos)
+
+    @staticmethod
+    def _from_sums(sum_pos_rank, sum_pos, sum_neg):
         if sum_pos <= 0 or sum_neg <= 0:
             log.warning("AUC is undefined with only one class; returning 0.5")
             return [0.5]
-        auc = (sum_pos_rank - sum_pos * sum_pos / 2.0) / (sum_pos * sum_neg)
-        return [auc]
+        return [(sum_pos_rank - sum_pos * sum_pos / 2.0)
+                / (sum_pos * sum_neg)]
+
+    # device evaluation: rows sorted by score once; a row's tie-averaged
+    # rank from the first and last index of its run of equal scores.
+    # Without weights everything is an integer and the result is the
+    # exact AUC of the scores the device holds; with weights the sums are
+    # float32 (float64 where the scores are)
+    _EXACT_ROWS = 1 << 24     # four 8-bit limbs of a rank sum fit uint32
+
+    def eval_device(self, score, objective=None):
+        pos, w = self._device_rows()
+        from .ops import metric_device
+        if w is None and len(self.label) <= self._EXACT_ROWS:
+            return metric_device.auc_counts(score, pos)
+        return metric_device.auc_weighted(score, pos, w)
+
+    def finish_device(self, sums):
+        if sums.dtype.kind == "u":
+            *limbs, n_pos = (int(v) for v in sums)
+            # twice the positives' tie-averaged rank sum, limb by limb
+            twice_rank = sum(v << (8 * j) for j, v in enumerate(limbs))
+            return self._from_sums(twice_rank / 2.0, float(n_pos),
+                                   float(len(self.label) - n_pos))
+        sum_pos_rank, sum_pos, sum_all = (float(v) for v in sums)
+        return self._from_sums(sum_pos_rank, sum_pos, sum_all - sum_pos)
 
 
 # --- factory (metric.cpp:11-56) -------------------------------------------- #

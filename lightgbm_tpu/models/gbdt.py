@@ -76,6 +76,19 @@ class _DatasetState:
         self._score_thunk = None
         self._score_written = False
         self.bundle = _bundle_maps(ds)
+        self._bins_t = None
+
+    def feature_major_bins(self):
+        """[G, rows in whole blocks] feature-major bins in the arena's
+        type, what ops/valid_score.py reads; made at the first fused
+        iteration that scores this (validation) set."""
+        if self._bins_t is None:
+            from ..ops import partition_pallas as _pp
+            from ..ops import valid_score as _vs
+            n = self.ds.num_data
+            self._bins_t = jnp.pad(_pp.feature_major(self.bins),
+                                   ((0, 0), (0, _vs.padded_rows(n) - n)))
+        return self._bins_t
 
     @property
     def score(self):
@@ -159,6 +172,11 @@ class GBDT:
         # deferred-tree pipeline state (train_one_iter/_drain_inflight);
         # subclasses that need host trees within the iteration opt out
         self._allow_deferred = True
+        # where the validation scores were updated by the last iteration
+        # that had any: "device" inside the fused iteration, from the
+        # device tree (ops/valid_score.py); "host" by the unfused spine,
+        # from a fetched host tree (_update_valid_scores)
+        self._valid_scoring = "host"
         self._inflight: List[dict] = []
         self._deferred_stopped = False
         # per-phase timers (TIMETAG analogue); sync_fn charges async
@@ -401,19 +419,32 @@ class GBDT:
     def add_valid(self, name: str, valid_set: BinnedDataset,
                   metrics: Sequence[Metric]) -> None:
         self._sync_model()
-        state = _DatasetState(valid_set, self.num_tree_per_iteration, self.dtype)
-        if valid_set.metadata.init_score is not None:
-            init = _expand_init_score(valid_set.metadata.init_score,
-                                      self.num_tree_per_iteration,
-                                      valid_set.num_data)
-            state.score = state.score + jnp.asarray(init, self.dtype)
         for m in metrics:
             m.init(valid_set.metadata, valid_set.num_data)
-        # replay existing model onto the new validation scores
-        for it in range(len(self.models) // self.num_tree_per_iteration):
-            for k in range(self.num_tree_per_iteration):
-                tree = self.models[it * self.num_tree_per_iteration + k]
-                _add_tree_score(state, tree, k, self)
+        # the plan again, now with what is validated: the sets, their rows
+        # and the metrics whose class evaluates on the device (the others
+        # are fed a fetched score vector, _eval_state)
+        rows = [st.ds.num_data for _n, st, _m in self.valid_states]
+        rows.append(valid_set.num_data)
+        plan = dict(
+            getattr(self, "_engine_plan", None) or {},
+            valid_sets=len(rows), valid_rows=",".join(map(str, rows)),
+            device_metrics=",".join(
+                m.name for m in metrics
+                if type(m).eval_device is not Metric.eval_device))
+        with obs_tracing.span("engine_plan", "setup", **plan):
+            state = _DatasetState(valid_set, self.num_tree_per_iteration,
+                                  self.dtype)
+            if valid_set.metadata.init_score is not None:
+                init = _expand_init_score(valid_set.metadata.init_score,
+                                          self.num_tree_per_iteration,
+                                          valid_set.num_data)
+                state.score = state.score + jnp.asarray(init, self.dtype)
+            # replay existing model onto the new validation scores
+            for it in range(len(self.models) // self.num_tree_per_iteration):
+                for k in range(self.num_tree_per_iteration):
+                    tree = self.models[it * self.num_tree_per_iteration + k]
+                    _add_tree_score(state, tree, k, self)
         self.valid_states.append((name, state, list(metrics)))
 
     # ------------------------------------------------------------------ #
@@ -511,16 +542,23 @@ class GBDT:
             for kk in range(k):
                 init_scores[kk] = self._boost_from_average(kk)
         # deferred (pipelined) tree materialization: only when nothing needs
-        # the host tree inside this iteration
-        deferred_ok = (self._allow_deferred and not self.valid_states
+        # the host tree inside this iteration.  A validation set does not:
+        # the fused iteration scores it from the device tree.  (Training
+        # metrics do: on the carried spine the training score is a sort
+        # over all rows away.)
+        deferred_ok = (self._allow_deferred
                        and not self.train_metrics
                        and self._cegb_coupled is None
                        and (self.objective is None
                             or not self.objective.is_renew_tree_output()))
+        # the unfused spine scores validation sets from the host tree
+        # (_update_valid_scores), so there a validation set still keeps
+        # the tree in the iteration
+        defer_unfused = deferred_ok and not self.valid_states
         # the partition engine can then fuse the score update into its
         # label-recovery scatter (emit="score"), skipping the per-row
         # leaf-value gather entirely (serial-gather cost on TPU)
-        self._score_emit_ok = deferred_ok
+        self._score_emit_ok = defer_unfused
 
         # single-dispatch fast path: gradients + tree + score update fused
         no_bagging = (self.config.bagging_freq <= 0
@@ -551,6 +589,8 @@ class GBDT:
                     packed_per_class = self._run_fused_iter_carried()
                 else:
                     packed_per_class = self._run_fused_iter()
+            if self.valid_states:
+                self._valid_scoring = "device"
             for packed in packed_per_class:
                 for p in packed:
                     p.copy_to_host_async()
@@ -595,7 +635,7 @@ class GBDT:
                 with self.profiler.phase("tree_grow"):
                     arrays, leaf_ids = self._grow_one_tree(grad[kk], hess[kk],
                                                            row_init)
-                if deferred_ok:
+                if defer_unfused:
                     packed = self._pack_tree_with_flag(arrays)
                     for p in packed:
                         p.copy_to_host_async()
@@ -635,6 +675,8 @@ class GBDT:
                 with self.profiler.phase("score_update"):
                     self._update_train_score(new_tree, kk, arrays, leaf_ids)
                     self._update_valid_scores(new_tree, kk)
+                    if self.valid_states:
+                        self._valid_scoring = "host"
                 if abs(init_scores[kk]) > K_EPSILON:
                     new_tree.add_bias(init_scores[kk])
             else:
@@ -695,6 +737,7 @@ class GBDT:
     def _build_fused_iter(self):
         from ..ops import grow_partition as gp
         from ..ops import quantize as qz
+        from ..ops import valid_score
         objective = self.objective
         interpret = pallas_interpret()
         k = max(self.num_tree_per_iteration, 1)
@@ -704,11 +747,14 @@ class GBDT:
 
         def fused(arena, bins_t, score, field_vals, row0, fmasks,
                   num_bins, default_bins, missing_types, sparams, monotone,
-                  penalty, bundle, shrink, qkey):
+                  penalty, bundle, shrink, qkey, vscores, vbins, vbundles):
             # score is [k, n]; gradients come back class-major and every
             # class's tree grows in the SAME program, reusing the one
             # donated arena; each class gets its own feature mask (the
-            # eager path samples per tree)
+            # eager path samples per tree).  vscores / vbins / vbundles:
+            # one entry per validation set (_valid_args), empty lists for
+            # a booster without one, which then traces the same program
+            # as before validation was scored here
             olds = [getattr(h, a) for h, a in fields]
             for (h, a), v in zip(fields, field_vals):
                 setattr(h, a, v)
@@ -751,11 +797,14 @@ class GBDT:
                         [ivec, trunc.astype(jnp.int32)[None]]))
                 fvecs.append(fvec)
                 deltas.append(delta.astype(score.dtype))
+                vscores = valid_score.add_tree(
+                    vscores, kk, vbins, arrays, shrink, num_bins,
+                    default_bins, vbundles)
             with jax.named_scope("lgbm.score"):
                 new_score = score + shrink * jnp.stack(deltas)
-            return ivecs, fvecs, new_score, arena
+            return ivecs, fvecs, new_score, arena, vscores
 
-        return jax.jit(fused, donate_argnums=(0, 2))
+        return jax.jit(fused, donate_argnums=(0, 2, 15))
 
     def _run_fused_iter(self):
         """One fused iteration; returns per-class packed (ivec, fvec)
@@ -783,7 +832,7 @@ class GBDT:
                 self.train_state.num_bins, self.train_state.default_bins,
                 self.train_state.missing_types, self.split_params,
                 self.monotone, self.penalty, self.train_state.bundle, sh,
-                qkey)
+                qkey) + self._valid_args()
         if rebuilt and getattr(self, "_tracing", False) \
                 and getattr(self.config, "tpu_trace_xla_analysis", True):
             # kernel attribution: one "compile" span per retrace carrying
@@ -801,7 +850,8 @@ class GBDT:
                 signature="leaves=%d depth=%d bin=%d cat=%d rows=%d" % (
                     key + (self.num_data,)),
                 donation_resident=(1, *range(3, 4 + n_field)))
-        ivecs, fvecs, new_score, arena = self._fused_fn(*args)
+        ivecs, fvecs, new_score, arena, vscores = self._fused_fn(*args)
+        self._store_valid_scores(vscores)
         if not getattr(self, "_fused_validated", False):
             # force materialization once so a device runtime fault raises
             # HERE, at the dispatch that caused it, instead of at a later
@@ -813,6 +863,19 @@ class GBDT:
         self.train_state.score = new_score
         self._last_truncated = jnp.asarray(False)   # flag rides ivec[-1]
         return list(zip(ivecs, fvecs))
+
+    def _valid_args(self):
+        """(scores, feature-major bins, bundle maps) of the validation
+        sets, one list entry per set: the fused iteration's last three
+        arguments.  The scores are donated."""
+        states = [st for _n, st, _m in self.valid_states]
+        return ([st.score for st in states],
+                [st.feature_major_bins() for st in states],
+                [st.bundle for st in states])
+
+    def _store_valid_scores(self, vscores) -> None:
+        for (_n, st, _m), sc in zip(self.valid_states, vscores):
+            st.score = sc
 
     # ---- carried-arena fast path -----------------------------------------
     # Scores and the objective's per-row constants ride the arena as
@@ -893,6 +956,7 @@ class GBDT:
         from ..ops import grow_partition as gp
         from ..ops import partition_pallas as _pp
         from ..ops import quantize as qz
+        from ..ops import valid_score
         objective = self.objective
         quantized = getattr(self, "_quantized", False)
         interpret = pallas_interpret()
@@ -911,7 +975,8 @@ class GBDT:
 
         def fused(arena, bins_t, root0, dst, field_vals, row0, fmask,
                   num_bins, default_bins, missing_types, sparams,
-                  monotone, penalty, bundle, shrink, qkey):
+                  monotone, penalty, bundle, shrink, qkey,
+                  vscores, vbins, vbundles):
             olds = [getattr(h, a) for h, a in fields_io]
             for (h, a), v in zip(fields_io, field_vals):
                 setattr(h, a, v)
@@ -971,9 +1036,12 @@ class GBDT:
                 ivec, fvec = grow_ops.pack_tree_arrays(arrays)
                 ivec = jnp.concatenate(
                     [ivec, trunc.astype(jnp.int32)[None]])
-            return ivec, fvec, arena
+            vscores = valid_score.add_tree(
+                vscores, 0, vbins, arrays, shrink, num_bins, default_bins,
+                vbundles)
+            return ivec, fvec, arena, vscores
 
-        return jax.jit(fused, donate_argnums=(0,))
+        return jax.jit(fused, donate_argnums=(0, 16))
 
     def _run_fused_iter_carried(self):
         key = (self.config.num_leaves, self.config.max_depth, self.max_bin,
@@ -990,12 +1058,14 @@ class GBDT:
         dst = jnp.int32(self._carry_slots[1 - p])
         from ..ops import quantize as _qz
         qkey = _qz.quantize_key(getattr(self, "_quant_seed", 0), self.iter)
-        ivec, fvec, arena = self._carried_fn(
+        ivec, fvec, arena, vscores = self._carried_fn(
             self._arena, self._bins_t, root0, dst, field_vals,
             self._row_all_in, fmask,
             self.train_state.num_bins, self.train_state.default_bins,
             self.train_state.missing_types, self.split_params,
-            self.monotone, self.penalty, self.train_state.bundle, sh, qkey)
+            self.monotone, self.penalty, self.train_state.bundle, sh, qkey,
+            *self._valid_args())
+        self._store_valid_scores(vscores)
         if not getattr(self, "_fused_validated", False):
             with obs_scaling.exempt():   # one-shot fault-surfacing sync
                 int(ivec[-1])
@@ -1141,8 +1211,10 @@ class GBDT:
                     # degenerate FIRST iteration keeps the boost-from-average
                     # prior as a constant tree, like the eager else-branch
                     new_tree.as_constant(ent["init_score"])
-                    self.train_state.add_constant(ent["init_score"],
-                                                  ent["slot"] % max(k, 1))
+                    for st in [self.train_state] + [
+                            vs for _n, vs, _m in self.valid_states]:
+                        st.add_constant(ent["init_score"],
+                                        ent["slot"] % max(k, 1))
                 self.models[ent["slot"]] = new_tree
             if not any_grew:
                 log.warning("Stopped training because there are no more "
@@ -1648,20 +1720,34 @@ class GBDT:
         return self._eval_state(self.train_state, self.train_metrics)
 
     def eval_valid(self) -> Dict[str, Dict[str, List[float]]]:
-        self._sync_model()
-        return {name: self._eval_state(vs, metrics)
-                for name, vs, metrics in self.valid_states}
+        # no _sync_model: the validation scores are on the device whichever
+        # spine updated them, and a metric reads nothing of self.models
+        with obs_tracing.span("eval_valid", "eval"):
+            return {name: self._eval_state(vs, metrics)
+                    for name, vs, metrics in self.valid_states}
 
     def _eval_state(self, state: _DatasetState, metrics) -> Dict[str, List[float]]:
-        out = {}
+        """A metric whose class evaluates on the device (metric.py
+        `eval_device`) hands back a few sums; every other metric is fed
+        the score vector, fetched once for all of them and only if one
+        asks.  One blocking read brings both."""
         if not metrics:
-            return out
-        with self.profiler.phase("metric_eval(fetch)"):
-            score = np.asarray(state.score, np.float64)
-        flat = score.reshape(-1) if self.num_tree_per_iteration > 1 else score[0]
-        for m in metrics:
-            out[m.name] = m.eval(flat, self.objective)
-        return out
+            return {}
+        sums = [m.eval_device(state.score, self.objective)
+                if self.num_tree_per_iteration == 1 else None
+                for m in metrics]
+        host_fed = any(s is None for s in sums)
+        with obs_tracing.span("valid/metric_fetch", "eval"), \
+                self.profiler.phase("metric_eval(fetch)"):
+            sums, score = jax.device_get(
+                (sums, state.score if host_fed else None))
+        if host_fed:
+            score = np.asarray(score, np.float64)
+            flat = (score.reshape(-1) if self.num_tree_per_iteration > 1
+                    else score[0])
+        return {m.name: (m.eval(flat, self.objective) if s is None
+                         else m.finish_device(s))
+                for m, s in zip(metrics, sums)}
 
     # ------------------------------------------------------------------ #
     # Prediction on raw features (gbdt_prediction.cpp)

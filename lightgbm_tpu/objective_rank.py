@@ -4,7 +4,10 @@ Re-design of src/objective/rank_objective.hpp:19-237 (LambdarankNDCG): the
 reference's per-query O(n^2) pairwise OMP loop runs fully on device as
 padded size-bucketed query blocks (ops/ranking.py DeviceLambdarank) — a
 handful of jitted dispatches per iteration regardless of query count.
-The numpy per-query path (`_one_query`) is kept as the parity oracle.
+The device path never sorts: it sums the pairs in slot order and gets each
+document's rank discount from a count over its query.  The numpy per-query
+path (`_one_query`), which sorts as the reference does, is kept as the
+parity oracle.
 
 The 1M-entry sigmoid lookup table (rank_objective.hpp:181-194) is replaced
 by the exact expression it approximates: GetSigmoid(d) = 2/(1+exp(2*sigmoid*d)).

@@ -1,16 +1,16 @@
 """Device ranking ops (ops/ranking.py) vs the numpy per-query oracles."""
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.metadata import Metadata
 from lightgbm_tpu.metric_rank import NDCGMetric
 from lightgbm_tpu.objective_rank import LambdarankNDCG
-
-# interpret-mode Pallas dominates these — excluded from the
-# fast tier (pytest -m 'not slow'); run the full suite before
-# committing engine changes
-import pytest  # noqa: E402
-pytestmark = pytest.mark.slow
+from lightgbm_tpu.ops import ranking
 
 
 def _rank_data(rng, num_queries=60, max_docs=40):
@@ -104,9 +104,8 @@ def test_ndcg_empty_query_counts_as_one(rng):
 def test_lambdarank_f32_path_matches_f64_oracle(rng):
     """The shipped production default runs the device kernels in f32
     (jax_enable_x64 off); the harness forces x64, so this test disables
-    it to exercise the f32 score-sort tie-breaking and pair sums against
-    the f64 host oracle under a loosened tolerance."""
-    import jax
+    it to exercise the f32 tie-breaking and pair sums against the f64
+    host oracle under a loosened tolerance."""
     meta, n, _ = _rank_data(rng, num_queries=30)
     obj = LambdarankNDCG(Config({"objective": "lambdarank"}))
     obj.init(meta, n)
@@ -126,7 +125,6 @@ def test_lambdarank_f32_path_matches_f64_oracle(rng):
 
 
 def test_ndcg_f32_path_matches_f64_oracle(rng):
-    import jax
     meta, n, _ = _rank_data(rng, num_queries=30)
     m = NDCGMetric(Config({"metric": "ndcg", "eval_at": [5]}))
     m.init(meta, n)
@@ -140,3 +138,140 @@ def test_ndcg_f32_path_matches_f64_oracle(rng):
         jax.config.update("jax_enable_x64", prev)
     np.testing.assert_allclose(dev, m.eval_host(score), rtol=2e-4,
                                atol=2e-5)
+
+
+# ---- the slot-order pair sums: ties, signed zeros, padding ---------------
+CASES = ("all_equal", "partial_ties", "signed_zeros", "single_doc",
+         "equal_labels", "mostly_padding")
+LAYOUTS = {"S8": 8, "S128": 128}
+
+
+def _case_rows(case, S, rng, num_queries=11):
+    """(scores, labels, counts) of one padded bucket: [Q, S] float64 and
+    [Q]; slots past a query's count are padding."""
+    cnt = rng.randint(2, S + 1, num_queries)
+    if case == "mostly_padding":
+        cnt = rng.randint(1, max(S // 4, 3) + 1, num_queries)
+    if case == "single_doc":
+        cnt[[0, 3]] = 1
+    score = rng.randn(num_queries, S)
+    if case == "all_equal":
+        score[:] = 0.0          # the first iteration: every rank is a tie
+    elif case == "partial_ties":
+        score = np.round(score * 2.0) / 2.0
+    elif case == "signed_zeros":
+        score = rng.choice([0.0, -0.0, 0.5], size=score.shape)
+    label = rng.randint(0, 5, (num_queries, S)).astype(np.float64)
+    if case == "equal_labels":  # no valid pair; a row of zeros has no DCG
+        label[:] = rng.randint(0, 5, (num_queries, 1))
+    return score, label, cnt
+
+
+def _padded(score, label, cnt, obj, dtype):
+    """The arguments DeviceLambdarank hands _lambda_bucket, from rows."""
+    S = score.shape[1]
+    real = np.arange(S)[None, :] < cnt[:, None]
+    gains = np.where(real, obj.dcg.label_gain_np[label.astype(np.int64)], 0.0)
+    inv = np.zeros(len(cnt))
+    for q, c in enumerate(cnt):
+        mdcg = obj.dcg.cal_maxdcg_at_k(obj.optimize_pos_at, label[q, :c])
+        inv[q] = 1.0 / mdcg if mdcg > 0.0 else 0.0
+    return (jnp.asarray(np.where(real, score, -np.inf), dtype),
+            jnp.asarray(np.where(real, label, -1.0), dtype),
+            jnp.asarray(gains, dtype), jnp.asarray(real),
+            jnp.asarray(inv, dtype),
+            jnp.asarray(1.0 / np.log2(2.0 + np.arange(S)), dtype),
+            jnp.asarray(obj.sigmoid, dtype)), inv
+
+
+TOL = {"float64": dict(rtol=1e-7, atol=1e-9),
+       "float32": dict(rtol=2e-3, atol=2e-4)}
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+@pytest.mark.parametrize("layout", ["S8", "S128", "ragged"])
+@pytest.mark.parametrize("case", CASES)
+def test_lambda_sums_in_slot_order_match_oracle(case, layout, dtype, rng):
+    """Against the float64 per-query oracle (which sorts): one bucket
+    straight through _lambda_bucket at S = 8 and 128 (the chunk does not
+    divide the queries: whole padded queries too), and a ragged set
+    through DeviceLambdarank's two buckets."""
+    obj = LambdarankNDCG(Config({"objective": "lambdarank"}))
+    if layout == "ragged":
+        score_s, label_s, cnt_s = _case_rows(case, 8, rng)
+        score_l, label_l, cnt_l = _case_rows(case, 32, rng)
+        cnt_l = np.maximum(cnt_l, 17)           # stays in the S = 32 bucket
+        rows = [(score_s[q, :c], label_s[q, :c]) for q, c in enumerate(cnt_s)]
+        rows += [(score_l[q, :c], label_l[q, :c]) for q, c in enumerate(cnt_l)]
+        rows = [rows[i] for i in rng.permutation(len(rows))]
+        score = np.concatenate([r[0] for r in rows])
+        label = np.concatenate([r[1] for r in rows])
+        meta = Metadata(len(score))
+        meta.set_label(label)
+        meta.set_query(np.array([len(r[0]) for r in rows]))
+        obj.init(meta, len(score))
+        dev = ranking.DeviceLambdarank(
+            obj.query_boundaries, obj.label_np, obj.dcg.label_gain_np,
+            obj.inverse_max_dcgs, obj.sigmoid, dtype=jnp.dtype(dtype))
+        assert len(dev._buckets) == 2
+        got = [np.asarray(a, np.float64) for a in dev(score)]
+        want = obj.get_gradients_host(score)
+    else:
+        score, label, cnt = _case_rows(case, LAYOUTS[layout], rng)
+        args, inv = _padded(score, label, cnt, obj, jnp.dtype(dtype))
+        lam, hes = ranking._lambda_bucket(*args, chunk=4)
+        got = [np.concatenate([np.asarray(a, np.float64)[q, :c]
+                               for q, c in enumerate(cnt)])
+               for a in (lam, hes)]
+        pad = [np.asarray(a)[np.arange(score.shape[1])[None, :]
+                             >= cnt[:, None]] for a in (lam, hes)]
+        assert all(np.all(p == 0.0) for p in pad), "padding gets no lambda"
+        per_query = [obj._one_query(score[q, :c], label[q, :c], inv[q])
+                     for q, c in enumerate(cnt)]
+        want = [np.concatenate([g[k] for g in per_query]) for k in (0, 1)]
+    assert np.all(np.isfinite(got[0])) and np.all(np.isfinite(got[1]))
+    if case == "equal_labels":
+        assert not got[0].any() and not got[1].any()
+    np.testing.assert_allclose(got[0], want[0], **TOL[dtype])
+    np.testing.assert_allclose(got[1], want[1], **TOL[dtype])
+
+
+@pytest.mark.parametrize("S", sorted(LAYOUTS.values()))
+@pytest.mark.parametrize("case", CASES)
+def test_counted_rank_is_the_stable_sorts_position(case, S, rng):
+    score, _, cnt = _case_rows(case, S, rng)
+    real = np.arange(S)[None, :] < cnt[:, None]
+    neg = jnp.asarray(np.where(real, score, -np.inf), jnp.float32)
+    want = jnp.argsort(jnp.argsort(-neg, axis=1, stable=True), axis=1)
+    got = ranking._slot_rank(neg)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+_MOVES = re.compile(r"stablehlo\.(?:dynamic_)?(gather|sort|scatter)\b")
+
+
+def _chunk_args(Q=256, S=128):
+    """One MSLR chunk's arguments, as shapes."""
+    def f(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    return (f((Q, S)), f((Q, S)), f((Q, S)), f((Q, S), jnp.bool_),
+            f((Q,)), f((S,)), f(()))
+
+
+def test_lowered_pair_sums_move_no_element_by_index():
+    """What slot order buys: the lowered function holds no gather, sort or
+    scatter (on the chip each costs 7-10 ns an element, PERF.md §7)."""
+    text = ranking._lambda_bucket.lower(
+        *_chunk_args(), chunk=256).as_text(debug_info=True)
+    assert "lgbm.gradient.pairs" in text
+    assert "stablehlo.while" in text          # the chunk loop is there
+    assert _MOVES.findall(text) == []
+
+    # the same reading finds them in the form this replaced
+    def sort_and_permute(s):
+        order = jnp.argsort(-s, axis=1, stable=True)
+        return jnp.take_along_axis(s, order, axis=1)
+
+    old = jax.jit(sort_and_permute).lower(_chunk_args()[0]).as_text()
+    assert set(_MOVES.findall(old)) == {"sort", "gather"}

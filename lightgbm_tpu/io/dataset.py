@@ -383,11 +383,9 @@ class BinnedDataset:
                     conflicts=self.bundle.conflicts)
                 log.info("EFB bundled %d features into %d groups",
                          F, self.bundle.num_groups)
-            # what the search found rides the recorded span (an armed
-            # tracer); the profiler's annotation holds what was known at
-            # the span's start
-            if hasattr(sp_, "set"):
-                sp_.set(**found)
+            # what the search found rides the span with what was known
+            # at its start
+            sp_.set_metadata(**found)
 
     def _set_offsets(self) -> None:
         nb = [m.num_bin for m in self.bin_mappers]

@@ -29,6 +29,7 @@ from ..objective import ObjectiveFunction
 from ..ops import grow as grow_ops
 from ..ops import predict as predict_ops
 from ..ops.split import SplitParams
+from ..obs import device as obs_device
 from ..obs import scaling as obs_scaling
 from ..obs import tracing as obs_tracing
 from ..utils import log
@@ -528,9 +529,8 @@ class GBDT:
         # subsequent pending iteration is degenerate too (zero-valued
         # trees), so the stop point is recovered exactly on drain.
         if len(self._inflight) >= self.num_tree_per_iteration * _DRAIN_EVERY:
-            with self.profiler.phase("drain_inflight"):
-                if self._drain_inflight():
-                    self._deferred_stopped = True
+            if self._drain_inflight():
+                self._deferred_stopped = True
         if self._deferred_stopped:
             return True
 
@@ -663,6 +663,8 @@ class GBDT:
                     self._emit_truncation_warning(int(host_arrays.num_leaves))
                 if int(host_arrays.num_leaves) > 1:
                     new_tree = Tree.from_arrays(host_arrays, self.train_set)
+                self._record_split_ledger(new_tree, self.iter,
+                                          len(self.models))
 
             if new_tree.num_leaves > 1:
                 should_continue = True
@@ -840,7 +842,6 @@ class GBDT:
             # tagged with the shape signature that triggered the rebuild.
             # Must run BEFORE the executing call — arena and score are
             # donated, so their buffers are dead afterwards.
-            from ..obs import device as obs_device
             # resident flattened leaves: bins_t (1), the dataset field
             # planes (3..) and row_all_in — persistent across rounds, so
             # un-donatable by design; arena (0) and score (2) ARE donated
@@ -1175,6 +1176,18 @@ class GBDT:
         self.train_state.score = self.train_state.score.at[class_id].add(
             lv[jnp.clip(lids, 0, arrays.max_leaves - 1)])
 
+    def _record_split_ledger(self, tree: Tree, iteration: int,
+                             slot: int) -> float:
+        """A trained tree has reached the host: its split ledger into the
+        process's ring (obs/device.py).  The one helper of the three
+        sites that materialise a trained tree (the drain, the unfused
+        spine's fetch, rf.py); loading a model comes nowhere near it.
+        Returns the tree's passes over the rows."""
+        partition_rows, histogram_rows = tree.split_ledger()
+        obs_device.record_split_ledger(iteration, slot, self.num_data,
+                                       partition_rows, histogram_rows)
+        return float(partition_rows.sum()) / max(self.num_data, 1)
+
     def _drain_inflight(self) -> bool:
         """Materialize pending deferred trees (possibly several
         iterations' worth).  Returns True when a drained iteration was
@@ -1184,10 +1197,20 @@ class GBDT:
         necessarily degenerate too — the degenerate iteration added zero
         leaf values, so they trained on identical scores — and their
         device score updates were all zero, so scores need no undo."""
-        if not self._inflight:
-            return False
-        pending, self._inflight = self._inflight, []
+        with self.profiler.phase("drain_inflight") as span:
+            if not self._inflight:
+                return False
+            pending, self._inflight = self._inflight, []
+            stopped, row_passes = self._materialize(pending)
+            # tree depth beside the drain, in an operator's trace
+            span.set_metadata(trees=len(pending), row_passes=row_passes)
+            return stopped
+
+    def _materialize(self, pending) -> Tuple[bool, float]:
+        """(a drained iteration was degenerate, the drained trees' passes
+        over the rows) of _drain_inflight."""
         k = self.num_tree_per_iteration
+        row_passes = 0.0
         groups: Dict[int, list] = {}
         for ent in pending:
             groups.setdefault(ent["it"], []).append(ent)
@@ -1215,6 +1238,8 @@ class GBDT:
                             vs for _n, vs, _m in self.valid_states]:
                         st.add_constant(ent["init_score"],
                                         ent["slot"] % max(k, 1))
+                row_passes += self._record_split_ledger(new_tree, it,
+                                                        ent["slot"])
                 self.models[ent["slot"]] = new_tree
             if not any_grew:
                 log.warning("Stopped training because there are no more "
@@ -1231,8 +1256,8 @@ class GBDT:
                 # from the surviving model so post-stop metrics and any
                 # further training see a consistent state
                 self._rebuild_train_score()
-                return True
-        return False
+                return True, row_passes
+        return False, row_passes
 
     def _load_forced_splits(self) -> tuple:
         """forcedsplits_filename JSON -> static BFS plan of
@@ -1710,8 +1735,7 @@ class GBDT:
     def _sync_model(self) -> None:
         """Materialize any deferred trees before the model is read; a stop
         detected here must still end training on the next update."""
-        with obs_tracing.span("sync_model", "train"), \
-                self.profiler.phase("drain_inflight"):
+        with obs_tracing.span("sync_model", "train"):
             if self._drain_inflight():
                 self._deferred_stopped = True
 

@@ -67,6 +67,8 @@ class RF(GBDT):
                 host_arrays = grow_ops.fetch_tree_arrays(arrays)
                 if int(host_arrays.num_leaves) > 1:
                     new_tree = Tree.from_arrays(host_arrays, self.train_set)
+                self._record_split_ledger(new_tree, self.iter,
+                                          len(self.models))
             if new_tree.num_leaves > 1:
                 self._renew_tree_output(new_tree, kk, leaf_ids)
                 if abs(self._rf_init_scores[kk]) > K_EPSILON:

@@ -126,6 +126,29 @@ class Tree:
         return t
 
     # ------------------------------------------------------------------ #
+    def split_ledger(self):
+        """(partition_rows, histogram_rows), int64 arrays of num_leaves - 1:
+        the rows each step of the growth loop worked on, as the tree
+        itself records them.  Node i is created by step i, which
+        partitions the parent's rows (internal_count[i]) and sums the
+        histogram of the smaller child's (the child's internal_count if
+        it was split later, else its leaf_count).  A tree of one leaf
+        made no step: two empty arrays."""
+        n = self.num_leaves - 1
+        if n < 1:
+            return np.zeros(0, np.int64), np.zeros(0, np.int64)
+        internal = self.internal_count[:n].astype(np.int64)
+        leaf = self.leaf_count[:self.num_leaves].astype(np.int64)
+
+        def rows_of(child):
+            child = child[:n]
+            return np.where(child >= 0, internal[np.maximum(child, 0)],
+                            leaf[np.maximum(~child, 0)])
+
+        return internal, np.minimum(rows_of(self.left_child),
+                                    rows_of(self.right_child))
+
+    # ------------------------------------------------------------------ #
     def to_json(self, index: int = 0) -> dict:
         """Recursive JSON structure (Tree::ToJSON, src/io/tree.cpp:
         NodeToJSON): internal nodes carry split metadata, leaves carry

@@ -1,14 +1,21 @@
-"""Device-side observability: XLA compile/retrace counters + live-buffer probe.
+"""Device-side observability: what the process counts about its own
+device work, in plain Python, process-wide, bounded.
 
-On TPU the dominant hidden cost is not FLOPs but compilation: a retrace
-in the middle of training stalls every iteration behind XLA.  jax ships
-the hooks to see it — `jax.monitoring` fires named events for every
-backend compile and jaxpr trace — but nothing in the stack counts them
-per process.  This module installs ONE process-wide listener (idempotent)
-into plain int counters, and exposes a cheap probe of live device state
-(buffer count/bytes via jax.live_arrays, jit cache occupancy via the
-pjit inference cache) for the per-iteration telemetry events and the
-/metrics gauges.
+- **Compile counters.**  On TPU the dominant hidden cost is not FLOPs but
+  compilation: a retrace in the middle of training stalls every iteration
+  behind XLA.  `jax.monitoring` fires named events for every backend
+  compile and jaxpr trace; ONE process-wide listener (idempotent) counts
+  them (`compile_counts`), and `analyze_compiled` keeps XLA's own cost and
+  peak-HBM estimate of a jitted callable.
+- **Live-buffer probe** (`device_stats`): buffer count and bytes via
+  jax.live_arrays, jit cache occupancy via the pjit inference cache, for
+  the per-iteration telemetry events and the /metrics gauges.
+- **Donation audit** (`donation_audit`): which large inputs of a jitted
+  callable the caller donated.
+- **Split ledger** (`record_split_ledger` / `split_ledgers`): the rows
+  each step of the growth loop worked on, for the last
+  `SPLIT_LEDGER_TREES` trained trees; always on, like the compile
+  counters (docs/Tracing.md, "Counters").
 
 Everything is guarded: a jax version without an event name, without
 jax.monitoring, or without the private pjit cache degrades to zeros,
@@ -16,8 +23,9 @@ never to an exception — telemetry must not be able to kill training.
 """
 from __future__ import annotations
 
+import collections
 import threading
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from ..utils import log
 
@@ -38,6 +46,11 @@ _hbm = {"peak_hbm_bytes": 0, "analyses": 0}
 _donation: Dict[str, Dict] = {}
 # inputs smaller than this are noise, not donation candidates
 DONATION_MIN_BYTES = 1 << 16
+# the split ledgers of the last trained trees, oldest first (a ring: a
+# long job keeps the newest, a benchmark's traced slice is the last few)
+SPLIT_LEDGER_TREES = 64
+_split_ledgers: collections.deque = collections.deque(
+    maxlen=SPLIT_LEDGER_TREES)
 
 # event name fragments -> counter key; matched by substring so minor
 # renames across jax versions keep counting instead of silently zeroing
@@ -215,7 +228,8 @@ def donation_audit(fn, args, label: str = "",
     table which large inputs the caller donated: un-donated large
     buffers force XLA to keep input AND output alive across the
     dispatch — double HBM residency plus a copy the aliasing would have
-    elided, one of ROADMAP item 1's four named scaling suspects.
+    elided (an arena that is not donated is a second 6-10 GB on a 16 GB
+    chip: the benchmark's `correct` holds every train cell to it).
 
     ``resident`` lists the flattened-argument indices that are
     semantically impossible to donate (buffers reused on later rounds,
@@ -283,6 +297,31 @@ def hbm_stats() -> Dict[str, int]:
     every analyze_compiled call) + how many analyses fed it."""
     with _lock:
         return dict(_hbm)
+
+
+def record_split_ledger(iteration: int, slot: int, num_data: int,
+                        partition_rows, histogram_rows) -> None:
+    """One trained tree's split ledger into the ring (the one writer is
+    GBDT._record_split_ledger, wherever a trained tree reaches the host).
+    Entry i of `partition_rows` is the rows step i's `partition_segment`
+    call moved, of `histogram_rows` the rows its `segment_histogram` call
+    summed (models/tree.py `Tree.split_ledger`); a tree of one leaf has
+    two empty arrays.  The counts are what the tree records: under a mesh
+    the global ones, not one chip's share."""
+    entry = {"iteration": int(iteration), "slot": int(slot),
+             "num_data": int(num_data),
+             "partition_rows": partition_rows,
+             "histogram_rows": histogram_rows}
+    with _lock:
+        _split_ledgers.append(entry)
+
+
+def split_ledgers() -> List[Dict]:
+    """The split ledgers of the last SPLIT_LEDGER_TREES trained trees of
+    this process, oldest first (the one reader is the benchmark's
+    readers/row_ledger.py; an operator calls it after a run)."""
+    with _lock:
+        return list(_split_ledgers)
 
 
 def compile_counts() -> Dict[str, int]:

@@ -74,15 +74,19 @@ _SPAN_MS_BOUNDS = (0.05, 0.2, 1.0, 5.0, 20.0, 100.0, 500.0, 2000.0, 10000.0)
 ANNOTATION_PREFIX = "lgbm:"
 
 
+def _scalars(args: Dict) -> Dict:
+    return {k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
 def _annotation(name: str, args: Optional[Dict]) -> TraceAnnotation:
-    """The profiler's annotation of a span; scalar arguments given at the
-    span's start ride it into the trace file (`lgbm:engine_plan`)."""
+    """The profiler's annotation of a span; its scalar arguments ride it
+    into the trace file: those given at the span's start
+    (`lgbm:engine_plan`) and those `set_metadata` adds while it is open
+    (`lgbm:drain_inflight`)."""
     if not args:
         return TraceAnnotation(ANNOTATION_PREFIX + name)
-    return TraceAnnotation(
-        ANNOTATION_PREFIX + name,
-        **{k: v for k, v in args.items()
-           if isinstance(v, (bool, int, float, str))})
+    return TraceAnnotation(ANNOTATION_PREFIX + name, **_scalars(args))
 
 
 class _Span:
@@ -111,11 +115,14 @@ class _Span:
         stack.append(self)
         return self
 
-    def set(self, **kv) -> None:
-        """Attach args discovered mid-span (e.g. batch size at dispatch)."""
+    def set_metadata(self, **kv) -> None:
+        """Attach args discovered mid-span (the trees a drain fetched);
+        named as `TraceAnnotation.set_metadata`, which is what `span()`
+        hands out when the tracer is off, and passed on to it."""
         if self.args is None:
             self.args = {}
         self.args.update(kv)
+        self.annotation.set_metadata(**_scalars(kv))
 
     def __exit__(self, exc_type, exc, tb) -> None:
         tr = self.tracer

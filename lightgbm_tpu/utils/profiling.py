@@ -62,14 +62,14 @@ class Profiler:
         # lgbm:<name> annotation in a jax.profiler trace, and a recorded
         # span when tpu_trace_path arms the tracer, with the accumulators
         # on or off.  The span closes AFTER sync_fn, so it covers device
-        # time like the clock.
-        with tracing.span(name, "phase"):
+        # time like the clock.  The span is yielded for `set_metadata`.
+        with tracing.span(name, "phase") as span:
             if not self.enabled:
-                yield
+                yield span
                 return
             start = time.perf_counter()
             try:
-                yield
+                yield span
             finally:
                 if self.sync_fn is not None:
                     try:

@@ -34,13 +34,17 @@ Histogram accumulation stays f32 (MXU accumulators), matching the
 reference GPU learner's single-precision default.
 
 Pipeline invariant in both kernels: tile j's read is complete when its
-loop iteration starts; iteration j issues read j+1, computes j (overlapped
-with that read), then waits read j+1.  `partition_segment` issues tile
-j's output writes (the FLUSH_W chunks its appends completed) at the end
-of iteration j's compute, beside read j+1 still in flight.  The in-place
-stream is safe all the same: those writes end at most (j+1)*tile columns
-past the segment start, columns read before iteration j began, and read
-j+1 covers the tile after them.
+loop iteration starts.  `segment_histogram` (and `partition_segment` over
+an arena cut into channel blocks) issues read j+1 in iteration j, computes
+j, then waits read j+1.  `partition_segment`'s one-block loop runs a stage
+ahead on a ring of three read slots: iteration j waits read j+1 and issues
+read j+2 at its top, moves tile j and makes tile j+1's predicate part
+beside it (`_partition_kernel`).  Either way tile j's output writes (the
+FLUSH_W chunks its appends completed) are issued inside iteration j,
+beside a read still in flight, and the in-place stream is safe all the
+same: those writes end at most (j+1)*tile columns past the segment start,
+columns read before iteration j began, and every read in flight covers a
+tile after them.
 """
 from __future__ import annotations
 
@@ -162,12 +166,13 @@ def _align8(rows: int) -> int:
 
 
 _VMEM_DEFAULT = 16 << 20     # Mosaic's scoped VMEM limit when none is given
-# partition_segment's VMEM per arena channel, one block: the read slots
-# (8 KiB), the two carries (2 KiB), the staging (16 KiB) and what Mosaic
-# spills of the sort products and the appends (6 KiB: compiled for a v5e,
-# C = 496 fits the default limit and C = 528 asks for 16.47 MB; the tile's
-# predicate part holds nothing per channel and, since PR 33, no one-hot)
-_VMEM_PER_CHANNEL = 34 << 10
+# partition_segment's VMEM per arena channel, one block: the read ring's
+# three slots (12 KiB), the two carries (2 KiB), the staging (16 KiB) and
+# what Mosaic spills of the sort products and the appends (8 KiB: compiled
+# for a v5e, C = 432 fits the default limit, C = 448 does not and C = 464
+# asks for 16.75 MB; the tile's predicate part holds nothing per channel
+# and, since PR 33, no one-hot)
+_VMEM_PER_CHANNEL = 38 << 10
 
 
 def _side_effect_params():
@@ -444,13 +449,58 @@ def _partition_kernel(sc_ref, mask_ref, arena_any, pred_any,
     the loop state), before the parity is staged again two tiles later,
     and after the loop: exactly one wait per started DMA.
 
+    THE ONE-BLOCK LOOP RUNS ONE STAGE AHEAD (PR 37).  Inside a tile
+    everything waits for the prefix scan (positions -> operands -> products
+    -> appends; counts -> append plan -> every shift and flush predicate),
+    and the scan is a chain of eight dependent lane rotations, 114 cycles
+    each from issue to `vpop.permute` by the compiler's own latencies:
+    944 cycles in about 56 bundles, a third of the 1.70 us the tile took
+    while that chain stood alone before the first product.  Between two
+    tiles nothing depends but four scalars (fill and written per stream).
+    So iteration j MOVES tile j (products, appends, flushes) from the
+    positions and the 2K subblock counts that ride the loop's carry (the
+    counts as scalars: the vector-to-scalar pops are paid a tile ahead
+    too) and MAKES tile j+1's predicate part (`tile_permutation`) beside
+    it; a prologue makes tile 0's, and the part made past the last tile
+    is dropped (its `valid` is false everywhere; the fused histogram, a
+    side effect, is skipped there).  Order inside an iteration, and why:
+      1. wait the flushes of tile j-2 (same staging parity), wait read
+         j+1, start read j+2.  EVERY WAIT IS AT THE TOP: Mosaic starts a
+         new scheduling block at each `dma.done` wait and moves nothing
+         across one, and a chain is hidden only by work of its own block
+         (the wait of read j+1 put behind the flush starts, where the
+         read would have had half an iteration more, left tile j+1's
+         part alone in a last block: slower than no pipeline at all,
+         PERF.md section 6).  The read ring has THREE slots because tile
+         j+1 is read from (its decision group, its pred tile, the fused
+         histogram's rows) while tile j+2 arrives; one read is in
+         flight, for a whole iteration, and takes 0.7 / 0.8 / 1.45 us
+         from start to done at C = 48 / 64 / 160, under iterations of
+         1.17 / 1.32 / 2.67.
+      2. tile j's products and appends, each subblock's two flush DMAs
+         started right behind its two appends: a DMA start cuts no block
+         but is issued behind every vector store before it in program
+         order, so started behind the tile's last append (as the blocked
+         loop below does) the 32 starts are 270 bundles of scalar work
+         with nothing beside them.
+      3. tile j+1's predicate part, in the same block as 2.
+    Staging and its two-parity flush waits are as before the pipeline.
+
     Stream A may write over the parent segment in place: tile j's flushes
     reach at most dstA + wA + FLUSH_W <= start + (j+1)*tile, columns whose
-    reads (tiles 0..j) completed before iteration j began, and the read of
-    tile j+1 in flight beside them is disjoint from them.
+    reads (tiles 0..j) completed before iteration j's products began; the
+    read in flight beside them is of tile j+2 (tile j+1's, waited at the
+    top, lies beyond them as well).  Nothing past the segment's last tile
+    is read: reads are started under j+2 < n_tiles, and what a ring slot
+    holds past the last tile decides nothing (`valid`).
 
     CHANNEL BLOCKS (cb < C: an arena too wide for one [C, tile] slab in
-    VMEM).  Where a row goes depends on the predicate only, so the tile's
+    VMEM) keep the two-slot loop and its schedule: there the predicate part
+    is made once a tile for C/cb block steps (0.7 of Epsilon's 30 us a
+    tile), `P_ref` would need a second 1 MiB parity to be made ahead, and
+    `dec_buf` already is a tile ahead.  The two loops differ by a shape
+    (cb < C), not by a knob, and share `tile_permutation`, `sort_append`,
+    `start_flush`, `flushed_state` and `_append_plan`.  Where a row goes depends on the predicate only, so the tile's
     decision, prefix scan, K [SUB, SUB] permutation operands (stored as
     bf16 in `P_ref`) and append plan are made ONCE per tile, from the
     16-row group that holds the split feature's channel (the same code as
@@ -564,21 +614,28 @@ def _partition_kernel(sc_ref, mask_ref, arena_any, pred_any,
             hmask = (hs * predB + (1 - hs) * predA).astype(
                 jnp.float32).reshape(1, tile)
             nb_h, k_h, m_h, lo_h, hi_h, pay_h = hist_plan
-            _radix_accumulate(hist_ref, rows_at(), hmask, n_blocks=nb_h,
-                              k=k_h, m=m_h, lo_n=lo_h, hi_n=hi_h,
-                              payload=pay_h)
+
+            # the pipelined loop makes this a tile ahead, past the last
+            # tile too: a ring slot no read has filled may hold anything
+            # (the mask is a factor of the payload, and 0 * NaN is no 0)
+            @pl.when(j < n_tiles)
+            def _():
+                _radix_accumulate(hist_ref, rows_at(), hmask, n_blocks=nb_h,
+                                  k=k_h, m=m_h, lo_n=lo_h, hi_n=hi_h,
+                                  payload=pay_h)
 
         pred2 = jnp.concatenate([predA, predB], axis=0)    # [2K, SUB]
         pref2 = _prefix_scan_lanes(pred2)
         return _sort_pos(pref2, pred2, K), pref2[:, SUB - 1]
 
-    def sort_append(block, P_at, plan_of, carries_in, slot):
+    def sort_append(block, P_at, plan_of, carries_in, slot, after=None):
         """K dependency-free SORT matmuls (chunk[rows, s] . Pt[t, s]:
         A-prefix + B-suffix in a single product — the split point ca_k
         is known from the prefix scan before any product, so the two
         streams share one SUB-wide output) and the 2K straight-line
         appends of one block of channels.  `plan_of()` gives the tile's
-        (cA, cB) subblock counts, `plan_of(cA, cB)` its two append plans.
+        (cA, cB) subblock counts, `plan_of(cA, cB)` its two append plans;
+        `after(k)`, if given, is traced behind subblock k's two appends.
         Returns (the two new carries, the plans)."""
         comps = [jax.lax.dot_general(block[:, k * SUB:(k + 1) * SUB],
                                      P_at(k), _SORT_DIMS,
@@ -600,28 +657,45 @@ def _partition_kernel(sc_ref, mask_ref, arena_any, pred_any,
                 out[stream] = append(
                     out[stream], chunk, lo, p_fill[k], p_flushed[k],
                     (stream, slot, k))
+            if after is not None:
+                after(k)
         return out, plans
 
-    def start_flushes(plans, written, slot, row0=None):
-        """Start the DMAs of the chunks a step's appends completed.
-        Returns per stream (fill, written) after the tile and the bit
-        mask of the appends that flushed."""
+    def start_flush(plans, written, slot, stream, k, row0=None):
+        """Start the DMA of the chunk that `stream`'s append k completed,
+        if it completed one."""
+        _, p_flushed, p_chunk_no, _ = plans[stream]
+
+        @pl.when(p_flushed[k])
+        def _():
+            flush_dma(stream, slot, k,
+                      dsts[stream] + written[stream]
+                      + p_chunk_no[k] * FLUSH_W, row0).start()
+
+    def flushed_state(plans, written, each=None):
+        """Per stream (fill, written) after the tile and the bit mask of
+        the appends that flushed: scalars of the plans alone.  `each
+        (stream, k)`, if given, is traced at append k's turn."""
         new_fills, new_written, new_pending = [], [], []
         for stream in range(2):
-            _, p_flushed, p_chunk_no, end = plans[stream]
+            _, p_flushed, _, end = plans[stream]
             bits = jnp.int32(0)
             for k in range(K):
-                @pl.when(p_flushed[k])
-                def _(stream=stream, k=k, chunk_no=p_chunk_no[k]):
-                    flush_dma(stream, slot, k,
-                              dsts[stream] + written[stream]
-                              + chunk_no * FLUSH_W, row0).start()
+                if each is not None:
+                    each(stream, k)
                 bits = bits | (p_flushed[k].astype(jnp.int32) << k)
             done = jax.lax.div(end, jnp.int32(FLUSH_W))
             new_fills.append(end - done * FLUSH_W)
             new_written.append(written[stream] + done * FLUSH_W)
             new_pending.append(bits)
         return tuple(new_fills), tuple(new_written), tuple(new_pending)
+
+    def start_flushes(plans, written, slot, row0=None):
+        """Start the DMAs of the chunks a step's appends completed, all
+        of them behind the step's last append.  Returns `flushed_state`."""
+        return flushed_state(
+            plans, written, lambda stream, k: start_flush(
+                plans, written, slot, stream, k, row0))
 
     z2 = (jnp.int32(0), jnp.int32(0))
     if not blocked:
@@ -640,52 +714,73 @@ def _partition_kernel(sc_ref, mask_ref, arena_any, pred_any,
                         pred_any.at[:, pl.ds(pl.multiple_of(psrc, 128), tile)],
                         pred_buf.at[slot], pred_sems.at[slot]))
 
-        @pl.when(n_tiles > 0)
-        def _():
-            for d in read_dmas(0, 0):
-                d.start()
-            for d in read_dmas(0, 0):
-                d.wait()
-        carryA[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
-        carryB[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
-
-        def loop(j, state):
-            fills, written, pending, pending2 = state
-            slot = jax.lax.rem(j, jnp.int32(2))
-            nslot = 1 - slot
-            # tile j-2 staged this parity: its flushes must have landed
-            wait_flushes(slot, pending2)
-
-            @pl.when(j + 1 < n_tiles)
-            def _():
-                for d in read_dmas(j + 1, nslot):
-                    d.start()
-
+        def permutation_of(j, slot):
+            """Tile j's predicate part from ring slot `slot`: its rows'
+            positions and the 2K subblock counts AS SCALARS, so that the
+            iteration that moves the tile starts at its products."""
             pos, cnt2 = tile_permutation(
                 j, lambda: in_buf[slot, pl.ds(grp, _SUBL), :],
                 lambda: pred_buf[slot], lambda: in_buf[slot])
+            return pos, tuple(cnt2[k] for k in range(2 * K))
 
-            def plan_of(cA=None, cB=None):
-                if cA is None:
-                    return ([cnt2[k] for k in range(K)],
-                            [cnt2[K + k] for k in range(K)])
-                return (_append_plan(fills[0], cA),
-                        _append_plan(fills[1], cB))
+        def next_slot(slot):
+            return jnp.where(slot == 2, 0, slot + 1)
 
-            (carryA[:], carryB[:]), plans = sort_append(
-                in_buf[slot], lambda k: _sort_P(pos, k), plan_of,
-                lambda: [carryA[:], carryB[:]], slot)
-            new_fills, new_written, new_pending = start_flushes(
-                plans, written, slot)
+        # prologue: tiles 0 and 1 on their way, tile 0's predicate part
+        for t in range(2):
+            @pl.when(n_tiles > t)
+            def _(t=t):
+                for d in read_dmas(t, t):
+                    d.start()
+        carryA[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
+        carryB[:] = jnp.zeros((C, FLUSH_W), jnp.float32)
 
+        @pl.when(n_tiles > 0)
+        def _():
+            for d in read_dmas(0, 0):
+                d.wait()
+
+        def loop(j, state):
+            fills, written, pending, pending2, slot, pos, counts = state
+            parity = jax.lax.rem(j, jnp.int32(2))
+            slot1 = next_slot(slot)
+            # tile j-2 staged this parity: its flushes must have landed
+            wait_flushes(parity, pending2)
+
+            # tile j+1, whose read was started a whole iteration ago
             @pl.when(j + 1 < n_tiles)
             def _():
-                for d in read_dmas(j + 1, nslot):
+                for d in read_dmas(j + 1, slot1):
                     d.wait()
-            return new_fills, new_written, new_pending, pending
 
-        fills, written, pending, pending2 = jax.lax.fori_loop(
-            0, n_tiles, loop, (z2, z2, z2, z2))
+            @pl.when(j + 2 < n_tiles)
+            def _():
+                for d in read_dmas(j + 2, next_slot(slot1)):
+                    d.start()
+
+            cA, cB = list(counts[:K]), list(counts[K:])
+            plans = (_append_plan(fills[0], cA), _append_plan(fills[1], cB))
+
+            def plan_of(a=None, b=None):
+                return (cA, cB) if a is None else plans
+
+            def flush_after(k):
+                # a DMA start is issued behind every vector store before
+                # it in program order: started behind the tile's last
+                # append, as the blocked loop starts them, the flushes
+                # are a tail of scalar work with nothing beside it
+                for stream in range(2):
+                    start_flush(plans, written, parity, stream, k)
+
+            (carryA[:], carryB[:]), _ = sort_append(
+                in_buf[slot], lambda k: _sort_P(pos, k), plan_of,
+                lambda: [carryA[:], carryB[:]], parity, flush_after)
+            return (*flushed_state(plans, written), pending, slot1,
+                    *permutation_of(j + 1, slot1))
+
+        fills, written, pending, pending2, _, _, _ = jax.lax.fori_loop(
+            0, n_tiles, loop,
+            (z2, z2, z2, z2, jnp.int32(0)) + permutation_of(0, 0))
         n_steps = n_tiles
     else:
         def rows_dma(j, row0, rows, buf, sems, slot):
@@ -887,13 +982,13 @@ def partition_segment(arena, pred, start, cnt, dstA, dstB,
         ]
     else:
         scratch = [
-            pltpu.VMEM((2, C, tile), ARENA_DT),
-            pltpu.VMEM((2, 1, tile), jnp.float32),
+            pltpu.VMEM((3, C, tile), ARENA_DT),
+            pltpu.VMEM((3, 1, tile), jnp.float32),
             pltpu.VMEM((C, FLUSH_W), jnp.float32),
             pltpu.VMEM((C, FLUSH_W), jnp.float32),
             pltpu.VMEM(_staging_shape(C, tile), ARENA_DT),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SemaphoreType.DMA((3,)),
             pltpu.SemaphoreType.DMA((2, 2, K)),
         ]
     if with_hist and not blocked:

@@ -397,13 +397,20 @@ def test_compiled_for_v5e_never_copies_or_selects_the_cache(name, one_chip):
     _assert_no_whole_cache_ops(hlo, name)
 
 
-@pytest.mark.parametrize("F", [28, 37, 137, 2000])  # C = 48, 64, 160, 2 016
+# C = 48, 64, 160, 2 016 and 416, the widest arena that is one block
+@pytest.mark.parametrize("F", [28, 37, 137, 2000, 400])
 def test_partition_segment_compiles_for_v5e_at_the_cells_widths(F, one_chip):
     """The tile body's lane gather, its dynamic 16-row slice of the tile in
     VMEM and the transposed sort products pass interpret mode whatever
     Mosaic makes of them: compile the kernel for the chip at the four
-    cells' widths, one block and six."""
+    cells' widths, one block and six, and at the widest arena the
+    one-block loop's three-deep read ring leaves in one block under the
+    default VMEM limit (PR 37: 38 KiB a channel)."""
     C, cap = pp.arena_geometry(100_000, F)
+    # one block, under the default limit, serves every cell but Epsilon,
+    # whose six blocks of 336 stay
+    assert pp.partition_channel_block(C) == {2016: 336}.get(C, C)
+    assert C == 2016 or pp._partition_vmem_limit(C, []) is None
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -368,6 +368,8 @@ def _go_left(share, cnt):
         return i == cnt // 2
     if share == "all_but_one":
         return i != cnt // 3
+    if share == "alternate":
+        return i % 2 == 0
     # every sub-block gives one stream SUB - 1 rows: from the second on,
     # each of its appends crosses a FLUSH_W boundary of the carry
     return i % pp.SUB != 77
@@ -387,23 +389,32 @@ def _aligned(n):
     return -(-n // pp.FLUSH_W) * pp.FLUSH_W
 
 
+# destinations past a segment of up to seven tiles, in an arena eight tiles
+# longer (the pipelined loop's cases)
+_FAR_A, _FAR_B = 9 * pp.TILE, 17 * pp.TILE - 3 * pp.FLUSH_W
+
+
 def _run_partition(F, cnt, share, in_place, mode, hist_stream=None,
-                   max_bin=0):
+                   max_bin=0, xr=None, far=False):
     """Run the kernel on a random arena; check streams, order, counts and
     that no column outside align(count, FLUSH_W) of each dst changed.
     Returns (before, go_to_A, outputs)."""
     C = pp.arena_channels(F)
     arena = _base_arena(C)
+    dst_a, dst_b = (_FAR_A, _FAR_B) if far else (_DST_A, _DST_B)
+    if far:
+        arena = np.concatenate([arena, arena[:, ::-1][:, :8 * pp.TILE]], 1)
+    cap = arena.shape[1]
     go = _go_left(share, cnt)
-    dstA = _START if in_place else _DST_A
+    dstA = _START if in_place else dst_a
     kw = {}
     if mode == 0:
-        pred = np.zeros((1, _CAP), np.float32)
+        pred = np.zeros((1, cap), np.float32)
         pred[0, _START:_START + cnt] = go
         to_A = go
     else:
         # bin value < 100 goes left; xr = 1 sends the left rows to B
-        xr = int(not in_place)
+        xr = int(not in_place) if xr is None else xr
         arena[0, _START:_START + cnt] = np.where(go, 10, 200)
         pred = np.zeros((1, pp.TILE), np.float32)
         kw["decision"] = (0, jnp.asarray(np.arange(256) < 100, jnp.float32),
@@ -412,17 +423,17 @@ def _run_partition(F, cnt, share, in_place, mode, hist_stream=None,
     if hist_stream is not None:
         kw.update(hist_stream=hist_stream, num_features=F, max_bin=max_bin)
     out = pp.partition_segment(jnp.asarray(arena, pp.ARENA_DT),
-                               jnp.asarray(pred), _START, cnt, dstA, _DST_B,
+                               jnp.asarray(pred), _START, cnt, dstA, dst_b,
                                interpret=True, **kw)
     got = np.asarray(out[0].astype(jnp.float32))
     seg = arena[:, _START:_START + cnt]
     nA, nB = int(to_A.sum()), int((~to_A).sum())
     assert list(np.asarray(out[1])) == [nA, nB]
     np.testing.assert_array_equal(got[:, dstA:dstA + nA], seg[:, to_A])
-    np.testing.assert_array_equal(got[:, _DST_B:_DST_B + nB], seg[:, ~to_A])
-    untouched = np.ones(_CAP, bool)
+    np.testing.assert_array_equal(got[:, dst_b:dst_b + nB], seg[:, ~to_A])
+    untouched = np.ones(cap, bool)
     untouched[dstA:dstA + _aligned(nA)] = False
-    untouched[_DST_B:_DST_B + _aligned(nB)] = False
+    untouched[dst_b:dst_b + _aligned(nB)] = False
     np.testing.assert_array_equal(got[:, untouched], arena[:, untouched])
     return seg, to_A, out
 
@@ -443,10 +454,14 @@ def test_partition_segment_is_stable_partition(F, cnt, share, in_place,
 def test_partition_segment_fused_histogram(hist_stream, cnt, mode):
     """The hist_stream variant partitions as the plain kernel does and
     returns the chosen stream's [F, max_bin, 3] histogram."""
-    F, B = 28, 255
+    _fused_histogram_case(28, cnt, mode, hist_stream)
+
+
+def _fused_histogram_case(F, cnt, mode, hist_stream, **kw):
+    B = 255
     Fp = pp.feature_channels(F)
     seg, to_A, out = _run_partition(F, cnt, "half", True, mode,
-                                    hist_stream=hist_stream, max_bin=B)
+                                    hist_stream=hist_stream, max_bin=B, **kw)
     rows = seg[:, ~to_A if hist_stream else to_A]
     want = np.zeros((F, B, 3))
     g = rows[Fp:Fp + 3].sum(0)
@@ -457,6 +472,52 @@ def test_partition_segment_fused_histogram(hist_stream, cnt, mode):
         np.add.at(want[f, :, 1], b, h)
         np.add.at(want[f, :, 2], b, 1.0)
     np.testing.assert_array_equal(np.asarray(out[2], np.float64), want)
+
+
+# --------------------------------------------------------------------- #
+# the one-block loop's pipeline (PR 37): tile j + 1's predicate part is made
+# in iteration j from a three-deep read ring, so a call's first tile (the
+# prologue), its last (the part made past it is never used) and the ring's
+# wrap are each a case: 0, 1, 2, 3, 4 and 7 tiles
+# --------------------------------------------------------------------- #
+_T = pp.TILE
+# whole tiles and one row over; 1 534 and 4 606 rows split evenly leave each
+# stream one row short of a FLUSH_W chunk (3 * 256 - 1 and 9 * 256 - 1 rows)
+_TILED = (0, _T, 2 * _T, 2 * _T + 1, 3 * _T, 4 * _T, 7 * _T - 3)
+_SHORT = (6 * pp.FLUSH_W - 2, 18 * pp.FLUSH_W - 2)
+
+
+# in place by `pred`, in place by the decision with either `xr`, and stream A
+# to a destination of its own
+@pytest.mark.parametrize("in_place,mode,xr", [
+    (True, 0, None), (True, 1, 0), (True, 1, 1), (False, 1, 1)])
+@pytest.mark.parametrize("cnt", _TILED)
+@pytest.mark.parametrize("F", [28, 37, 137])        # C = 48, 64, 160
+def test_pipelined_loop_over_whole_tiles(F, cnt, in_place, mode, xr):
+    _run_partition(F, cnt, "half", in_place, mode, xr=xr, far=True)
+
+
+@pytest.mark.parametrize("share,cnt", [
+    ("none", 3 * _T), ("all", 3 * _T), ("none", 7 * _T - 3),
+    ("all", 7 * _T - 3), ("straddle", 4 * _T), ("alternate", _SHORT[0]),
+    ("alternate", _SHORT[1])])
+@pytest.mark.parametrize("F,mode", [(28, 0), (28, 1), (37, 1), (137, 1)])
+def test_pipelined_loop_one_sided_and_chunk_short(F, mode, share, cnt):
+    if share == "alternate":
+        assert (cnt // 2 + 1) % pp.FLUSH_W == 0
+    _run_partition(F, cnt, share, True, mode, far=True)
+
+
+@pytest.mark.parametrize("hist_stream", [0, 1])
+@pytest.mark.parametrize("F,cnt,mode", [
+    (28, 0, 1), (28, _T, 0), (28, _T, 1), (28, 2 * _T + 1, 0),
+    (28, 2 * _T + 1, 1), (28, 7 * _T - 3, 1), (37, 2 * _T, 1),
+    (37, 4 * _T + 9, 0)])
+def test_pipelined_loop_fused_histogram(F, cnt, mode, hist_stream):
+    """Tile j + 1's rows are summed in iteration j, in tile order; the
+    part made past the last tile sums nothing (a ring slot that was never
+    read holds no row of the segment)."""
+    _fused_histogram_case(F, cnt, mode, hist_stream, far=True)
 
 
 # --------------------------------------------------------------------- #

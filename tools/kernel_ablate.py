@@ -13,10 +13,15 @@ The partition stages are ADDITIVE: the compute stages run over a tile that
 is RESIDENT in VMEM (two tiles are read once, before the loop; tile j
 computes on slot j % 2, so nothing can be hoisted out of the loop), where
 no read hides them: a stage's increment over the stage before is what it
-adds to a compute-bound tile body.  (Until PR 33 every stage ran under the
+adds to a compute-bound tile body, RUN ALONE: since PR 37 the shipped loop
+makes tile j + 1's `column`, `lookup` and `scan` inside iteration j, beside
+tile j's products and appends, where the scan's eight dependent lane
+rotations (a chain of latencies, not of instructions) cost next to
+nothing.  (Until PR 33 every stage ran under the
 read pipeline and read max(read, compute): `decide` = 4.60 ms beside `dma`
-= 4.56 was taken for 0.03 ms of work.)  `dma` is the read pipeline alone,
-one tile in flight, and `full` the shipped kernel (`pp.partition_segment`:
+= 4.56 was taken for 0.03 ms of work.)  `dma` is the read ring alone
+(three slots, tile j + 2 started when tile j + 1 has been waited, as the
+shipped loop does), and `full` the shipped kernel (`pp.partition_segment`:
 read, appends, flushes and write-back included), so what the appends and
 flushes cost is `full` less `chunks` less whatever of the read the tile
 body does not hide.  Every stage sums what it makes into the loop's carry,
@@ -103,7 +108,7 @@ def tile_body(stage, in_buf, slot, j, sc_ref, mask_ref, sink):
 
 def _kernel(sc_ref, mask_ref, arena_any, out_any, cnt_ref, in_buf, sink,
             read_sems, *, tile: int, stage: str):
-    """`dma`: the shipped kernel's read pipeline alone.  Every other
+    """`dma`: the shipped one-block loop's read ring alone.  Every other
     stage: two tiles read once, then the stage loop over the resident
     tiles; nothing is written back.  sc_ref and mask_ref are the shipped
     kernel's."""
@@ -119,23 +124,27 @@ def _kernel(sc_ref, mask_ref, arena_any, out_any, cnt_ref, in_buf, sink,
     sink[:] = jnp.zeros_like(sink)
     zero = jnp.zeros((8, 128), jnp.float32)
     if stage == "dma":
+        # the shipped loop's reads (PR 37): a ring of three, tile j + 1
+        # waited and tile j + 2 started at the top of iteration j
+        for t in range(2):
+            @pl.when(n_tiles > t)
+            def _(t=t):
+                read_dma(t, t).start()
+
         @pl.when(n_tiles > 0)
         def _():
-            read_dma(0, 0).start()
             read_dma(0, 0).wait()
 
         def loop(j, chk):
-            slot = jax.lax.rem(j, jnp.int32(2))
-
             @pl.when(j + 1 < n_tiles)
             def _():
-                read_dma(j + 1, 1 - slot).start()
-            chk = chk + in_buf[slot, 0:8, 0:128].astype(jnp.float32)
+                read_dma(j + 1, jax.lax.rem(j + 1, jnp.int32(3))).wait()
 
-            @pl.when(j + 1 < n_tiles)
+            @pl.when(j + 2 < n_tiles)
             def _():
-                read_dma(j + 1, 1 - slot).wait()
-            return chk
+                read_dma(j + 2, jax.lax.rem(j + 2, jnp.int32(3))).start()
+            return chk + in_buf[jax.lax.rem(j, jnp.int32(3)), 0:8,
+                                0:128].astype(jnp.float32)
     else:
         for t in range(2):
             read_dma(t, t).start()
@@ -186,9 +195,9 @@ def run_stage(arena, decision, *, stage, n, reps):
             out_shape=(jax.ShapeDtypeStruct((C, cap), ARENA_DT),
                        jax.ShapeDtypeStruct((2,), jnp.int32)),
             scratch_shapes=[
-                pltpu.VMEM((2, C, TILE), ARENA_DT),
+                pltpu.VMEM((3, C, TILE), ARENA_DT),
                 pltpu.VMEM((pp.SUB // 2, pp.SUB), jnp.int32),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((3,)),
             ],
             input_output_aliases={2: 0},
             compiler_params=pltpu.CompilerParams(has_side_effects=True),

@@ -25,7 +25,13 @@ kernel:
   tile, and an intercept that is the launch alone: the rows' line takes
   in the padding of a call's last tile), and the calls under one tile;
 - the roofline share over all calls and over each tree's first call alone
-  (for `partition_segment` the second is `partition_root_roofline`).
+  (for `partition_segment` the second is `partition_root_roofline`);
+- for `partition_segment`, `pipelined_share`: the share of the slice's
+  tiles whose predicate part was made a tile ahead, inside the iteration
+  that moved the tile before them (PR 37: every tile but a call's first,
+  1 - calls / tiles; 0 where the arena is cut into channel blocks, whose
+  loop makes a tile's predicate part once for all its blocks and is not
+  pipelined).
 
 `--save DIR` leaves there every call's (rows, seconds) per kernel (JSON)
 and, with `--rehearse`, the trace (gzip) and the slice's ledger entries:
@@ -89,7 +95,16 @@ def fits(reader, run, name):
     # TILE rows, so a call's time is a step function of its rows
     tiled = residual(reader, [-(-r // TILE) for r in rows], seconds)
     args = {"pattern": pattern, "rows": field, "what": "roofline"}
-    return {
+    more = {}
+    if name == "partition":
+        from lightgbm_tpu.ops import partition_pallas as pp
+        C = pp.arena_channels(run.shape["features"])
+        blocks = C // pp.partition_channel_block(C)
+        tiles = sum(-(-r // TILE) for r in rows)
+        more = {"tiles": tiles, "channel_blocks": blocks,
+                "pipelined_share":
+                1.0 - len(rows) / tiles if blocks == 1 else 0.0}
+    return dict(more, **{
         "kernel": name, "paired": True, "trees": len(trees),
         "calls": len(rows),
         "ms_per_pass_of_the_line": a and a * run.shape["rows"] * 1e3,
@@ -109,7 +124,7 @@ def fits(reader, run, name):
         "roofline_first_calls": reader.read(run, dict(args, calls="first")),
         "passes": reader.read(run, dict(args, what="passes")),
         "ms_per_pass": reader.read(run, dict(args, what="ms_per_pass")),
-    }
+    })
 
 
 def main():

@@ -42,6 +42,7 @@ def run(bench):
         rows = traffic["walker_rows"]
         diff = float(np.max(np.abs(
             first[:rows] - walker.predict(text, batches[0][:rows]))))
+        bench.hold("walker_diff", diff, traffic["walker_atol"])
         if not diff <= traffic["walker_atol"]:
             problems.append(
                 "Booster.predict differs from the plain walker on the model "

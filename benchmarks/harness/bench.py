@@ -3,6 +3,7 @@ benchmark's own spans, the compile counters around the window and the
 profiler session of a traced run."""
 import contextlib
 import json
+import math
 import os
 import shutil
 import time
@@ -25,12 +26,34 @@ class Bench:
         self.window_spans = ()          # those of the measured window
         self.window = None              # (start, end), perf_counter
         self.compiles_in_window = None
+        self.compared = {}              # name -> [number, its limit]
         self.trace_dir = None
         self._compiles_at_open = None
 
     # -- everything but the last line is said here ---------------------- #
     def say(self, what, **fields):
         print("[bench] " + json.dumps(dict(what=what, **fields)), flush=True)
+
+    def hold(self, name, value, limit):
+        """Put on record a number `correct` was decided by, beside its
+        limit: run.py prints them last on standard error and under
+        `compared` in the result's line.  A name held twice (once a tree,
+        say) keeps its worst reading; `None` is a number that could not
+        be read, which the problem list explains."""
+        value = None if value is None else float(value)
+        old = self.compared.get(name)
+        if old is None or old[0] is None or (
+                value is not None and value > old[0]):
+            self.compared[name] = [value, float(limit)]
+
+    def compared_record(self):
+        """{name: {"value", "limit"}} of what was held, for a line of
+        JSON: a number that is not finite (the gain of a split the
+        reference forbids is -inf) is null there, and the problem list
+        says why."""
+        return {name: {"value": value if value is None
+                       or math.isfinite(value) else None, "limit": limit}
+                for name, (value, limit) in self.compared.items()}
 
     # -- files the run may write: all under benchmarks/.cache ------------ #
     def cache_path(self, *parts):

@@ -50,6 +50,24 @@ def _gradients(objective, score, y, group):
     raise ValueError("the plain reference has no objective %r" % objective)
 
 
+def hold_tree(bench, c, ref_tree, sys_values=None, ref_values=None):
+    """Put a replayed tree's numbers on the run's record beside their
+    limits (`Bench.hold` keeps the worst tree's): the largest shortfall of
+    a chosen split's gain against `gain_rtol` and, where the leaf values
+    were compared, the largest |difference| in units of what
+    `leaf_value_rtol` and `leaf_value_atol_of_largest` allow that leaf."""
+    bench.hold("gain_shortfall", ref_tree.gain_shortfall, c["gain_rtol"])
+    if sys_values is not None:
+        allowed = (c["leaf_value_atol_of_largest"] * np.abs(ref_values).max()
+                   + c["leaf_value_rtol"] * np.abs(ref_values))
+        bench.hold("leaf_value_off_of_allowed", np.max(
+            np.abs(sys_values - ref_values) / allowed), 1.0)
+
+
+def hold_quality(bench, name, q, q_ref, band):
+    bench.hold(name + "_quality_gap", abs(q - q_ref) / abs(q_ref), band)
+
+
 def against_reference(bench, lgb, params):
     """On a seeded sample at the configuration's full widths and leaf
     count: the system in float32 must grow trees the plain reference
@@ -81,7 +99,7 @@ def against_reference(bench, lgb, params):
     lap("data_and_binning_s")
 
     problems = []
-    rules = grower.SplitRules(params)
+    rules = grower.SplitRules(params, c.get("bound_rtol", 0.0))
     lr = float(params["learning_rate"])
     objective = params["objective"]
     f32 = _train(lgb, dict(params, tpu_quantized_grad=False), ds, c["trees"])
@@ -95,9 +113,13 @@ def against_reference(bench, lgb, params):
             b.bins, b.feature_num_bins(), grad, hess, rules,
             system_splits(sys_tree), c["gain_rtol"])
         if misses:
+            hold_tree(bench, c, ref_tree)
             problems.append(
                 "tree %d: %d split(s) the reference does not accept, first "
                 "(step, gain, best gain) = %s" % (t, len(misses), misses[0]))
+            bench.say("reference-check", tree=t, miss=grower.explain_miss(
+                b.bins, b.feature_num_bins(), grad, hess, rules,
+                system_splits(sys_tree), misses[0][0]))
             break
         if not np.array_equal(ref_tree.leaf_count,
                               sys_tree.leaf_count[:sys_tree.num_leaves]):
@@ -106,6 +128,7 @@ def against_reference(bench, lgb, params):
         sys_values = (np.asarray(sys_tree.leaf_value[:sys_tree.num_leaves])
                       - (init if t == 0 else 0.0))
         ref_values = lr * ref_tree.leaf_value
+        hold_tree(bench, c, ref_tree, sys_values, ref_values)
         if not np.allclose(sys_values, ref_values, rtol=c["leaf_value_rtol"],
                            atol=c["leaf_value_atol_of_largest"]
                            * np.abs(ref_values).max()):
@@ -130,6 +153,7 @@ def against_reference(bench, lgb, params):
         raw = booster.predict(Xh, raw_score=True)
         q = quality_of(c["quality"], yh, raw, gh)
         found[name] = q
+        hold_quality(bench, name, q, q_ref, band)
         if not abs(q - q_ref) <= band * abs(q_ref):
             problems.append("%s after %d trees: holdout %s %.6f against the "
                             "reference's %.6f, band %g"
@@ -147,6 +171,7 @@ def against_walker(bench, booster, X, atol):
     diff = float(np.max(np.abs(np.asarray(booster.predict(X))
                                - walker.predict(text, X))))
     bench.say("walker-check", rows=len(X), max_abs_diff=diff)
+    bench.hold("walker_diff", diff, atol)
     return [] if diff <= atol else [
         "Booster.predict differs from the plain walker on the model text "
         "by %g (allowed %g)" % (diff, atol)]

@@ -28,10 +28,13 @@ def plain_bins(X, columns):
     return (X[:, columns] != 0).toarray().astype(np.uint8)
 
 
-def judge_trees(trees, bins, hold_bins, grad_of, rules, lr, init, c):
+def judge_trees(trees, bins, hold_bins, grad_of, rules, lr, init, c,
+                bench=None):
     """Replay the system's float32 trees on the plain bins, split by split
     (reference/grower.replay), then compare leaf counts and leaf values.
-    Returns (problems, the reference's raw holdout scores)."""
+    Returns (problems, the reference's raw holdout scores); a refused
+    split is described on, and each tree's numbers are held by, the run's
+    `bench` where one is given."""
     num_bins = np.full(bins.shape[1], 2, np.int64)
     score = np.full(len(bins), init)
     ref_hold = np.full(len(hold_bins), init)
@@ -41,6 +44,12 @@ def judge_trees(trees, bins, hold_bins, grad_of, rules, lr, init, c):
             bins, num_bins, grad, hess, rules,
             checks.system_splits(sys_tree), c["gain_rtol"])
         if misses:
+            if bench is not None:
+                checks.hold_tree(bench, c, ref_tree)
+                bench.say("reference-check", tree=t,
+                          miss=grower.explain_miss(
+                              bins, num_bins, grad, hess, rules,
+                              checks.system_splits(sys_tree), misses[0][0]))
             return ["tree %d: %d split(s) the reference does not accept, "
                     "first (step, gain, best gain) = %s"
                     % (t, len(misses), misses[0])], ref_hold
@@ -50,6 +59,8 @@ def judge_trees(trees, bins, hold_bins, grad_of, rules, lr, init, c):
         sys_values = (np.asarray(sys_tree.leaf_value[:sys_tree.num_leaves])
                       - (init if t == 0 else 0.0))
         ref_values = lr * ref_tree.leaf_value
+        if bench is not None:
+            checks.hold_tree(bench, c, ref_tree, sys_values, ref_values)
         if not np.allclose(sys_values, ref_values, rtol=c["leaf_value_rtol"],
                            atol=c["leaf_value_atol_of_largest"]
                            * np.abs(ref_values).max()):
@@ -98,7 +109,8 @@ def against_reference(bench, lgb, params):
     problems, ref_hold = judge_trees(
         f32._gbdt.models, bins, hold_bins,
         lambda score: checks._gradients(objective, score, ys, gs),
-        grower.SplitRules(params), float(params["learning_rate"]), init, c)
+        grower.SplitRules(params, c.get("bound_rtol", 0.0)),
+        float(params["learning_rate"]), init, c, bench=bench)
     lap("reference_s")
     if problems:
         return problems
@@ -114,6 +126,7 @@ def against_reference(bench, lgb, params):
         raw = booster.predict(Xh, raw_score=True)
         q = checks.quality_of(c["quality"], yh, raw, gh)
         found[name] = q
+        checks.hold_quality(bench, name, q, q_ref, band)
         if not abs(q - q_ref) <= band * abs(q_ref):
             problems.append("%s after %d trees: holdout %s %.6f against the "
                             "reference's %.6f, band %g"

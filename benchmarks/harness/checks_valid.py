@@ -78,6 +78,8 @@ def against_reference(bench, booster, name, X, y, returned):
     got = np.asarray(returned, np.float64)
     worst = (float(np.max(np.abs(got - reference)))
              if len(got) == len(reference) else None)
+    bench.hold("valid_auc_diff", worst, c["auc_atol"])
+    bench.hold("valid_score_diff", off, c["score_atol"])
     bench.say("valid-check", rows=len(y), iterations=len(reference),
               auc_first=reference[0], auc_last=reference[-1],
               returned_last=returned[-1] if len(returned) else None,

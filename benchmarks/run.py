@@ -7,11 +7,12 @@ A new process: it loads, warms up every shape the cell uses (all of that
 is `setup_s`), measures for `--seconds`, checks that what the system
 produced is correct, and prints one JSON object as the last line of
 standard output with the keys `correct`, `attempted`, `failed`, `metrics`
-and `device` (and `breakdown` in a traced run).  With `--trace 0` the
-metrics are the cell's end-to-end metrics; with `--trace 1` a short slice
-after the window is traced with jax.profiler and the metrics are the
-cell's per-layer metrics.  Everything else worth reading is on earlier
-`[bench] {...}` lines.
+and `device` (and `breakdown` in a traced run), then `compared`: each
+number `correct` was decided by beside its limit, which are also the last
+lines on standard error.  With `--trace 0` the metrics are the cell's
+end-to-end metrics; with `--trace 1` a short slice after the window is
+traced with jax.profiler and the metrics are the cell's per-layer metrics.
+Everything else worth reading is on earlier `[bench] {...}` lines.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 3 and
 prints no result.  `--rehearse` runs the tiny preset the cell's files
@@ -118,10 +119,19 @@ def main(argv=None, root=ROOT):
               compiles_in_window=bench.compiles_in_window,
               left_out=[m["name"] for m in wanted
                         if m["name"] not in metrics])
+    # each number `correct` was decided by, beside its limit: last on
+    # standard error and last in the result's line
+    compared = bench.compared_record()
+    compared["problems"] = {"value": len(problems), "limit": 0}
+    sys.stdout.flush()
+    for name, pair in compared.items():
+        print("compared %s %r limit %r" % (name, pair["value"],
+                                           pair["limit"]), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(dict(
         correct=not problems, attempted=outcome["attempted"],
-        failed=outcome["failed"], metrics=metrics, device=device, **traced)),
-        flush=True)
+        failed=outcome["failed"], metrics=metrics, device=device, **traced,
+        compared=compared)), flush=True)
     return 0
 
 

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 import scipy.sparse as sp
 
+import manifest_shape as shape
 from bench_overlay import REPO, copy_of_the_benchmark
 from benchmarks import run
 from benchmarks.data import allstate
@@ -201,53 +202,7 @@ def test_scan_glue_reads_the_scope_of_the_scan():
 
 # ---- the cell --------------------------------------------------------------
 def test_the_manifest_lists_the_cell_where_the_issue_says():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        m = json.load(f)
-    (entry,) = [w for w in m["workloads"] if w["name"] == CELL]
-    assert (entry["config"], entry["traffic"], entry["chips"]) \
-        == ("allstate-onehot-int8", "train-fullbag-sparse", 1)
-    (config,) = [c for c in m["configs"] if c["name"] == entry["config"]]
-    assert config["reduced"] == ["num_iterations"]
-    listed = {x["name"] for x in m["end_to_end"] + m["per_layer"]
-              if CELL in x.get("workloads", ())}
-    assert listed == {"train_iter_ms", "setup.bin_s", "setup.bin_256k_s",
-                      "setup.warmup_s", "kernel.root.ms_per_iter",
-                      "fused_root_roofline", "xla.quantize.ms_per_iter",
-                      "partition_root_roofline", "xla.scan_glue.ms_per_iter",
-                      "split_scan_roofline"}
-    with open(os.path.join(REPO, config["file"])) as f:
-        c = json.load(f)
-    assert c["source"] == config["source"]
-    assert c["data"]["rows"] == c["published"]["rows"] == 13_184_290
-    assert c["data"]["features"] == c["published"]["features"] == 4228
-    assert sum(c["data"]["args"]["cardinalities"]) == 4228
-    for key in ("num_leaves", "learning_rate", "max_bin"):
-        assert c["params"][key] == c["published"][key], key
-    # the block of the Higgs row of the same table, and bundling at defaults
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           "higgs-binary-int8.json")) as f:
-        higgs = json.load(f)["params"]
-    assert {k: c["params"][k] for k in higgs} == higgs
-    # nothing pins the rounding's seed: it is `seed`, which --seed sets
-    assert set(c["params"]) - set(higgs) == {
-        "enable_bundle", "max_conflict_rate"}
-    assert c["seed_params"] == ["seed"]
-    # the source's block where the run departs from it, and the departure
-    assert c["published"]["min_data_in_leaf"] == 0
-    assert c["published"]["min_sum_hessian_in_leaf"] == 100
-    assert {"min_data_in_leaf", "min_sum_hessian_in_leaf", "why",
-            "effect"} <= set(c["departs"])
-    assert c["reduced"] == ["num_iterations"]
-    assert c["params"]["enable_bundle"] is True
-    assert c["params"]["max_conflict_rate"] == 0
-    assert c["expect"] == {"engine": "partition", "quantized": True,
-                           "carried": True, "scan_space": "group"}
-    assert 0 < c["correct"]["conflict_rows_max_share"] < 1e-2
-    for key in ("gain_rtol", "leaf_value_rtol", "leaf_value_atol_of_largest",
-                "f32_band", "own_band", "walker_atol"):
-        with open(os.path.join(REPO, "benchmarks", "configs",
-                               "higgs-binary-int8.json")) as f:
-            assert c["correct"][key] == json.load(f)["correct"][key], key
+    shape.check_allstate_is_listed_where_pr_32_says(REPO)
 
 
 def test_the_harness_finds_the_new_kind_by_the_traffic_files_name():

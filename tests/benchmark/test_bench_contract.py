@@ -10,15 +10,20 @@ from bench_overlay import (add_predict_cell, add_train_cell,
                            copy_of_the_benchmark)
 from benchmarks import run
 
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                 "compared"}
 
 
-def _last_line(capsys):
-    out = capsys.readouterr().out.strip().splitlines()
+def _parsed(stdout):
+    out = stdout.strip().splitlines()
     # the program logs to standard output too; the result is the last line
     return json.loads(out[-1]), [json.loads(line[len("[bench] "):])
                                  for line in out[:-1]
                                  if line.startswith("[bench] ")]
+
+
+def _last_line(capsys):
+    return _parsed(capsys.readouterr().out)
 
 
 def test_without_a_tpu_nothing_is_measured(capsys):
@@ -54,8 +59,24 @@ def test_rehearsal_prints_the_contracts_line_and_no_time(
         add_predict_cell(root)
     assert run.main(["--workload", cell, "--seed", "3", "--seconds", "0.5",
                      "--trace", str(trace), "--rehearse"], root=root) == 0
-    last, said = _last_line(capsys)
+    captured = capsys.readouterr()
+    (last, said), err = _parsed(captured.out), captured.err
     assert set(last) == CONTRACT_KEYS          # a CPU trace has no breakdown
+    # each number compared beside its limit, last in the line and last on
+    # standard error, every one within its limit in a run that is correct
+    assert list(last)[-1] == "compared"
+    compared = last["compared"]
+    assert compared["problems"] == {"value": 0, "limit": 0}
+    assert "walker_diff" in compared
+    if cell.endswith(".train"):
+        assert {"gain_shortfall", "leaf_value_off_of_allowed",
+                "f32_quality_gap", "own_quality_gap"} <= set(compared)
+    assert all(0 <= pair["value"] <= pair["limit"]
+               for pair in compared.values()), compared
+    told = [line.split() for line in err.strip().splitlines()
+            if line.startswith("compared ")]
+    assert [t[1] for t in told] == list(compared)
+    assert err.strip().splitlines()[-1].startswith("compared problems 0 ")
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] >= 1
     assert last["device"]["platform"] == "cpu"

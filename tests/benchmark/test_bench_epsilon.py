@@ -14,6 +14,7 @@ import pytest
 from benchmarks import run
 from benchmarks.harness import costs_partition, manifest
 from benchmarks.harness import trace_reduce as tr
+import manifest_shape as shape
 from bench_overlay import REPO, copy_of_the_benchmark
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -119,29 +120,7 @@ def test_reader_returns_nothing_when_there_is_nothing_to_read(scoped):
 
 
 def test_the_manifest_lists_the_cell_where_the_issue_says():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        m = json.load(f)
-    cell = "epsilon-int8.train"
-    (entry,) = [w for w in m["workloads"] if w["name"] == cell]
-    assert (entry["config"], entry["traffic"], entry["chips"]) \
-        == ("epsilon-dense-int8", "train-fullbag", 1)
-    (config,) = [c for c in m["configs"] if c["name"] == entry["config"]]
-    assert config["reduced"] == ["num_iterations"]
-    listed = {x["name"] for x in m["end_to_end"] + m["per_layer"]
-              if cell in x.get("workloads", ())}
-    assert listed == {"train_iter_ms", "setup.bin_s", "setup.bin_256k_s",
-                      "setup.warmup_s", "kernel.root.ms_per_iter",
-                      "fused_root_roofline", "xla.quantize.ms_per_iter",
-                      METRIC}
-    with open(os.path.join(REPO, config["file"])) as f:
-        c = json.load(f)
-    assert c["data"]["rows"] == c["published"]["rows"] == 400_000
-    assert c["data"]["features"] == c["published"]["features"] == 2000
-    for key in ("num_leaves", "learning_rate", "max_bin", "min_data_in_leaf",
-                "min_sum_hessian_in_leaf"):
-        assert c["params"][key] == c["published"][key], key
-    assert c["expect"] == {"engine": "partition", "quantized": True,
-                           "carried": True}
+    shape.check_epsilon_is_listed_where_pr_27_says(REPO)
 
 
 def test_the_cell_rehearses_in_channel_blocks(tmp_path, capsys):
@@ -167,3 +146,51 @@ def test_the_cell_rehearses_in_channel_blocks(tmp_path, capsys):
                if line.startswith('[bench] {"what": "quality"')]
     assert quality[0]["path"] == {"engine": "partition", "quantized": True,
                                   "spine": "fused", "carried": True}
+
+
+# ---- the hessian floor's control, at a size a test run can hold (PR 38) ----
+def _check_lines(capsys):
+    lines = capsys.readouterr().out.strip().splitlines()
+    said = [json.loads(line[len("[bench] "):]) for line in lines
+            if line.startswith("[bench] ")]
+    checks_ = [json.loads(line[len("[check] "):]) for line in lines
+               if line.startswith("[check] ")]
+    return json.loads(lines[-1]), checks_, said
+
+
+def test_a_system_at_half_the_hessian_floor_is_not_correct(tmp_path, capsys):
+    """The control of `bound_rtol` (on the chip at the cell's own size:
+    PERF.md section 6, PR 38): the system's boosters trained at half the
+    floor the reference holds them to.  The tiny preset with a floor that
+    binds: 2 048 rows of hessian 0.25 against a floor of 100 allow five
+    leaves of 400 rows; at 50 the system grows leaves of 200."""
+    from benchmarks import check_seeds
+    root = copy_of_the_benchmark(tmp_path)
+    path = os.path.join(root, "benchmarks", "configs",
+                        "epsilon-dense-int8.json")
+    with open(path) as f:
+        config = json.load(f)
+    assert config["correct"]["bound_rtol"] == 1e-5
+    assert config["correct"]["bound_rtol_why"]
+    config["rehearse"]["params"]["min_sum_hessian_in_leaf"] = 100
+    with open(path, "w") as f:
+        json.dump(config, f)
+    argv = ["--workload", "epsilon-int8.train", "--seeds", "2147483693",
+            "--rehearse"]
+    assert check_seeds.main(argv, root=root) == 0
+    last, (sound,), said = _check_lines(capsys)
+    assert sound["correct"] is True and sound["problems"] == []
+    assert last == {"cell": "epsilon-int8.train", "platform": "cpu",
+                    "seeds": 1, "correct": 1, "system_params": {}}
+    assert check_seeds.main(
+        argv + ["--system-params", '{"min_sum_hessian_in_leaf": 50}'],
+        root=root) == 0
+    last, (control,), said = _check_lines(capsys)
+    assert last["correct"] == 0 and control["correct"] is False
+    assert "the reference does not accept" in control["problems"][0]
+    # the line names the miss: a child between the two floors
+    (line,) = [s for s in said if s["what"] == "reference-check"]
+    chosen = line["miss"]["chosen"]
+    assert chosen["allowed_gain"] is None or chosen["allowed_gain"] < 0
+    assert -0.5 <= min(chosen["hessian_over_floor_rel"]) < -1e-3
+    assert min(chosen["rows"]) >= 200

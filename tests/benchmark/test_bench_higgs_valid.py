@@ -12,14 +12,14 @@ import types
 import numpy as np
 import pytest
 
+import manifest_shape as shape
 from bench_overlay import REPO, copy_of_the_benchmark
 from benchmarks import run
 from benchmarks.harness import checks_valid, manifest
 from benchmarks.reference import metrics, walker
 
 CELL = "higgs-valid.train"
-NEW_METRICS = {"xla.valid_score.ms_per_iter", "xla.valid_metric.ms_per_iter",
-               "entry.eval_host_ms_per_iter"}
+NEW_METRICS = set(shape.VALID_THREE)
 
 
 def _cell(rehearse=False):
@@ -28,45 +28,11 @@ def _cell(rehearse=False):
 
 # ---- the manifest --------------------------------------------------------
 def test_the_manifest_lists_the_cell_as_the_issue_names_it():
-    m = manifest.load_json(REPO, "BENCHMARK.json")
-    entry, = [w for w in m["workloads"] if w["name"] == CELL]
-    assert entry == {
-        "name": CELL, "config": "higgs-binary-int8-valid",
-        "traffic": "train-eval", "chips": 1, "why": entry["why"]}
-    assert entry is m["workloads"][-1] and len(entry["why"]) <= 200
-    assert "eval_valid()" in entry["why"] and "500K" in entry["why"]
-    config = m["configs"][-1]
-    assert config["name"] == "higgs-binary-int8-valid"
-    assert config["file"] == "benchmarks/configs/higgs-binary-int8-valid.json"
-    assert config["reduced"] == ["num_iterations"]
-    # a source of its own, the accuracy table's row
-    assert config["source"].endswith("Experiments.rst?plain=1#L127")
-    assert config["source"] not in [c["source"] for c in m["configs"][:-1]]
+    shape.check_higgs_valid_is_listed_as_pr_34_names_it(REPO)
 
 
 def test_the_cell_reports_the_train_metrics_and_its_own_three():
-    m = manifest.load_json(REPO, "BENCHMARK.json")
-    cell = _cell()
-    assert [e["name"] for e in cell.end_to_end] == [
-        "train_iter_ms", "peak_hbm_gib", "setup_s"]
-    names = {p["name"] for p in cell.per_layer}
-    assert NEW_METRICS <= names
-    # what an int8 training cell on the carried spine reports, all of it
-    headline = {p["name"] for p in
-                manifest.Cell(REPO, "higgs-int8.train").per_layer}
-    assert names - NEW_METRICS == headline
-    for p in m["per_layer"]:
-        if p["name"] in NEW_METRICS:
-            assert p["workloads"] == [CELL] and p["moves"] == "train_iter_ms"
-            assert p["unit"] == "ms" and p["better"] == "lower"
-    # appended, every one: nothing the benchmark had moved
-    assert [p["name"] for p in m["per_layer"][-3:]] == [
-        "xla.valid_score.ms_per_iter", "xla.valid_metric.ms_per_iter",
-        "entry.eval_host_ms_per_iter"]
-    for metric, reader_args, reader in cell.layer_readers():
-        if metric["name"] in NEW_METRICS:
-            assert reader.__name__.endswith(
-                ("trace_scope", "program_span")), reader.__name__
+    shape.check_higgs_valid_reports_the_train_metrics_and_its_own_three(REPO)
 
 
 def test_the_configuration_states_its_source_its_cut_and_its_guesses():

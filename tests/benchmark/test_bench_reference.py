@@ -152,3 +152,158 @@ def test_walker_handles_missing_values_as_the_reference_project_does():
     assert list(walker.raw_scores(text, X)) == [30.0, 10.0, 20.0, 20.0]
     with pytest.raises(ValueError, match="categorical"):
         walker.parse_model(text.replace("num_cat=0", "num_cat=1"))
+
+
+# ---- the hessian floor within the rounding of a float32 sum (PR 38) --------
+FLOOR = 100.0
+BAND = 1e-5
+
+
+def _leaf_at_the_floor(off):
+    """1 000 rows, two columns of two bins.  Column 0 parts the first 400
+    rows, whose hessians sum to FLOOR * (1 + off), from the other 600 and
+    has by far the larger gain; column 1 parts 500 from 500, both far over
+    the floor.  -> (bins, num_bins, grad, hess)"""
+    bins = np.zeros((1000, 2), np.uint8)
+    bins[400:, 0] = 1
+    bins[::2, 1] = 1
+    hess = np.full(1000, 0.25)
+    hess[:400] = FLOOR * (1 + off) / 400
+    grad = np.where(bins[:, 0] == 0, -0.5, 0.3) + 0.1 * (bins[:, 1] - 0.5)
+    return bins, np.array([2, 2]), grad, hess
+
+
+def _judge(off, splits, bound_rtol):
+    rules = grower.SplitRules(
+        {"num_leaves": 2, "min_data_in_leaf": 1,
+         "min_sum_hessian_in_leaf": FLOOR}, bound_rtol)
+    return grower.replay(*_leaf_at_the_floor(off), rules, splits, 1e-3)[1]
+
+
+def test_a_child_a_rounding_under_the_floor_is_allowed_within_the_band():
+    """Epsilon's case: 400 rows of one hessian sum to a hair under the
+    floor in float64 and onto it in float32; the system takes the child."""
+    took_column_0 = [(0, 0, 0)]
+    assert _judge(-5e-7, took_column_0, BAND) == []
+    (miss,) = _judge(-5e-7, took_column_0, 0.0)
+    assert miss[0] == 0 and miss[1] == -np.inf and miss[2] > 0
+    # a child a thousandth under the floor is refused with the band too
+    for bound_rtol in (BAND, 0.0):
+        (miss,) = _judge(-1e-3, took_column_0, bound_rtol)
+        assert miss[:2] == (0, -np.inf), bound_rtol
+    # and on the floor or over it the band changes nothing
+    for off in (0.0, 5e-7, 1e-3):
+        assert _judge(off, took_column_0, BAND) == []
+        assert _judge(off, took_column_0, 0.0) == []
+
+
+def test_a_child_a_rounding_over_the_floor_is_not_demanded_within_the_band():
+    """The other direction: float32 can round a sum down from the floor,
+    and the system then does not see the split the reference likes best."""
+    took_column_1 = [(0, 1, 0)]
+    assert _judge(5e-7, took_column_1, BAND) == []
+    (miss,) = _judge(5e-7, took_column_1, 0.0)
+    assert miss[0] == 0 and 0 < miss[1] < miss[2] * (1 - 1e-3)
+    # a thousandth over the floor it is demanded, band or none
+    for bound_rtol in (BAND, 0.0):
+        (miss,) = _judge(1e-3, took_column_1, bound_rtol)
+        assert miss[0] == 0 and miss[1] < miss[2], bound_rtol
+
+
+def test_a_stop_before_a_split_inside_the_band_is_no_early_stop():
+    bins, num_bins, grad, hess = _leaf_at_the_floor(5e-7)
+    one = bins[:, :1], num_bins[:1], grad, hess     # column 0 alone
+    params = {"num_leaves": 2, "min_data_in_leaf": 1,
+              "min_sum_hessian_in_leaf": FLOOR}
+    assert grower.replay(*one, grower.SplitRules(params, BAND), [],
+                         1e-3)[1] == []
+    (miss,) = grower.replay(*one, grower.SplitRules(params), [], 1e-3)[1]
+    assert miss[:2] == (0, None) and miss[2] > 0
+    # a tree that took a split where not even the loose floor allows one
+    (miss,) = grower.replay(bins[:, :1], num_bins[:1], grad,
+                            _leaf_at_the_floor(-1e-3)[3],
+                            grower.SplitRules(params, BAND), [(0, 0, 0)],
+                            1e-3)[1]
+    assert miss == (0, -np.inf, None)
+
+
+def test_the_refused_split_is_described_by_its_floors():
+    bins, num_bins, grad, hess = _leaf_at_the_floor(-5e-7)
+    rules = grower.SplitRules({"num_leaves": 2, "min_data_in_leaf": 1,
+                               "min_sum_hessian_in_leaf": FLOOR})
+    told = grower.explain_miss(bins, num_bins, grad, hess, rules,
+                               [(0, 0, 0)], 0)
+    assert told["step"] == 0 and told["leaves"] == 1
+    chosen, best = told["chosen"], told["best"]
+    assert (chosen["column"], best["column"]) == (0, 1)
+    assert chosen["rows"] == [400, 600] and best["rows"] == [500, 500]
+    assert chosen["allowed_gain"] == -np.inf < best["gain"] < chosen["gain"]
+    assert chosen["hessian_over_floor_rel"][0] == pytest.approx(-5e-7,
+                                                                rel=1e-6)
+    assert chosen["rows_over_floor"] == [399, 599]
+    assert best["allowed_gain"] == best["gain"]
+
+
+def _plain_gains(g, hist):
+    """reference/grower.py's `_gains` as it stood before PR 38: every floor
+    a bare float64 comparison."""
+    r = g.rules
+    left = np.cumsum(hist, axis=1)
+    total = left[:, -1:, :]
+    right = total - left
+    ok = (g._real
+          & (left[:, :, 2] >= r.min_data_in_leaf)
+          & (right[:, :, 2] >= r.min_data_in_leaf)
+          & (left[:, :, 1] >= r.min_sum_hessian_in_leaf)
+          & (right[:, :, 1] >= r.min_sum_hessian_in_leaf))
+    with np.errstate(divide="ignore", invalid="ignore"):
+        gain = (grower._leaf_gain(left[:, :, 0], left[:, :, 1], r)
+                + grower._leaf_gain(right[:, :, 0], right[:, :, 1], r)
+                - grower._leaf_gain(total[:, :, 0], total[:, :, 1], r)
+                - r.min_gain_to_split)
+    return np.where(ok & (gain > 0.0), gain, -np.inf)
+
+
+@pytest.mark.parametrize("objective", ["binary", "lambdarank"])
+def test_without_a_band_the_grower_is_the_plain_rule_bit_for_bit(objective):
+    """`bound_rtol` absent or 0: one gain table per leaf, equal to the bare
+    comparison's in every element, after every split of the reference's
+    own tree; `grow` keeps the plain rule whatever the rules' band, and
+    `replay` gives the same verdicts as before on the same choices."""
+    _, y, group, ds = _case(objective)
+    _, (grad, hess) = _first_gradients(objective, y, group)
+    b = ds._binned
+    # a floor that binds: the plain rule must forbid some candidates
+    params = dict(PARAMS, objective=objective,
+                  min_sum_hessian_in_leaf=float(hess.sum() / 8))
+    plain, banded = grower.SplitRules(params), grower.SplitRules(params, BAND)
+    assert plain.bound_rtol == 0.0
+    g = grower.LeafwiseGrower(b.bins, b.feature_num_bins(), grad, hess, plain)
+    while len(g.rows) < plain.num_leaves and g.best() is not None:
+        g.split(*g.best()[1:])
+    assert len(g.rows) > 3
+    forbidden = 0
+    for leaf in g.rows:
+        tight, loose = g._gains(g.hist[leaf])
+        assert tight is loose
+        expected = _plain_gains(g, g.hist[leaf])
+        assert np.array_equal(g.gains[leaf], expected)
+        forbidden += int(np.isneginf(expected[g._real]).sum())
+    assert forbidden
+    own = g.finish()
+    for rules in (plain, banded):
+        tree = grower.grow(b.bins, b.feature_num_bins(), grad, hess, rules)
+        assert tree.split_leaf == own.split_leaf
+        assert tree.split_feature == own.split_feature
+        assert tree.split_bin == own.split_bin
+        assert np.array_equal(tree.leaf_value, own.leaf_value)
+    splits = list(zip(own.split_leaf, own.split_feature, own.split_bin))
+    assert grower.replay(b.bins, b.feature_num_bins(), grad, hess, plain,
+                         splits, 1e-3)[1] == []
+    worse = [(0, (splits[0][1] + 1) % b.bins.shape[1], 127)] + splits[1:]
+    a = grower.replay(b.bins, b.feature_num_bins(), grad, hess, plain,
+                      worse, 1e-3)
+    z = grower.replay(b.bins, b.feature_num_bins(), grad, hess,
+                      grower.SplitRules(params, 0.0), worse, 1e-3)
+    assert a[1] == z[1] and a[1] and a[1][0][0] == 0
+    assert np.array_equal(a[0].leaf_count, z[0].leaf_count)

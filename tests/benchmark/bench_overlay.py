@@ -4,6 +4,8 @@ import json
 import os
 import shutil
 
+import manifest_shape as shape
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -27,14 +29,31 @@ def edit_manifest(root, edit):
     return manifest
 
 
-def add_train_cell(root, name="higgs-int8.train-bagged",
-                   config="higgs-binary-int8", traffic="train-bagged",
-                   like="higgs-int8.train"):
-    """A further training cell over files the benchmark already has — by
-    default the bagged one PERF.md keeps for later (on the chip its
-    iteration time moves with each tree's depth, too much for a bound).
-    It joins every metric's `workloads` list that the cell `like` it (same
-    kind of traffic, same precision) is in."""
+def write_new(root, rel, spec):
+    """benchmarks/<rel>, which must not exist yet: a later PR only adds
+    files.  `spec` is the file's text, or an object written as JSON."""
+    path = os.path.join(root, "benchmarks", rel)
+    assert not os.path.exists(path), "a later PR only adds files"
+    with open(path, "w") as f:
+        f.write(spec if isinstance(spec, str) else json.dumps(spec))
+
+
+def add_probe_mix(root, name=shape.PROBE_MIX, **changes):
+    """traffic/train-fullbag.json under a name of the reserved prefix, with
+    `changes` laid over its top-level keys."""
+    assert name.startswith(shape.PROBE), name
+    mix = dict(shape.load(root, "benchmarks", "traffic", "train-fullbag.json"),
+               **changes)
+    write_new(root, "traffic/%s.json" % name, mix)
+    return mix
+
+
+def add_train_cell(root, name=shape.PROBE_CELL, config="higgs-binary-int8",
+                   traffic=shape.PROBE_MIX, like="higgs-int8.train"):
+    """A further training cell, under the reserved prefix by default, over
+    a mix that add_probe_mix wrote (or any mix the copy has).  It joins
+    every metric's `workloads` list that the cell `like` it (same kind of
+    traffic, same precision) is in."""
     def edit(manifest):
         manifest["workloads"].append({
             "name": name, "config": config, "traffic": traffic, "chips": 1,

@@ -23,6 +23,16 @@ LEDGER_SEVEN = ["kernel.partition.row_passes_per_iter",
                 "kernel.partition.ms_per_pass", "partition_roofline",
                 "kernel.partition.call_us", "kernel.seg_hist.ms_per_pass",
                 "seg_hist_roofline", "kernel.seg_hist.call_us"]
+# The prefix only tests give what they append to a copy of the benchmark,
+# so that no name a later PR takes is claimed here first.  The appended-cell
+# fixture's five: a cell, a configuration, a traffic mix, a check
+# (harness/checks_<PROBE_CHECK>.py) and a per-layer metric.
+PROBE = "probe"
+PROBE_CELL = "probe-appended.train"
+PROBE_CONFIG = "probe-config"
+PROBE_MIX = "probe-mix"
+PROBE_CHECK = "probe"
+PROBE_METRIC = "probe.appended_ms_per_iter"
 
 
 def load(root, *parts):
@@ -156,6 +166,44 @@ def check_full_check_fits_the_drivers_budget(root):
     total = ((2 + 14 * cells) * (manifest_of(root)["run_seconds"] + 60)
              + cells * 2 * 90 + 1200)
     assert total <= 43200
+
+
+def probes_in(root):
+    """Everything in the checkout that carries the reserved prefix: the
+    manifest's names, the checks configurations name, and the files under
+    benchmarks/ (a check's file by the name after `checks_`)."""
+    manifest = manifest_of(root)
+    found = {("name", e["name"]) for key in ("configs", "workloads",
+                                              "end_to_end", "per_layer")
+             for e in manifest[key] if e["name"].startswith(PROBE)}
+    found |= {("traffic", w["traffic"]) for w in manifest["workloads"]
+              if w["traffic"].startswith(PROBE)}
+    for c in manifest["configs"]:
+        check = load(root, c["file"]).get("correct", {}).get("check", "")
+        if check.startswith(PROBE):
+            found.add(("check", check))
+    for folder, dirs, files in os.walk(os.path.join(root, "benchmarks")):
+        # what runs leave behind (compiled modules, caches) is no entry
+        dirs[:] = [d for d in dirs
+                   if not (d.startswith(".") or d == "__pycache__")]
+        for f in files:
+            if f.startswith(PROBE) or f.startswith("checks_" + PROBE):
+                found.add(("file", os.path.relpath(os.path.join(folder, f),
+                                                   root)))
+    return found
+
+
+def check_the_probe_prefix_is_the_appended_fixtures_alone(root):
+    """Nothing real takes the reserved prefix: a checkout holds none of it,
+    or exactly what the appended-cell fixture appends."""
+    fixture = {("name", PROBE_CELL), ("name", PROBE_CONFIG),
+               ("name", PROBE_METRIC), ("traffic", PROBE_MIX),
+               ("check", PROBE_CHECK),
+               ("file", "benchmarks/configs/%s.json" % PROBE_CONFIG),
+               ("file", "benchmarks/traffic/%s.json" % PROBE_MIX),
+               ("file", "benchmarks/harness/checks_%s.py" % PROBE_CHECK),
+               ("file", "benchmarks/layer_metrics/%s.json" % PROBE_METRIC)}
+    assert probes_in(root) in (set(), fixture), probes_in(root) ^ fixture
 
 
 def check_the_fused_root_metrics_list_only_int8_cells(root):

@@ -2,13 +2,13 @@
 it refuses to measure; with --rehearse it runs the cell's tiny preset
 through every phase, names the platform it ran on and prints no time."""
 import json
-import os
 
 import pytest
 
-from bench_overlay import (add_predict_cell, add_train_cell,
-                           copy_of_the_benchmark)
+from bench_overlay import (REPO, add_predict_cell, add_probe_mix,
+                           add_train_cell, copy_of_the_benchmark)
 from benchmarks import run
+from benchmarks.harness import manifest
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                  "compared"}
@@ -97,24 +97,55 @@ def test_rehearsal_prints_the_contracts_line_and_no_time(
                        "compiles_in_window": 0}
 
 
+# upstream examples/binary_classification/train.conf's sampling block, with
+# its bags and column subsets fixed by the mix's own seeds
+SAMPLED = {"bagging_fraction": 0.8, "bagging_freq": 5,
+           "feature_fraction": 0.8, "bagging_seed": 3,
+           "feature_fraction_seed": 2}
+
+
+def _stated(root, cell):
+    """The path a cell's files state, as drivers/train.py compares it."""
+    c = manifest.Cell(root, cell, rehearse=True)
+    want = dict(c.config["expect"], **c.traffic["expect"])
+    if want["spine"] != "fused":
+        want["carried"] = False
+    return want
+
+
 @pytest.mark.parametrize("cell,path", [
-    ("higgs-int8.train-bagged",
-     {"engine": "partition", "quantized": True, "spine": "unfused",
-      "carried": False}),
+    ("probe-sampled.train", None),
     ("mslr-rank.train",
      {"engine": "partition", "quantized": False, "spine": "fused",
       "carried": False}),
 ])
 def test_rehearsal_takes_the_path_the_cells_files_state(tmp_path, capsys,
                                                         cell, path):
+    """A cell is correct exactly when it took the path its files state.  A
+    sampled mix may take whichever spine the program gives a row bag: the
+    run is held to what it reports, not to a spine."""
     root = copy_of_the_benchmark(tmp_path)
-    if cell.endswith("-bagged"):
-        add_train_cell(root)
+    if path is None:
+        # blocks of one and one traced iteration: the CPU walks the out-of-bag
+        # rows of every iteration of the unfused spine
+        add_probe_mix(root, "probe-sampled", params=SAMPLED,
+                      expect={"spine": "unfused"},
+                      rehearse={"warmup_iterations": 2, "block_iterations": 1,
+                                "trace_iterations": 1})
+        add_train_cell(root, cell, traffic="probe-sampled")
     assert run.main(["--workload", cell, "--seed", "4", "--seconds", "0.5",
                      "--trace", "1", "--rehearse"], root=root) == 0
     last, said = _last_line(capsys)
-    assert last["correct"] is True, said
-    assert [s for s in said if s["what"] == "quality"][0]["path"] == path
+    took = [s for s in said if s["what"] == "quality"][0]["path"]
+    stated = path or _stated(root, cell)
+    assert path is None or took == path
+    assert last["correct"] is (took == stated), said
+    problems = [s for s in said if s["what"] == "verdict"][0]["problems"]
+    if took["spine"] != stated["spine"]:
+        assert any("spine is %r" % took["spine"] in p for p in problems), \
+            problems
+    if took["spine"] == "unfused":
+        assert took["carried"] is False
     assert [s for s in said if s["what"] == "reference-check"]
     # the traced slice ran and was looked for; a CPU trace has no chip in it
     trace = [s for s in said if s["what"] == "trace"][0]
@@ -124,19 +155,61 @@ def test_rehearsal_takes_the_path_the_cells_files_state(tmp_path, capsys,
 
 def test_a_cell_on_another_path_than_it_states_is_not_correct(tmp_path,
                                                               capsys):
-    """`expect` is checked, not assumed: a bagged mix that states the fused
-    spine fails the run rather than changing the cell."""
+    """`expect` is checked, not assumed: the full-bag Higgs mix, whose fused
+    spine `higgs-int8.train` checks in every run, stating the unfused one
+    fails the run rather than changing the cell."""
     root = copy_of_the_benchmark(tmp_path)
-    with open(os.path.join(root, "benchmarks", "traffic",
-                           "train-bagged.json")) as f:
-        mix = dict(json.load(f), expect={"spine": "fused"})
-    with open(os.path.join(root, "benchmarks", "traffic",
-                           "train-misstated.json"), "w") as f:
-        json.dump(mix, f)
-    add_train_cell(root, "higgs-int8.misstated", traffic="train-misstated")
-    assert run.main(["--workload", "higgs-int8.misstated", "--seed", "4",
+    add_probe_mix(root, "probe-misstated", expect={"spine": "unfused"})
+    add_train_cell(root, "probe-misstated.train", traffic="probe-misstated")
+    assert run.main(["--workload", "probe-misstated.train", "--seed", "4",
                      "--seconds", "0.5", "--rehearse"], root=root) == 0
     last, said = _last_line(capsys)
     assert last["correct"] is False
     problems = [s for s in said if s["what"] == "verdict"][0]["problems"]
-    assert any("spine is 'unfused'" in p for p in problems), problems
+    assert any("spine is 'fused'" in p for p in problems), problems
+
+
+class _Booster:
+    """What drivers/train.py reads off a booster to say which path it took."""
+
+    def __init__(self, engine="partition", quantized=True, spine="fused",
+                 carried=True):
+        self.path = {"engine": engine, "quantized": quantized,
+                     "spine": spine, "carried": carried}
+        self._use_partition_engine = engine == "partition"
+        self._quantized = quantized
+        self._fused_validated = spine == "fused"
+        self._carried_active = carried
+
+
+class _Cell:
+    def __init__(self, config_expect, traffic_expect):
+        self.config = {"expect": config_expect}
+        self.traffic = {"expect": traffic_expect}
+
+
+HIGGS = {"engine": "partition", "quantized": True, "carried": True}
+
+
+@pytest.mark.parametrize("booster,traffic,wrong", [
+    # the headline as stated
+    (_Booster(), {"spine": "fused"}, []),
+    # only the fused spine can carry: stating unfused states not carried,
+    # whatever the configuration says
+    (_Booster(spine="unfused", carried=False), {"spine": "unfused"}, []),
+    (_Booster(), {"spine": "unfused"}, ["spine", "carried"]),
+    (_Booster(spine="unfused", carried=False), {"spine": "fused"},
+     ["spine", "carried"]),
+    # every key is compared
+    (_Booster(carried=False), {"spine": "fused"}, ["carried"]),
+    (_Booster(engine="label"), {"spine": "fused"}, ["engine"]),
+    (_Booster(quantized=False), {"spine": "fused"}, ["quantized"]),
+], ids=["as-stated", "unfused-not-carried", "fused-stated-unfused",
+        "unfused-stated-fused", "not-carried", "label-engine", "float32"])
+def test_path_problems_name_each_key_that_differs(booster, traffic, wrong):
+    train = manifest.load_module(REPO, "drivers", "train")
+    took, problems = train._path_problems(booster, _Cell(HIGGS, traffic))
+    assert took == booster.path
+    assert [p.split()[1] for p in problems] == wrong
+    for key, p in zip(wrong, problems):
+        assert "is %r" % took[key] in p

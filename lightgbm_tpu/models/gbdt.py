@@ -1444,7 +1444,10 @@ class GBDT:
                         # the two the split scan's rows are
                         groups=n_groups,
                         features=self.train_set.num_features,
-                        scan_space=self._scan_space)
+                        scan_space=self._scan_space,
+                        # what the objective moves on the device each
+                        # iteration (lambdarank's query windows)
+                        **getattr(self.objective, "device_plan", {}))
             log.info("partition engine plan: %s", ", ".join(
                 "%s=%s" % kv for kv in plan.items()))
         self._engine_plan = plan

@@ -5,7 +5,9 @@ reference's per-query O(n^2) pairwise OMP loop runs fully on device as
 padded size-bucketed query blocks (ops/ranking.py DeviceLambdarank) — a
 handful of jitted dispatches per iteration regardless of query count.
 The device path never sorts: it sums the pairs in slot order and gets each
-document's rank discount from a count over its query.  The numpy per-query
+document's rank discount from a count over its query, and it moves scores
+and gradients between rows and slots by whole query windows of aligned
+128-lane rows, not element by element.  The numpy per-query
 path (`_one_query`), which sorts as the reference does, is kept as the
 parity oracle.
 
@@ -62,6 +64,8 @@ class LambdarankNDCG(ObjectiveFunction):
             self.inverse_max_dcgs, self.sigmoid, dtype=dtype)
         self._weights_dev = (jnp.asarray(self.weights_np, dtype)
                             if self.weights_np is not None else None)
+        # the query windows each iteration moves between rows and slots
+        self.device_plan = self._device.qb.plan()
 
     def get_gradients(self, score):
         grad, hess = self._device(score)

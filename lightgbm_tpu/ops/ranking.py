@@ -15,7 +15,19 @@ masked padding: on the chip a per-element indexed move costs 7-10 ns, a
 vector operation on the same element a thousandth of that (PERF.md §7).
 NDCG still sorts: it runs once per evaluation, not per iteration.
 
-All statics (index maps, sorted label gains, inverse max DCG) are
+Rows and slots meet by whole query windows.  A query's rows are contiguous
+in row order, [start, start + count), count <= S.  Viewed as [R, 128]
+lane-dense rows, the vector holds that window in the W = ceil((S + 127) /
+128) aligned rows from start // 128 on, shifted by start % 128 lanes.  So
+the scores reach the slots by a gather of W whole rows a query and a shift
+by the query's lane offset (seven selects between two static slices, one
+a bit of the offset); lambdas and hessians go back by the inverse shift of
+zero-tailed windows and a scatter-add of whole rows, where neighbouring
+queries add only exact zeros into each other's rows.  No element moves by
+an index on the per-iteration path: each index moves a row of 128 (at the
+MSLR shape 37 800 rows each way in place of 2.42 M elements; PERF.md §6).
+
+All statics (window rows and shifts, label gains, inverse max DCG) are
 computed once at init; only scores stream through per iteration.
 """
 from __future__ import annotations
@@ -36,6 +48,8 @@ _BUCKET_MIN = 8
 # chunk moves the call by a tenth and the peak not at all; what the loop
 # buys is the whole bucket not being one [Q, S, S] fusion (17.1 ms).
 _CHUNK_BUDGET = 1 << 23
+# rows and slots meet in whole rows of this many lanes
+LANES = 128
 
 
 def _bucket_size(sz: int) -> int:
@@ -45,12 +59,21 @@ def _bucket_size(sz: int) -> int:
     return b
 
 
+def _window_rows(S: int) -> int:
+    """Aligned rows of LANES that hold S slots starting at any lane."""
+    return (S + 2 * LANES - 2) // LANES
+
+
 class QueryBuckets:
     """Static padded layout of queries grouped by size class.
 
-    For each bucket: `idx` [Q, S] int32 row indices into the data arrays
-    (padding = n, a sentinel one past the end), plus the query ids [Q]
-    for per-query scalars.
+    For each bucket, in `buckets`: `idx` [Q, S] int32 row indices into the
+    data arrays (padding = n, a sentinel one past the end), plus the query
+    ids [Q] for per-query scalars.  In `windows`, the same queries as
+    windows of the [num_rows, LANES] view of the data: `rows` [Q, W] int32,
+    the aligned rows from start // LANES on that hold each query's rows
+    (W = _window_rows(S)); `shift` [Q] int32, start % LANES, the lane at
+    which the query's first row lies in the first of them.
     """
 
     def __init__(self, query_boundaries: np.ndarray, num_data: int):
@@ -64,6 +87,8 @@ class QueryBuckets:
                 continue
             by_bucket.setdefault(_bucket_size(int(sz)), []).append(q)
         self.buckets = []           # list of (idx [Q,S] i32, qids [Q] i32)
+        self.windows = []           # list of (rows [Q,W] i32, shift [Q] i32)
+        self.num_rows = -(-self.num_data // LANES)
         for S in sorted(by_bucket):
             qids = np.asarray(by_bucket[S], np.int32)
             idx = np.full((len(qids), S), self.num_data, np.int64)
@@ -71,6 +96,74 @@ class QueryBuckets:
                 a, b = qb[q], qb[q + 1]
                 idx[r, :b - a] = np.arange(a, b)
             self.buckets.append((idx.astype(np.int32), qids))
+            start = qb[qids]
+            rows = (start // LANES)[:, None] + np.arange(_window_rows(S))
+            self.windows.append((rows.astype(np.int32),
+                                 (start % LANES).astype(np.int32)))
+            self.num_rows = max(self.num_rows, int(rows[:, -1].max()) + 1)
+
+    def plan(self) -> dict:
+        """What one pass between rows and slots moves, for the trace:
+        per bucket `S:Q:W` (a trace argument holds no comma), the windows,
+        the aligned rows moved each way and the real rows."""
+        return dict(
+            rank_buckets=" ".join("%d:%d:%d" % (idx.shape[1], idx.shape[0],
+                                                rows.shape[1])
+                                  for (idx, _), (rows, _s) in
+                                  zip(self.buckets, self.windows)),
+            rank_windows=sum(len(q) for _, q in self.buckets),
+            rank_rows_moved=sum(rows.size for rows, _ in self.windows),
+            rank_rows=sum(int((idx < self.num_data).sum())
+                          for idx, _ in self.buckets))
+
+
+_SHIFT_BITS = LANES.bit_length() - 1
+
+
+def _bit(shift, k):
+    return ((shift >> k) & 1)[:, None] == 1
+
+
+def _shift_left(x, shift, S: int):
+    """x [Q, >= S + LANES - 1] -> [Q, S], out[q, j] = x[q, j + shift[q]],
+    shift in [0, LANES).  The high bit first: each stage selects between
+    two static slices, and the lanes still needed narrow by its bit."""
+    for k in reversed(range(_SHIFT_BITS)):
+        w = S + (1 << k) - 1
+        x = jnp.where(_bit(shift, k), x[:, 1 << k:(1 << k) + w], x[:, :w])
+    return x
+
+
+def _shift_right(x, shift):
+    """x [..., Q, S] -> [..., Q, S + LANES - 1], out[q, j] = x[q, j -
+    shift[q]] and +0.0 outside it: _shift_left's inverse, the low bit
+    first, each stage a select between the two zero-padded copies."""
+    lead = [(0, 0)] * (x.ndim - 1)
+    for k in range(_SHIFT_BITS):
+        x = jnp.where(_bit(shift, k), jnp.pad(x, lead + [(1 << k, 0)]),
+                      jnp.pad(x, lead + [(0, 1 << k)]))
+    return x
+
+
+@jax.jit
+def _to_slots(rows, win_rows, shift, real):
+    """Scores [Q, S] in slot order from the [R, LANES] view of the rows:
+    exactly ext[idx], -inf in every padded slot."""
+    Q, S = real.shape
+    win = rows[win_rows].reshape(Q, -1)
+    return jnp.where(real, _shift_left(win, shift, S), -jnp.inf)
+
+
+@jax.jit
+def _add_rows(moved, lam, hes, win_rows, shift, real):
+    """moved [2, R, LANES] plus lam and hes [Q, S] at their rows: the
+    windows zero-tailed, shifted onto their aligned rows and added row by
+    row.  Each element receives its one value and exact zeros."""
+    Q, W = win_rows.shape
+    upd = _shift_right(jnp.where(real, jnp.stack([lam, hes]), 0.0), shift)
+    upd = jnp.pad(upd, ((0, 0), (0, 0), (0, W * LANES - upd.shape[-1])))
+    return moved.at[:, win_rows.reshape(-1)].add(
+        upd.reshape(2, Q * W, LANES))
 
 
 def _chunk(Q: int, S: int) -> int:
@@ -168,12 +261,14 @@ class DeviceLambdarank:
         gain_tab = np.asarray(label_gain, np.float64)
         inv = np.asarray(inverse_max_dcgs, np.float64)
         self._buckets = []
-        for idx, qids in self.qb.buckets:
+        for (idx, qids), (rows, shift) in zip(self.qb.buckets,
+                                              self.qb.windows):
             lab_pad = np.full(idx.shape, -1, np.int32)
             real = idx < n
             lab_pad[real] = labels[idx[real]].astype(np.int32)
             self._buckets.append(dict(
-                idx=jnp.asarray(idx),
+                rows=jnp.asarray(rows),
+                shift=jnp.asarray(shift),
                 lab=jnp.asarray(lab_pad.astype(np.float64), dtype),
                 gains=jnp.asarray(
                     np.where(real, gain_tab[np.clip(lab_pad, 0, None)], 0.0),
@@ -186,22 +281,23 @@ class DeviceLambdarank:
 
     def __call__(self, score) -> tuple:
         score = jnp.asarray(score, self.dtype).reshape(-1)
-        ext = jnp.concatenate(
-            [score, jnp.asarray([-jnp.inf], self.dtype)])
-        grad = jnp.zeros(self.n + 1, self.dtype)
-        hess = jnp.zeros(self.n + 1, self.dtype)
+        R = self.qb.num_rows
+        with jax.named_scope("lgbm.gradient.scatter"):
+            rows = jnp.pad(score, (0, R * LANES - self.n)).reshape(R, LANES)
+            moved = jnp.zeros((2, R, LANES), self.dtype)
         for b in self._buckets:
             with jax.named_scope("lgbm.gradient.scatter"):
-                sp = ext[b["idx"]]
+                sp = _to_slots(rows, b["rows"], b["shift"], b["real"])
             lam, hes = _lambda_bucket(sp, b["lab"], b["gains"], b["real"],
                                       b["inv"], b["disc"],
                                       jnp.asarray(self.sigmoid, self.dtype),
                                       chunk=b["chunk"])
             with jax.named_scope("lgbm.gradient.scatter"):
-                flat = jnp.where(b["real"], b["idx"], self.n).reshape(-1)
-                grad = grad.at[flat].add(lam.reshape(-1), mode="drop")
-                hess = hess.at[flat].add(hes.reshape(-1), mode="drop")
-        return grad[:self.n], hess[:self.n]
+                moved = _add_rows(moved, lam, hes, b["rows"], b["shift"],
+                                  b["real"])
+        with jax.named_scope("lgbm.gradient.scatter"):
+            grad, hess = moved.reshape(2, -1)[:, :self.n]
+        return grad, hess
 
 
 @partial(jax.jit, static_argnames=("ks",))

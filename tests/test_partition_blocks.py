@@ -416,7 +416,9 @@ def test_a_new_arena_is_built_after_the_last_boosters_is_let_go():
 
 def test_the_plan_rides_the_set_up_span_and_the_log(tmp_path, capsys):
     """`lgbm:engine_plan` in a profiler trace of booster set-up, with the
-    plan as the annotation's arguments; the same numbers on an Info line."""
+    plan as the annotation's arguments; the same numbers on an Info line.
+    A lambdarank booster's span also carries its query windows: what one
+    iteration moves between rows and slots, as its query sizes imply."""
     import glob
     import lightgbm_tpu as lgb
     from jax.profiler import ProfileData
@@ -424,21 +426,37 @@ def test_the_plan_rides_the_set_up_span_and_the_log(tmp_path, capsys):
     X = rng.randn(256, 520).astype(np.float32)
     ds = lgb.Dataset(X, (X[:, 0] > 0).astype(np.float32),
                      params={"max_bin": 63, "verbose": -1}).construct()
+    sizes = np.array([1, 7, 120, 130, 40, 0, 9])
+    Xr = rng.randn(sizes.sum(), 6).astype(np.float32)
+    ranked = lgb.Dataset(Xr, rng.randint(0, 5, sizes.sum()), group=sizes,
+                         params={"verbose": -1}).construct()
     jax.profiler.start_trace(str(tmp_path))
     try:
         g = lgb.Booster({"objective": "binary", "num_leaves": 7,
                          "max_bin": 63, "verbose": 1,
                          "tpu_quantized_grad": True,
                          "tpu_tree_engine": "partition"}, ds)._gbdt
+        r = lgb.Booster({"objective": "lambdarank", "num_leaves": 7,
+                         "verbose": -1, "tpu_tree_engine": "partition"},
+                        ranked)._gbdt
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
     found = [dict(e.stats) for plane in ProfileData.from_file(path).planes
              for line in plane.lines for e in line.events
              if e.name == "lgbm:engine_plan"]
-    assert found == [g._engine_plan]
+    assert found == [g._engine_plan, r._engine_plan]
     assert found[0]["channels"] == 544 and found[0]["partition_blocks"] == 2
     assert found[0]["arena_bytes"] == 544 * g._arena.shape[1] * 2
+    assert not any(k.startswith("rank_") for k in found[0])
+    # slots: the next power of two from 8; aligned 128-row windows: those
+    # that hold that many slots from any lane
+    slots = [max(8, 1 << int(np.ceil(np.log2(s)))) for s in sizes if s]
+    windows = [-(-(S + 127) // 128) for S in slots]
+    assert found[1]["rank_windows"] == 6
+    assert found[1]["rank_rows_moved"] == sum(windows) == 13
+    assert found[1]["rank_rows"] == sizes.sum() == 307
+    assert found[1]["rank_buckets"] == "8:2:2 16:1:2 64:1:2 128:1:2 256:1:3"
     out = capsys.readouterr().out
     assert "partition engine plan: channels=544, partition_block=272" in out
 

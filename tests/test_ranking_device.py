@@ -275,3 +275,132 @@ def test_lowered_pair_sums_move_no_element_by_index():
 
     old = jax.jit(sort_and_permute).lower(_chunk_args()[0]).as_text()
     assert set(_MOVES.findall(old)) == {"sort", "gather"}
+
+
+# ---- the move between rows and query slots, by whole windows -------------
+def _element_form(dev, score):
+    """The move the windows replaced, kept as the oracle: `ext[idx]` into
+    the slots, two `.at[].add` of single elements back to the rows."""
+    score = jnp.asarray(score, dev.dtype).reshape(-1)
+    ext = jnp.concatenate([score, jnp.asarray([-jnp.inf], dev.dtype)])
+    grad = jnp.zeros(dev.n + 1, dev.dtype)
+    hess = jnp.zeros(dev.n + 1, dev.dtype)
+    slots = []
+    for (idx, _), b in zip(dev.qb.buckets, dev._buckets):
+        sp = ext[idx]
+        slots.append(sp)
+        lam, hes = ranking._lambda_bucket(
+            sp, b["lab"], b["gains"], b["real"], b["inv"], b["disc"],
+            jnp.asarray(dev.sigmoid, dev.dtype), chunk=b["chunk"])
+        flat = jnp.where(b["real"], idx, dev.n).reshape(-1)
+        grad = grad.at[flat].add(lam.reshape(-1), mode="drop")
+        hess = hess.at[flat].add(hes.reshape(-1), mode="drop")
+    return grad[:dev.n], hess[:dev.n], slots
+
+
+def _ragged_sizes():
+    """Query sizes in the buckets S = 8, 32, 128 and 256, one empty query,
+    shuffled; the last query ends at n, and n is no multiple of 128."""
+    r = np.random.RandomState(41)
+    sizes = np.concatenate([r.randint(1, 9, 40), r.randint(17, 33, 12),
+                            r.randint(65, 129, 8), r.randint(129, 257, 5),
+                            [0]])
+    sizes = np.append(r.permutation(sizes), 5)
+    if sizes.sum() % ranking.LANES == 0:
+        sizes[-1] += 1
+    return sizes
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint64 if a.dtype == np.float64 else np.uint32)
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+@pytest.mark.parametrize("scores", ["normal", "ties_and_zeros"])
+def test_window_move_is_the_element_move_bit_for_bit(x64, scores, rng,
+                                                     monkeypatch):
+    """Over a ragged set (four buckets, windows across 128-row boundaries,
+    n % 128 != 0): the slots each bucket hands _lambda_bucket are ext[idx]
+    bit for bit, -inf in every padded slot, and grad and hess are the
+    element form's bit for bit."""
+    sizes = _ragged_sizes()
+    qb = np.concatenate([[0], np.cumsum(sizes)])
+    n = int(qb[-1])
+    assert n % ranking.LANES and sizes[-1] > 0
+    labels = rng.randint(0, 5, n)
+    score = rng.randn(n)
+    if scores == "ties_and_zeros":
+        score = rng.choice([0.0, -0.0, 0.5, -1.25], size=n)
+    handed = []
+    lambda_bucket = ranking._lambda_bucket
+
+    def recording(sp, *args, **kw):
+        handed.append(sp)
+        return lambda_bucket(sp, *args, **kw)
+    prev = jax.config.jax_enable_x64
+    try:
+        jax.config.update("jax_enable_x64", x64)
+        dtype = jnp.float64 if x64 else jnp.float32
+        dev = ranking.DeviceLambdarank(qb, labels, 2.0 ** np.arange(8) - 1,
+                                       1.0 / (1.0 + np.arange(len(sizes))),
+                                       1.0, dtype=dtype)
+        want_g, want_h, want_slots = _element_form(dev, score)
+        monkeypatch.setattr(ranking, "_lambda_bucket", recording)
+        got_g, got_h = dev(score)
+    finally:
+        jax.config.update("jax_enable_x64", prev)
+    assert [idx.shape[1] for idx, _ in dev.qb.buckets] == [8, 32, 128, 256]
+    for (rows, shift), (idx, qids) in zip(dev.qb.windows, dev.qb.buckets):
+        ends = shift + sizes[qids]
+        assert (ends > ranking.LANES).any(), "a window crosses a row"
+    assert len(handed) == len(want_slots)
+    for sp, want, b in zip(handed, want_slots, dev._buckets):
+        assert sp.dtype == dtype
+        np.testing.assert_array_equal(_bits(sp), _bits(want))
+        assert np.all(np.asarray(sp)[~np.asarray(b["real"])] == -np.inf)
+    assert got_g.shape == got_h.shape == (n,)
+    np.testing.assert_array_equal(_bits(got_g), _bits(want_g))
+    np.testing.assert_array_equal(_bits(got_h), _bits(want_h))
+    assert np.any(np.asarray(got_g) != 0.0)
+
+
+_GATHER = re.compile(r'"stablehlo\.gather".*?slice_sizes = '
+                     r'array<i64: ([\d, ]+)>')
+_SCATTER = re.compile(r'"stablehlo\.scatter"(.*?)\}\) : \([^)]*, '
+                      r'tensor<([\dx]+)x[a-z]\w*>\)', re.S)
+_WINDOW_DIMS = re.compile(r"update_window_dims = \[([\d, ]*)\]")
+
+
+def _elements_an_index(text):
+    """For each gather and scatter in lowered StableHLO text, the number
+    of elements one index moves: its slice, or its update window."""
+    def ints(s, sep):
+        return [int(v) for v in s.replace(" ", "").split(sep) if v]
+    gathers = [int(np.prod(ints(m, ","))) for m in _GATHER.findall(text)]
+    scatters = []
+    for attrs, shape in _SCATTER.findall(text):
+        dims = _WINDOW_DIMS.search(attrs)       # absent where it is empty
+        dims = ints(dims.group(1), ",") if dims else []
+        scatters.append(int(np.prod([ints(shape, "x")[d] for d in dims])))
+    return gathers, scatters
+
+
+def test_lowered_gradient_moves_whole_rows_not_elements():
+    """At an MSLR-like shape (queries of 120 documents, S = 128) the
+    lowered DeviceLambdarank.__call__ moves no element by an index: each
+    gather and scatter index moves a row of 128.  The element form,
+    lowered the same way, is found by the same reading."""
+    n = 64 * 120
+    qb = np.arange(0, n + 1, 120)
+    dev = ranking.DeviceLambdarank(
+        qb, np.random.RandomState(0).randint(0, 5, n),
+        2.0 ** np.arange(8) - 1, np.ones(64), 1.0, dtype=jnp.float32)
+    arg = jax.ShapeDtypeStruct((n,), jnp.float32)
+    text = jax.jit(dev.__call__).lower(arg).as_text(debug_info=True)
+    assert "lgbm.gradient.scatter" in text
+    gathers, scatters = _elements_an_index(text)
+    assert gathers == [ranking.LANES]
+    assert scatters == [2 * ranking.LANES]     # lam and hes side by side
+    old = jax.jit(lambda s: _element_form(dev, s)[:2]).lower(arg).as_text()
+    assert _elements_an_index(old) == ([1], [1, 1])

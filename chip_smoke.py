@@ -398,9 +398,9 @@ def kernel_coverage(lgb, jax, jnp, args):
         assert not g._truncation_warned, name
         done.append(name)
 
-    # bagging: the unfused spine, whose root pass fuses partition +
-    # histogram (f32 7-plane and int8 3-plane) and recovers leaf ids
-    # through compact_segments
+    # bagging: the fused spine under a row bag (carried for binary), whose
+    # root pass fuses partition + histogram (f32 7-plane and int8
+    # 3-plane) and whose out-of-bag rows are scored in the program
     bag = {"bagging_fraction": 0.8, "bagging_freq": 1}
     check("bagging-f32", _train_small(lgb, jax, X, y, dict(force, **bag)))
     check("bagging-int8", _train_small(

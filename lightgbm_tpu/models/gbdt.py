@@ -168,7 +168,6 @@ class GBDT:
         self.train_state: Optional[_DatasetState] = None
         self.valid_states: List[Tuple[str, _DatasetState, List[Metric]]] = []
         self.best_iteration = 0
-        self._bag_rng = np.random.RandomState(config.bagging_seed)
         self._feat_rng = np.random.RandomState(config.feature_fraction_seed)
         # deferred-tree pipeline state (train_one_iter/_drain_inflight);
         # subclasses that need host trees within the iteration opt out
@@ -392,8 +391,11 @@ class GBDT:
         self.train_state.bundle = _bundle_maps(
             train_set, self._scan_space,
             feature_too=bool(self._forced_splits))
-        # bagging state
-        self._bag_mask: Optional[jnp.ndarray] = None
+        # bagging state: the drawn bag (ops/bagging.py), its index, and
+        # its row-order mask, made by _bagging for the unfused spine
+        self._bag = None
+        self._bag_index: Optional[int] = None
+        self._bag_mask = None
         self._row_all_in = jnp.zeros(self.num_data, jnp.int32)
         # init scores seed the training scores unconditionally (the reference
         # seeds ScoreUpdater at construction, score_updater.hpp:40-55), so
@@ -452,20 +454,43 @@ class GBDT:
     # Bagging (gbdt.cpp:159-241)
     # ------------------------------------------------------------------ #
     def _bagging(self, it: int) -> jnp.ndarray:
+        """The row mask of iteration `it` (0 in the bag, -1 out): a new
+        bag every bagging_freq iterations, drawn on the device as a
+        function of bagging_seed and the bag's index (ops/bagging.py), so
+        both spines and a resumed booster draw the same rows."""
+        self._draw_bag(it)
+        if self._bag is None:
+            return self._row_all_in
+        if self._bag_mask is None:
+            from ..ops import bagging
+            self._bag_mask = bagging.row_mask(self._bag,
+                                              num_data=self.num_data)
+        return self._bag_mask
+
+    def _bag_in_use(self) -> int:
+        """Rows of every bag under the config, 0 without bagging."""
         cfg = self.config
-        n = self.num_data
-        if cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0 \
-           and it % cfg.bagging_freq == 0:
-            bag_cnt = int(cfg.bagging_fraction * n)
-            idx = self._bag_rng.choice(n, bag_cnt, replace=False)
-            mask = np.full(n, -1, np.int32)
-            mask[idx] = 0
-            self._bag_mask = jnp.asarray(mask)
-            self._bag_count = bag_cnt       # telemetry: rows in this bag
-        elif cfg.bagging_freq <= 0 or cfg.bagging_fraction >= 1.0:
+        if cfg.bagging_freq <= 0 or cfg.bagging_fraction >= 1.0:
+            return 0
+        from ..ops import bagging
+        return max(bagging.bag_rows(cfg.bagging_fraction, self.num_data), 1)
+
+    def _draw_bag(self, it: int) -> None:
+        count = self._bag_in_use()
+        if not count:
+            self._bag = self._bag_index = self._bag_count = None
             self._bag_mask = None
-            self._bag_count = None
-        return self._bag_mask if self._bag_mask is not None else self._row_all_in
+            return
+        index = it // self.config.bagging_freq
+        if self._bag is not None and self._bag_index == index \
+                and self._bag_count == count:
+            return
+        from ..ops import bagging
+        self._bag = bagging.draw(self.config.bagging_seed % (1 << 32), index,
+                                 num_data=self.num_data, count=count)
+        self._bag_index = index
+        self._bag_count = count          # telemetry: rows in this bag
+        self._bag_mask = None
 
     def _feature_sample(self) -> jnp.ndarray:
         frac = self.config.feature_fraction
@@ -560,15 +585,13 @@ class GBDT:
         # leaf-value gather entirely (serial-gather cost on TPU)
         self._score_emit_ok = defer_unfused
 
-        # single-dispatch fast path: gradients + tree + score update fused
-        no_bagging = (self.config.bagging_freq <= 0
-                      or self.config.bagging_fraction >= 1.0)
-        fused_ok = no_bagging and self._fused_eligible(deferred_ok, k, custom)
+        # single-dispatch fast path: gradients + tree + score update fused;
+        # a row bag rides it as an argument (drawn below, _draw_bag)
+        fused_ok = self._fused_eligible(deferred_ok, k, custom)
         # carried-arena lifecycle: any iteration that will NOT run the
-        # carried path (custom gradients, bagging turned on mid-training
-        # via reset_parameter, lost fused eligibility) — or an external
-        # score write (rollback, refit, merge) — must demote NOW, firing
-        # the deferred materializer while the arena planes are still
+        # carried path (custom gradients, lost fused eligibility) — or an
+        # external score write (rollback, refit, merge) — must demote NOW,
+        # firing the deferred materializer while the arena planes are still
         # valid; the upcoming tree clobbers the carry slots.  Demotion
         # rewrites the pristine block (carried mode roots in it), so the
         # standard paths resume seamlessly.
@@ -584,6 +607,7 @@ class GBDT:
                 self._carried_active = False
                 if self._carried_ok(k):
                     self._init_carried()
+            self._draw_bag(self.iter)
             with self.profiler.phase("fused_iter"):
                 if self._carried_active:
                     packed_per_class = self._run_fused_iter_carried()
@@ -746,6 +770,7 @@ class GBDT:
         quantized = getattr(self, "_quantized", False)
         self._fused_fields = self._objective_device_fields()
         fields = self._fused_fields
+        bag_rows = self._bag_in_use()
 
         def fused(arena, bins_t, score, field_vals, row0, fmasks,
                   num_bins, default_bins, missing_types, sparams, monotone,
@@ -756,7 +781,8 @@ class GBDT:
             # eager path samples per tree).  vscores / vbins / vbundles:
             # one entry per validation set (_valid_args), empty lists for
             # a booster without one, which then traces the same program
-            # as before validation was scored here
+            # as before validation was scored here.  row0: the drawn bag
+            # (ops/bagging.py) under bagging, else unread
             olds = [getattr(h, a) for h, a in fields]
             for (h, a), v in zip(fields, field_vals):
                 setattr(h, a, v)
@@ -787,12 +813,14 @@ class GBDT:
                     None, None, self.is_categorical, bundle,
                     max_leaves=self.config.num_leaves,
                     max_depth=self.config.max_depth,
-                    max_bin=self.max_bin, emit="score", full_bag=True,
+                    max_bin=self.max_bin, emit="score",
+                    full_bag=not bag_rows,
                     max_cat_threshold=self.config.max_cat_threshold,
                     hist_slots=self._hist_slots,
                     forced_splits=self._forced_splits,
                     pristine=True, quantized=quantized,
-                    quant_scales=qsc, interpret=interpret)
+                    quant_scales=qsc, bag_rows=bag_rows,
+                    interpret=interpret)
                 with jax.named_scope("lgbm.finish"):
                     ivec, fvec = grow_ops.pack_tree_arrays(arrays)
                     ivecs.append(jnp.concatenate(
@@ -815,7 +843,7 @@ class GBDT:
         # the jitted fn bakes these in at trace time; rebuild if a
         # reset_parameter callback changed them mid-training
         key = (self.config.num_leaves, self.config.max_depth, self.max_bin,
-               self.config.max_cat_threshold)
+               self.config.max_cat_threshold, self._bag_in_use())
         rebuilt = (getattr(self, "_fused_fn", None) is None
                    or getattr(self, "_fused_key", None) != key)
         if rebuilt:
@@ -830,7 +858,7 @@ class GBDT:
         # kill-and-resume replays the identical rounding noise
         qkey = _qz.quantize_key(getattr(self, "_quant_seed", 0), self.iter)
         args = (self._arena, self._bins_t, self.train_state.score,
-                field_vals, self._row_all_in, fmasks,
+                field_vals, self._row0(), fmasks,
                 self.train_state.num_bins, self.train_state.default_bins,
                 self.train_state.missing_types, self.split_params,
                 self.monotone, self.penalty, self.train_state.bundle, sh,
@@ -848,8 +876,8 @@ class GBDT:
             n_field = len(jax.tree_util.tree_leaves(field_vals))
             obs_device.analyze_compiled(
                 self._fused_fn, args,
-                signature="leaves=%d depth=%d bin=%d cat=%d rows=%d" % (
-                    key + (self.num_data,)),
+                signature="leaves=%d depth=%d bin=%d cat=%d bag=%d rows=%d"
+                % (key + (self.num_data,)),
                 donation_resident=(1, *range(3, 4 + n_field)))
         ivecs, fvecs, new_score, arena, vscores = self._fused_fn(*args)
         self._store_valid_scores(vscores)
@@ -864,6 +892,11 @@ class GBDT:
         self.train_state.score = new_score
         self._last_truncated = jnp.asarray(False)   # flag rides ivec[-1]
         return list(zip(ivecs, fvecs))
+
+    def _row0(self):
+        """The fused programs' row argument: the drawn bag under bagging,
+        else the all-rows mask (which a full-bag program does not read)."""
+        return self._bag if self._bag is not None else self._row_all_in
 
     def _valid_args(self):
         """(scores, feature-major bins, bundle maps) of the validation
@@ -896,6 +929,7 @@ class GBDT:
         if spec is None:
             return False
         from ..ops import partition_pallas as _pp
+        from ..ops import valid_score as _vs
         G = self._bins_t.shape[0]
         base = _pp.feature_channels(G) + _pp.N_AUX
         need = 3 + sum(p for _a, p in spec)
@@ -906,7 +940,12 @@ class GBDT:
         # allocations: demand >= 2n so eligibility never trades the sort
         # for truncation (always true at tpu_arena_factor >= 4)
         n_al = -(-self._bins_t.shape[1] // _pp.TILE) * _pp.TILE
-        return cap - self._carried_layout()[1] >= 2 * n_al
+        bump0 = self._carried_layout()[1]
+        # under a bag the out-of-bag rows are dumped at bump0 and scored
+        # there in whole blocks (ops/grow_partition.py)
+        oob = self.num_data - (self._bag_in_use() or self.num_data)
+        return (cap - bump0 >= 2 * n_al
+                and bump0 + (_vs.padded_rows(oob) if oob else 0) <= cap)
 
     def _carried_layout(self):
         """((root slot 0, root slot 1), first bump column).  The two
@@ -967,6 +1006,7 @@ class GBDT:
         spec = objective.carry_fields()
         n_planes = [p for _a, p in spec]
         L = self.config.num_leaves
+        bag_rows = self._bag_in_use()
         self._fused_fields = self._objective_device_fields()
         fields_io = self._fused_fields
 
@@ -1005,21 +1045,23 @@ class GBDT:
                 g_in, h_in, _gs, _hs = qz.quantize_gradients(
                     g_in, h_in, qkey)
                 qsc = (_gs, _hs)
-            arrays, _used, arena, trunc = gp.grow_tree_partition_impl(
+            arrays, oob, arena, trunc = gp.grow_tree_partition_impl(
                 arena, bins_t, g_in, h_in, row0, fmask,
                 num_bins, default_bins, missing_types, sparams,
                 monotone, penalty, None, None, self.is_categorical,
                 bundle,
                 max_leaves=L, max_depth=self.config.max_depth,
-                max_bin=self.max_bin, emit="carry", full_bag=True,
+                max_bin=self.max_bin, emit="carry", full_bag=not bag_rows,
                 max_cat_threshold=self.config.max_cat_threshold,
                 hist_slots=self._hist_slots,
                 forced_splits=self._forced_splits,
                 pristine=False, carried_root=root0, carry_dst=dst,
                 carried_bump0=bump0, quantized=quantized,
-                quant_scales=qsc, interpret=interpret)
+                quant_scales=qsc, bag_rows=bag_rows, interpret=interpret)
             # per-row leaf value over the compacted order (leaf-index
-            # segments): boundary scatter + cumsum, no gather
+            # segments): boundary scatter + cumsum, no gather.  Under a bag
+            # the in-bag rows are the first bag_rows, the out-of-bag rows
+            # follow with the values the tree gave them (oob)
             with jax.named_scope("lgbm.score"):
                 lv = arrays.leaf_value.astype(jnp.float32)
                 lc = arrays.leaf_count
@@ -1028,6 +1070,9 @@ class GBDT:
                 diffs = diffs.at[bounds[:-1]].add(lv[1:] - lv[:-1],
                                                   mode="drop")
                 delta = jnp.cumsum(diffs)
+                if bag_rows:
+                    delta = jnp.concatenate(
+                        [delta[:bag_rows], oob.astype(jnp.float32)])
                 sc_new = merge(jax.lax.dynamic_slice(
                     arena, (jnp.int32(base), dst), (3, n))) + shrink * delta
                 arena = jax.lax.dynamic_update_slice(
@@ -1046,7 +1091,7 @@ class GBDT:
 
     def _run_fused_iter_carried(self):
         key = (self.config.num_leaves, self.config.max_depth, self.max_bin,
-               self.config.max_cat_threshold)
+               self.config.max_cat_threshold, self._bag_in_use())
         if (getattr(self, "_carried_fn", None) is None
                 or getattr(self, "_carried_key", None) != key):
             self._carried_fn = self._build_fused_iter_carried()
@@ -1061,7 +1106,7 @@ class GBDT:
         qkey = _qz.quantize_key(getattr(self, "_quant_seed", 0), self.iter)
         ivec, fvec, arena, vscores = self._carried_fn(
             self._arena, self._bins_t, root0, dst, field_vals,
-            self._row_all_in, fmask,
+            self._row0(), fmask,
             self.train_state.num_bins, self.train_state.default_bins,
             self.train_state.missing_types, self.split_params,
             self.monotone, self.penalty, self.train_state.bundle, sh, qkey,
@@ -1448,6 +1493,9 @@ class GBDT:
                         # what the objective moves on the device each
                         # iteration (lambdarank's query windows)
                         **getattr(self.objective, "device_plan", {}))
+            if self._bag_in_use():
+                # rows every tree grows on: the bag's (ops/bagging.py)
+                plan.update(bag_rows=self._bag_in_use())
             log.info("partition engine plan: %s", ", ".join(
                 "%s=%s" % kv for kv in plan.items()))
         self._engine_plan = plan
@@ -2123,7 +2171,6 @@ class GBDT:
             "round": int(self.iter),
             "boosting": type(self).__name__.lower(),
             "shrinkage_rate": float(self.shrinkage_rate),
-            "bag_rng": _rng_state_to_json(self._bag_rng),
             "feat_rng": _rng_state_to_json(self._feat_rng),
         }
         state.update(self._aux_state_extra())
@@ -2138,7 +2185,6 @@ class GBDT:
                 "aux state is for round %d but the loaded model holds %d "
                 "iterations" % (int(state["round"]), self.iter))
         self.shrinkage_rate = float(state["shrinkage_rate"])
-        self._bag_rng = _rng_state_from_json(state["bag_rng"])
         self._feat_rng = _rng_state_from_json(state["feat_rng"])
         self._restore_aux_extra(state)
 

@@ -52,6 +52,8 @@ from ..parallel import collective as coll
 from . import partition_pallas as pp
 from . import quantize as qz
 from . import split_pallas as sp_pl
+from . import valid_score
+from .bagging import member
 from .grow import MISSING_NAN, MISSING_ZERO, BundleMaps, TreeArrays
 from .split import (K_MIN_SCORE, SplitParams,
                     best_split_per_feature_mixed, select_best_feature)
@@ -94,7 +96,8 @@ def grow_tree_partition_impl(
         bins_t: jnp.ndarray,          # [F, n] bf16/f32 feature-major bins
         grad: jnp.ndarray,            # [n] f32
         hess: jnp.ndarray,            # [n] f32
-        row_leaf_init: jnp.ndarray,   # [n] int32: 0 in-bag, -1 out
+        row_leaf_init: jnp.ndarray,   # [n] int32: 0 in-bag, -1 out; the
+        #   ops/bagging.py bag instead where bag_rows > 0
         feature_mask: jnp.ndarray,    # [F] bool
         num_bins: jnp.ndarray,        # [F] int32
         default_bins: jnp.ndarray,    # [F] int32
@@ -124,7 +127,7 @@ def grow_tree_partition_impl(
         #   assembled root segment (carried-arena mode): bins/rowids AND
         #   score/label planes live at [carried_root, carried_root+n);
         #   assembly only refreshes the g/h planes there.  Requires
-        #   full_bag; emit="carry" compacts the finished tree's segments
+        #   full_bag or bag_rows; emit="carry" compacts the finished tree's segments
         #   to carry_dst for the next iteration's root.
         carry_dst=None,               # traced col offset for emit="carry"
         carried_bump0: int = 0,       # static first bump column (past
@@ -134,6 +137,13 @@ def grow_tree_partition_impl(
         #   residue planes; histogram kernels run the 3-component radix
         #   and results are dequantized per-kernel via quant_scales
         quant_scales=None,            # traced (g_scale, h_scale) f32
+        bag_rows: int = 0,            # static rows in the bag, which
+        #   row_leaf_init then is: the root's in-bag rows are those it
+        #   holds, by their row ids, in whatever order the root segment
+        #   has them (not with full_bag).  The out-of-bag rows
+        #   (n - bag_rows) are scored in the program from the
+        #   tree (scope lgbm.oob_score), emit="score" returns every row's
+        #   value and emit="carry" carries them behind the in-bag rows
         interpret: bool = False):
     """Grow one leaf-wise tree.
 
@@ -211,8 +221,15 @@ def grow_tree_partition_impl(
     adt = pp.ARENA_DT
     n_al = _align(n, pp.TILE)
     carried = carried_root is not None
-    if carried and (not full_bag or dist):
-        raise ValueError("carried-arena mode requires full_bag serial")
+    if carried and dist:
+        raise ValueError("carried-arena mode is serial")
+    bagged = bag_rows > 0
+    if full_bag and bagged or bag_rows > n:
+        raise ValueError("a bag of %d rows under full_bag=%s, n=%d"
+                         % (bag_rows, full_bag, n))
+    if carried and not full_bag and not bagged:
+        raise ValueError("carried-arena mode draws its bag by row id: "
+                         "pass `bag_rows`")
     work0 = pp.pristine_work0(n) if pristine else 0
     with jax.named_scope("lgbm.root"):
         if quantized:
@@ -274,22 +291,48 @@ def grow_tree_partition_impl(
                 cursor0 = jnp.int32(work0 + n_al if pristine
                                     else n_al + pp.TILE)
         else:
-            in_bag = (row_leaf_init == 0)
-            pred0 = jnp.pad(in_bag.astype(dtype), (0, cap - n))[None, :]
-            # pristine: in-bag rows copied to the work region (pristine rows
-            # intact for the next tree); legacy: compacted in place
-            bag_dst = work0 if pristine else 0
-            oob_dst = bag_dst + n_al
+            if carried:
+                # the carried root holds the rows in the previous tree's
+                # leaf order: in-bag rows compacted in place (lagging
+                # writes), out-of-bag rows dumped past both root slots
+                root_at = jnp.asarray(carried_root, jnp.int32)
+                bag_dst = root_at
+                oob_at = carried_bump0
+                oob_end = oob_at + _align(n - bag_rows, pp.TILE)
+                # the pred stream is read by absolute column: it spans the
+                # higher root slot (the carried layout's second slot is
+                # pristine_work0 wide)
+                width = pp.pristine_work0(n) + n_al + pp.TILE
+            else:
+                # pristine: in-bag rows copied to the work region (pristine
+                # rows intact for the next tree); legacy: compacted in place
+                root_at = jnp.int32(0)
+                bag_dst = jnp.int32(work0 if pristine else 0)
+                oob_at = (work0 if pristine else 0) + n_al
+                oob_end = oob_at + n_al
+                width = n_al + pp.TILE
+            oob_dst = jnp.int32(oob_at)
+            if bagged:
+                rid = (pp.merge_rowid(*jax.lax.dynamic_slice(
+                    arena, (jnp.int32(Fp + 6), root_at), (3, n)))
+                    if carried else jnp.arange(n, dtype=jnp.int32))
+                in_bag = member(row_leaf_init, rid)
+                pred0 = jax.lax.dynamic_update_slice(
+                    jnp.zeros((1, width), dtype),
+                    in_bag.astype(dtype)[None, :], (jnp.int32(0), root_at))
+            else:
+                in_bag = (row_leaf_init == 0)
+                pred0 = jnp.pad(in_bag.astype(dtype), (0, cap - n))[None, :]
             # fused compaction + in-bag (stream A) histogram: the root
             # histogram covers every row the pass reads anyway, so here the
             # fusion is pure saving (one full-n re-read + a launch)
             arena, counts0, root_hist_b = part(
-                arena, pred0, jnp.int32(0), jnp.int32(n),
-                jnp.int32(bag_dst), jnp.int32(oob_dst), hist_stream=0,
+                arena, pred0, root_at, jnp.int32(n),
+                bag_dst, oob_dst, hist_stream=0,
                 num_features=G, max_bin=max_bin, quantized=quantized)
             root_c = counts0[0]
-            root_s0 = jnp.int32(bag_dst)
-            cursor0 = jnp.int32(oob_dst + n_al)  # past the oob dump space
+            root_s0 = bag_dst
+            cursor0 = jnp.int32(oob_end)     # past the oob dump space
 
         if full_bag:
             if quantized:
@@ -1046,18 +1089,46 @@ def grow_tree_partition_impl(
             is_cat=nm[:, 9] > 0.5,
             cat_mask=state.node_cat > 0.5)
 
+    if bagged and emit in ("score", "carry"):
+        # the out-of-bag rows, still where the root pass dumped them: each
+        # finds its leaf in the tree just grown by ops/valid_score.py's
+        # products over its bins, and has its row id beside them
+        n_oob = n - bag_rows
+        rows = valid_score.padded_rows(n_oob)
+        if oob_at + rows > cap:
+            raise ValueError("the arena has no room to score %d out-of-bag "
+                             "rows" % n_oob)
+        with jax.named_scope("lgbm.oob_score"):
+            oob_bins = jax.lax.dynamic_slice(
+                state.arena, (jnp.int32(0), oob_dst), (G, rows))
+            oob_val = valid_score.tree_delta(
+                oob_bins, tree, valid_score.tree_signatures(tree),
+                num_bins, default_bins, bundle)[:n_oob]
+            oob_rid = pp.merge_rowid(*jax.lax.dynamic_slice(
+                state.arena, (jnp.int32(Fp + 6), oob_dst), (3, n_oob)))
+
+    with jax.named_scope("lgbm.finish"):
         if emit == "carry":
             # carried-arena boundary: compact the live segments (leaf-index
             # order, full channels incl. score/label planes) into the other
             # root slot — NO row-order recovery, NO sort; the caller updates
             # the score planes from leaf_value/leaf_count and roots the next
             # tree at carry_dst (per-row leaf values derive from
-            # cumsum(leaf_count) over the same leaf order)
+            # cumsum(leaf_count) over the same leaf order).  Under a bag the
+            # out-of-bag dump is one more segment, behind the leaves: the
+            # second output is then its rows' leaf values, in that order
+            starts = lm[:, 6].astype(jnp.int32)
+            cnts = lm[:, 7].astype(jnp.int32)
+            live = state.nl
+            if bagged:
+                starts = jnp.append(starts, 0).at[live].set(oob_dst)
+                cnts = jnp.append(cnts, 0).at[live].set(n_oob)
+                live = live + 1
             arena2, used = pp.compact_carry(
-                state.arena, lm[:, 6].astype(jnp.int32),
-                lm[:, 7].astype(jnp.int32), state.nl,
+                state.arena, starts, cnts, live,
                 jnp.asarray(carry_dst, jnp.int32), interpret=interpret)
-            return tree, used, arena2, state.truncated
+            return (tree, oob_val if bagged else used, arena2,
+                    state.truncated)
 
         # ---- recover per-row outputs from the final segments -------------
         # The compact kernel streams ONLY the live segments (O(n) work,
@@ -1075,12 +1146,17 @@ def grow_tree_partition_impl(
         # dummy) — mask them to the dummy rowid before the reorder
         written = jnp.arange(capn, dtype=jnp.int32) < used[0]
         rid = jnp.where(written, stream[0].astype(jnp.int32), n)
-        if full_bag:
+        if full_bag or (bagged and emit == "score"):
             # every rowid in [0, n) appears exactly once (segments partition
-            # the full root segment), so a key/value sort puts the values in
-            # row order directly — measured ~2x faster than the XLA scatter
-            # (TPU scatters serialize; sort is a fast bitonic primitive)
-            _, sv = jax.lax.sort((rid, stream[1]), num_keys=1)
+            # the full root segment; under a bag the out-of-bag rows join
+            # them), so a key/value sort puts the values in row order
+            # directly — measured ~2x faster than the XLA scatter (TPU
+            # scatters serialize; sort is a fast bitonic primitive)
+            vals_all = stream[1]
+            if bagged:
+                rid = jnp.concatenate([rid, oob_rid])
+                vals_all = jnp.concatenate([vals_all, oob_val])
+            _, sv = jax.lax.sort((rid, vals_all), num_keys=1)
             if emit == "score":
                 return tree, sv[:n].astype(dtype), state.arena, state.truncated
             return (tree, jnp.round(sv[:n]).astype(jnp.int32), state.arena,
@@ -1106,5 +1182,5 @@ grow_tree_partition = partial(jax.jit, static_argnames=(
     "max_leaves", "max_depth", "max_bin", "emit", "full_bag",
     "max_cat_threshold", "axis_name", "learner", "num_machines", "top_k",
     "hist_slots", "forced_splits", "pristine", "carried_bump0",
-    "quantized", "interpret"),
+    "quantized", "bag_rows", "interpret"),
     donate_argnums=(0,))(grow_tree_partition_impl)

@@ -72,7 +72,8 @@ def _tiny():
 
 @pytest.mark.parametrize("extra", [
     {},                                          # fused iteration
-    {"bagging_fraction": 0.8, "bagging_freq": 1},   # unfused spine
+    {"boosting": "goss"},                        # unfused spine
+    {"bagging_fraction": 0.8, "bagging_freq": 1},   # fused, under a bag
     {"tree_learner": "data", "num_machines": 2,
      "tpu_comm_backend": "mesh"},                # shard_map'd grower
     {"tree_learner": "data", "num_machines": 2,

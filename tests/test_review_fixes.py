@@ -174,9 +174,10 @@ class TestEngineFailurePropagates:
     def test_partition_failure_raises(self, monkeypatch):
         """A lowering/runtime failure in the partition fast path raises
         with its message — the fused single-dispatch iteration AND the
-        plain per-tree grow.  (It used to demote the booster to the label
-        engine with a warning; a booster that quietly changed engine gets
-        measured as something it is not.)"""
+        plain per-tree grow (GOSS keeps the unfused spine), also under a
+        row bag.  (It used to demote the booster to the label engine with
+        a warning; a booster that quietly changed engine gets measured as
+        something it is not.)"""
         from lightgbm_tpu.ops import grow_partition as gp_mod
         rng = np.random.default_rng(0)
         X = rng.normal(size=(500, 6)).astype(np.float32)
@@ -185,7 +186,8 @@ class TestEngineFailurePropagates:
         def boom(*a, **k):
             raise RuntimeError("simulated Mosaic lowering failure")
 
-        for extra in ({}, {"bagging_fraction": 0.8, "bagging_freq": 1}):
+        for extra in ({}, {"boosting": "goss"},
+                      {"bagging_fraction": 0.8, "bagging_freq": 1}):
             bst = lgb.Booster(params=dict({"objective": "binary",
                                            "verbose": -1,
                                            "tpu_tree_engine": "partition"},

@@ -9,9 +9,9 @@ result line are printed as they are), then prints from the same trace and
 the same ledger (`lightgbm_tpu.obs.device.split_ledgers()`,
 benchmarks/readers/row_ledger.py) one `[fit] {"metrics": ...}` line with
 the seven per-layer metrics of benchmarks/layer_metrics/ that read the
-ledger (BENCHMARK.json does not list them yet: PERF.md section 7 says
-which test stands in the way), and one `[fit] {...}` line per in-loop
-kernel:
+ledger (BENCHMARK.json lists them, with no `workloads` key: every train
+cell on one chip reads them on its traced line), and one `[fit] {...}`
+line per in-loop kernel:
 
 - the Theil-Sen line seconds = a * rows + b through the slice's calls: a
   as ms a pass over the data set's rows, b in microseconds, and the median
